@@ -454,7 +454,7 @@ register(
 # dispatch of a compiled callable in one function must run under the
 # same set_mesh expression.
 
-_SET_MESH_CALLS = ("set_mesh", "compat.set_mesh", "jax.sharding.use_mesh")
+_SET_MESH_CALLS = ("set_mesh", "jax.set_mesh", "jax.sharding.use_mesh")
 
 
 def _mesh_ctx_expr(stmt: ast.With) -> ast.expr | None:

@@ -651,7 +651,6 @@ def run_comms_audit(
     from deeplearning_cfn_tpu.parallel.mesh import MeshSpec, build_mesh
     from deeplearning_cfn_tpu.train.data import SyntheticDataset
     from deeplearning_cfn_tpu.train.trainer import Trainer, TrainerConfig
-    from deeplearning_cfn_tpu.utils import compat
 
     devices = jax.devices()
     n = 8 if len(devices) >= 8 else len(devices)
@@ -669,7 +668,7 @@ def run_comms_audit(
     )
     sample = next(iter(ds.batches(1)))
     watcher = CommsWatcher()
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         state = trainer.init(jax.random.PRNGKey(0), sample.x)
         prediction = StrategyPrediction.from_state(state)
 
@@ -708,7 +707,7 @@ def run_comms_audit(
             **dp_kwargs,
         ),
     )
-    with compat.set_mesh(dp_mesh):
+    with jax.set_mesh(dp_mesh):
         dp_state = mono_dp.init(jax.random.PRNGKey(0), sample.x)
         dp_prediction = StrategyPrediction.from_state(dp_state)
         watcher.watch(

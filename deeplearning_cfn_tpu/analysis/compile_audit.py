@@ -52,13 +52,15 @@ REPO_ROOT = Path(__file__).resolve().parents[2]
 AUDITED_FILE = REPO_ROOT / "deeplearning_cfn_tpu" / "train" / "trainer.py"
 SERVE_AUDITED_FILE = REPO_ROOT / "deeplearning_cfn_tpu" / "serve" / "engine.py"
 
-# jax_log_compiles emits exactly two shapes (jax 0.4.x):
+# jax_log_compiles emits two shapes the watcher reads:
 #   "Finished tracing + transforming {name} for pjit in {t} sec"
 #     (logger jax._src.dispatch)
-#   "Compiling {name} with global shapes and types [...]"
+#   "Compiling jit({name}) with global shapes and types [...]"
 #     (logger jax._src.interpreters.pxla)
+# Both are keyed by the bare function name, so a trace and its compile
+# land under the same key.
 _TRACE_RE = re.compile(r"Finished tracing \+ transforming (.+?) for pjit")
-_COMPILE_RE = re.compile(r"^Compiling (.+?) with global shapes")
+_COMPILE_RE = re.compile(r"^Compiling jit\((.+)\) with global shapes")
 _COMPILE_LOGGERS = ("jax._src.dispatch", "jax._src.interpreters.pxla")
 
 _BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"

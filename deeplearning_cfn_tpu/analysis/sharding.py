@@ -111,7 +111,7 @@ _TRACED_CALLABLE_POSITIONS: dict[str, tuple[int, ...]] = {
     "jax.remat": (0,),
     "remat": (0,),
     "shard_map": (0,),
-    "compat.shard_map": (0,),
+    "jax.shard_map": (0,),
     "jax.experimental.shard_map.shard_map": (0,),
 }
 

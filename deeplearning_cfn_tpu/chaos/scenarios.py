@@ -420,8 +420,13 @@ def flaky_rpc(seed: int) -> ScenarioReport:
         else True,
         "backoff is jittered (not a fixed exponential ladder)",
     )
+    # Replay the clock's own arithmetic (a running +=): the built-in sum()
+    # is compensated since Python 3.12 and lands a last bit away from it.
+    slept = 0.0
+    for s in clock.sleeps:
+        slept += s
     report.check(
-        clock.now() == sum(clock.sleeps),
+        clock.now() == slept,
         "all waiting happened on the injected clock (no real sleeps)",
     )
 

@@ -1398,7 +1398,9 @@ def cmd_serve(args) -> int:
         plan_placement,
         run_load,
     )
+    from deeplearning_cfn_tpu.utils.compile_cache import enable_compile_cache
 
+    enable_compile_cache()
     if args.journal:
         os.environ["DLCFN_FLIGHT_JOURNAL"] = args.journal
     # The demo model: the flagship transformer at toy scale (the plane's

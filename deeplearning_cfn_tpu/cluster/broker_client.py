@@ -874,19 +874,21 @@ class BrokerQueue(RendezvousQueue):
         self._conn.close()
 
 
-def build_broker(force: bool = False) -> Path:
-    """Compile the broker with make (idempotent)."""
-    if BROKER_BIN.exists() and not force:
-        return BROKER_BIN
-    if shutil.which("make") is None or shutil.which("g++") is None:
-        raise BrokerError("make/g++ not available to build the broker")
+def build_broker() -> Path:
+    """``make`` the broker: a no-op when the binary is newer than its
+    source, a rebuild when it is not — so a stale or foreign artefact
+    left in the tree is never what gets spawned."""
+    if shutil.which("make") is None:
+        raise BrokerError("make not available to build the broker")
     # Bounded: a wedged compiler must fail the provision step, not hang it.
-    subprocess.run(
+    proc = subprocess.run(
         ["make", "-C", str(BROKER_DIR)],
-        check=True,
         capture_output=True,
+        text=True,
         timeout=600,
     )
+    if proc.returncode != 0:
+        raise BrokerError(f"building the broker failed:\n{proc.stderr}")
     return BROKER_BIN
 
 

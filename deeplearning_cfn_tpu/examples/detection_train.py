@@ -29,7 +29,6 @@ from deeplearning_cfn_tpu.models import retinanet
 from deeplearning_cfn_tpu.train.data import SyntheticDetectionDataset
 from deeplearning_cfn_tpu.train.datasets import IMAGENET_MEAN, IMAGENET_STD
 from deeplearning_cfn_tpu.train.trainer import Trainer, TrainerConfig
-from deeplearning_cfn_tpu.utils.compat import set_mesh
 
 BACKBONES = {
     "tiny": (1, 1, 1, 1),  # tests / CPU
@@ -348,7 +347,7 @@ def evaluate_map(model, trainer, state, anchors, args, batch, steps: int) -> dic
     full_hw = (args.image_size, args.image_size)
     for batch_data in eval_batches(steps):
         x = jax.device_put(batch_data.x, trainer.batch_sharding)
-        with set_mesh(trainer.mesh):
+        with jax.set_mesh(trainer.mesh):
             dets = jax.device_get(infer(state.params, state.model_state, x))
         for i in range(len(batch_data.x)):
             acc.add_image(

@@ -15,11 +15,16 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 
-from deeplearning_cfn_tpu.examples.common import base_parser, maybe_init_distributed
+from deeplearning_cfn_tpu.examples.common import (
+    base_parser,
+    maybe_init_distributed,
+    metrics_sink,
+    param_probe,
+    run_report,
+)
 from deeplearning_cfn_tpu.models import llama
 from deeplearning_cfn_tpu.parallel.mesh import MeshSpec, build_mesh
 from deeplearning_cfn_tpu.train.data import SyntheticTokenDataset
-from deeplearning_cfn_tpu.examples.common import metrics_sink
 from deeplearning_cfn_tpu.train.trainer import TrainerConfig
 
 
@@ -38,6 +43,21 @@ def token_record_batches(
         return None
     loader, spec, _ = loaded
     return lambda steps: token_batches(loader, spec, steps)
+
+
+def size_config(size: str, seq_len: int) -> llama.LlamaConfig:
+    """The ``--size`` ladder: name -> LlamaConfig at ``seq_len``."""
+    if size == "8b":
+        return llama.LlamaConfig.llama3_8b()
+    if size == "3b":
+        # The adafactor rung: pass --optimizer adafactor — adamw's moment
+        # state cannot hold this on a 16 GiB chip (llama_memory).
+        return llama.LlamaConfig.b3(seq_len=seq_len)
+    if size == "1b":
+        return llama.LlamaConfig.b1(seq_len=seq_len)
+    if size == "435m":
+        return llama.LlamaConfig.m435(seq_len=seq_len)
+    return llama.LlamaConfig.tiny(vocab_size=512, seq_len=seq_len)
 
 
 def main(argv: list[str] | None = None) -> dict:
@@ -75,18 +95,7 @@ def main(argv: list[str] | None = None) -> dict:
     dp = max(1, n // (fsdp * tp * sp * pp * ep))
     mesh = build_mesh(MeshSpec(dp=dp, fsdp=fsdp, pp=pp, sp=sp, tp=tp, ep=ep))
 
-    if args.size == "8b":
-        cfg = llama.LlamaConfig.llama3_8b()
-    elif args.size == "3b":
-        # The adafactor rung: pass --optimizer adafactor — adamw's moment
-        # state cannot hold this on a 16 GiB chip (llama_memory).
-        cfg = llama.LlamaConfig.b3(seq_len=args.seq_len)
-    elif args.size == "1b":
-        cfg = llama.LlamaConfig.b1(seq_len=args.seq_len)
-    elif args.size == "435m":
-        cfg = llama.LlamaConfig.m435(seq_len=args.seq_len)
-    else:
-        cfg = llama.LlamaConfig.tiny(vocab_size=512, seq_len=args.seq_len)
+    cfg = size_config(args.size, args.seq_len)
     if args.ring_attention:
         cfg = dataclasses.replace(cfg, use_ring_attention=True)
     if args.fused_qkv:
@@ -153,6 +162,7 @@ def main(argv: list[str] | None = None) -> dict:
         sink=metrics_sink(args, "llama"),
         log_every=args.log_every,
     )
+    probe = param_probe(state)
     state, losses = trainer.fit(
         state, batches(args.steps), steps=args.steps, logger=logger, checkpointer=ckpt
     )
@@ -163,9 +173,11 @@ def main(argv: list[str] | None = None) -> dict:
         "final_loss": losses[-1],
         "steps": len(losses),
         "mesh": {"dp": dp, "fsdp": fsdp, "pp": pp, "sp": sp, "tp": tp, "ep": ep},
+        "attention": llama.attention_kind(cfg, mesh, args.seq_len),
         "params": llama.param_count(cfg),
         "first_step_s": first_step_clock(trainer, t_main),
         "history": logger.history,
+        **run_report(trainer, state, losses, probe),
     }
     if args.eval_steps:
         import math

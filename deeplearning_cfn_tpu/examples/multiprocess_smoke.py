@@ -1,8 +1,14 @@
-"""Multi-process SPMD smoke — the real distributed-backend proof.
+"""Multi-process SPMD smoke — a CPU-only aid for the distributed backend.
+
+It starts N OS processes on one machine, which is right for virtual CPU
+devices and wrong for a TPU host: a chip belongs to one process at a
+time, so N processes cannot share the chips of one host (one process
+drives all of them — that is what ``chip_smoke.py`` runs).  On real
+hardware the same contract applies with one process per worker VM.
 
 The reference's distributed story is only exercised end-to-end by an
 actual cluster run (mpirun over the hostfile, run.sh:70-95).  This module
-is the TPU framework's equivalent proof, runnable anywhere: N OS
+is the framework's stand-in for that, runnable without a cluster: N OS
 processes (one per "worker VM") join a `jax.distributed` cluster using
 exactly the env contract the discovery agent publishes
 (DEEPLEARNING_WORKERS_COUNT / DEEPLEARNING_COORDINATOR / DLCFN_PROCESS_ID,

@@ -24,7 +24,10 @@ class FusedDense(nn.Module):
     lowering, not in parameters: the kernel accumulates in f32 on the
     MXU and applies bias/activation in VMEM before the single HBM
     write.  Leading axes are flattened to 2D around the kernel call
-    (the kernel's layout contract is ``x [M, K]``).
+    (the kernel's layout contract is ``x [M, K]``).  The kernel is the
+    compiled Mosaic one, so a model with its ``use_pallas_*`` flag on
+    needs a TPU; the CPU tests run it under
+    ``pltpu.force_tpu_interpret_mode()``.
     """
 
     features: int

@@ -423,13 +423,15 @@ def _block(
     elif kind == "flash":
         from deeplearning_cfn_tpu.ops.pallas_attention import flash_attention
 
-        attn = flash_attention(q, k, v, causal=True, mesh=mesh)
+        # attention_kind only answers "flash" on a tpu backend, so this is
+        # always the compiled Mosaic kernel.
+        attn = flash_attention(q, k, v, causal=True, mesh=mesh, interpret=False)
     else:
-        # "xla" covers use_flash_attention off-TPU (the Pallas kernel would
-        # run in interpret mode — slow) AND below-crossover sequences where
-        # XLA's fused attention measures faster than the Pallas kernel
-        # (docs/BENCH_NOTES.md): use_flash means "fastest memory-safe
-        # attention", not "always Pallas".
+        # "xla" covers use_flash_attention off-TPU (the Pallas kernel needs
+        # Mosaic) AND below-crossover sequences where XLA's fused attention
+        # measures faster than the Pallas kernel (docs/BENCH_NOTES.md):
+        # use_flash means "fastest memory-safe attention", not "always
+        # Pallas".
         attn = dot_product_attention(q, k, v, causal=True)
     x = x + attn.reshape(B, S, cfg.n_heads * hd) @ lp["wo"]
     h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)

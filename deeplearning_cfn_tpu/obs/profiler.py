@@ -379,18 +379,12 @@ NULL_PROFILER = StepProfiler(name="null", enabled=False)
 
 
 def program_cost(compiled: Any) -> dict[str, float | None]:
-    """Normalize an AOT-compiled program's ``cost_analysis`` to flops/bytes.
-
-    Same list-vs-dict normalization as ``Trainer.compile_stats`` (the
-    return shape varies across jax versions and backends); returns
-    ``None`` values when the backend reports no cost model.
-    """
+    """An AOT-compiled program's ``cost_analysis`` as flops/bytes, with
+    ``None`` values when the backend reports no cost model."""
     try:
         cost = compiled.cost_analysis()
     except Exception:
         return {"flops": None, "bytes_accessed": None}
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
     if not isinstance(cost, dict):
         return {"flops": None, "bytes_accessed": None}
     flops = cost.get("flops")

@@ -10,8 +10,8 @@ BASELINE.json) are transformers.  Two paths:
   everything on-chip for moderate sequence lengths.
 - ``flash_attention``: Pallas blockwise-softmax kernel (ops/pallas_attention)
   for long sequences where materializing the [S, S] score matrix would blow
-  HBM bandwidth.  Off-TPU it runs in Pallas interpret mode (identical
-  numerics, slow) — dispatch to ``dot_product_attention`` there instead.
+  HBM bandwidth.  It needs a TPU; callers dispatch to
+  ``dot_product_attention`` elsewhere (models/llama.attention_kind).
 
 Both are pure functions of [batch, seq, heads, head_dim] tensors, grouped-
 query aware (kv heads may be fewer than q heads).

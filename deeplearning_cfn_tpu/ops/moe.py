@@ -28,7 +28,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from deeplearning_cfn_tpu.utils import compat
 
 
 @dataclass(frozen=True)
@@ -89,8 +88,8 @@ def _n_data_groups(n_tokens: int) -> int:
     than the shard count could not be sharded evenly over (dp, fsdp) anyway,
     so if the tokens don't split evenly we fall back to one unsharded group.
     1 when no mesh context is active."""
-    mesh = compat.get_abstract_mesh()
-    if mesh is None or not mesh.axis_names:
+    mesh = jax.sharding.get_abstract_mesh()
+    if not mesh.axis_names:
         return 1
     g = mesh.shape.get("dp", 1) * mesh.shape.get("fsdp", 1)
     return g if g > 1 and n_tokens % g == 0 else 1

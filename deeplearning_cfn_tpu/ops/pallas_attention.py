@@ -24,11 +24,12 @@ transformer flagships (BERT, Llama-3).  Design is TPU-first:
   independent per (batch, head), so each shard computes locally with no
   collectives.  Sequence sharding (sp > 1) is NOT this kernel's job; that
   is ring attention (parallel/ring_attention.py).
-- Off-TPU the same kernel body runs in Pallas **interpret mode** — bit-true
-  numerics for tests/dry-runs, but grid-sequential and slow.  It is a
-  correctness path, not a performance fallback; performance-sensitive
-  callers should dispatch to ops.attention.dot_product_attention off-TPU
-  (models/llama.py does).
+- The kernel compiles through Mosaic and so needs a TPU; it does not guess
+  its backend.  Callers choose: ``models/llama.attention_kind`` only picks
+  this kernel on a ``tpu`` backend and takes ops.attention
+  .dot_product_attention everywhere else.  ``interpret=True`` runs the same
+  kernel body in the Pallas interpreter — grid-sequential and slow, for the
+  CPU tests that ask for it by name, never a fallback.
 
 Layout contract matches ops/attention.py: [batch, seq, heads, head_dim].
 """
@@ -42,12 +43,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-from deeplearning_cfn_tpu.utils import compat
-
-# CompilerParams is the modern (jax >= 0.6) name; 0.4.x spells the same
-# dataclass TPUCompilerParams.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
 
 NEG_INF = -1e30
 
@@ -244,7 +239,7 @@ def _flash_forward(
             pltpu.VMEM((block_q, 128), jnp.float32),
             pltpu.VMEM((block_q, 128), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             # batch/head/q blocks are independent (megacore-splittable); only
             # the kv axis is sequential — it carries the VMEM accumulator.
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
@@ -410,13 +405,14 @@ def flash_attention(
     sm_scale: float | None = None,
     block_q: int = DEFAULT_BLOCK_Q,
     block_k: int = DEFAULT_BLOCK_K,
-    interpret: bool | None = None,
+    interpret: bool = False,
     mesh: Mesh | None = None,
 ) -> jax.Array:
     """Flash attention, [B, S, H, D] in/out, GQA-aware (Hkv must divide Hq).
 
-    ``interpret=None`` auto-selects: compiled Pallas on TPU, interpreter
-    elsewhere (identical numerics; slow — see module docstring).
+    Compiled Mosaic kernel unless ``interpret=True`` (the Pallas
+    interpreter: slow, for CPU tests — see module docstring).  Off a TPU
+    the compiled form raises; nothing here falls back.
 
     ``mesh``: when given and any of dp/fsdp/tp is > 1, the kernel runs under
     ``shard_map`` with batch sharded over (dp, fsdp) and heads over tp; the
@@ -428,8 +424,6 @@ def flash_attention(
     if sm_scale is None:
         sm_scale = q.shape[-1] ** -0.5
     sm_scale = float(sm_scale)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     interpret = bool(interpret)
 
     def core(q, k, v):
@@ -447,7 +441,7 @@ def flash_attention(
         if Hkv % tp != 0:
             raise ValueError(f"tp={tp} must divide kv heads ({Hkv})")
         spec = P(("dp", "fsdp"), None, "tp", None)
-        return compat.shard_map(
+        return jax.shard_map(
             core,
             mesh=mesh,
             in_specs=(spec, spec, spec),

@@ -50,7 +50,6 @@ from typing import Any, Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from deeplearning_cfn_tpu.ops.quant import dequantize_flat, quantize_flat
@@ -457,11 +456,11 @@ def build_overlap_grad_fn(
         return out, tuple(new_residuals)
 
     @partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(param_specs, batch_spec, batch_spec, ef_specs),
         out_specs=(P(), P(), param_specs, ef_specs),
-        check_rep=False,
+        check_vma=False,
     )
     def grad_sync_step(params, x, y, residuals):
         flat_params, treedef = jax.tree_util.tree_flatten(params)

@@ -35,7 +35,6 @@ from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
-from deeplearning_cfn_tpu.utils.compat import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -191,7 +190,7 @@ def pipeline_apply(
 
     # Stage-axis spec for params; everything else stays GSPMD-auto.
     param_in_specs = jax.tree_util.tree_map(lambda _: P(axis), stage_params)
-    outs, aux = shard_map(
+    outs, aux = jax.shard_map(
         schedule,
         mesh=mesh,
         in_specs=(param_in_specs, P()),

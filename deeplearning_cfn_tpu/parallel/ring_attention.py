@@ -30,7 +30,6 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from deeplearning_cfn_tpu.utils.compat import shard_map
 
 from deeplearning_cfn_tpu.ops.attention import _repeat_kv
 
@@ -149,7 +148,7 @@ def ring_attention(
         return out
 
     spec = P(("dp", "fsdp"), axis, "tp", None)
-    return shard_map(
+    return jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(spec, spec, spec),

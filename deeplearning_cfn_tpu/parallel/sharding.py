@@ -18,7 +18,6 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from deeplearning_cfn_tpu.utils import compat
 
 # Default logical-to-mesh rules.  Keys are logical axis names used by models;
 # values are mesh axis names (or tuples) or None (replicate).
@@ -78,10 +77,21 @@ def maybe_shard(x: Any, spec: P) -> Any:
     """Apply a with_sharding_constraint hint when a mesh context is active;
     no-op otherwise.  Lets model code stay mesh-agnostic — the trainer sets
     the context mesh (trainer.train_step)."""
-    mesh = compat.get_abstract_mesh()
-    if mesh is None or not mesh.axis_names:
+    if not jax.sharding.get_abstract_mesh().axis_names:
         return x
     return jax.lax.with_sharding_constraint(x, spec)
+
+
+def bytes_by_device(tree: Any) -> dict[int, int]:
+    """Bytes of ``tree``'s addressable shards resident on each device,
+    keyed by device id — how a run shows that its state and its input
+    reached every chip and did not pile up on device 0.  Reads shard
+    metadata only: no transfer, no sync."""
+    out: dict[int, int] = {}
+    for leaf in jax.tree_util.tree_leaves(tree):
+        for shard in getattr(leaf, "addressable_shards", ()):
+            out[shard.device.id] = out.get(shard.device.id, 0) + shard.data.nbytes
+    return out
 
 
 def shard_pytree(tree: Any, shardings: Any) -> Any:

@@ -525,7 +525,16 @@ class DevicePrefetcher:
 
                     self._stats.add_transfer(nbytes_of((item.x, item.y)))
                 t_put = time.perf_counter()
-                item = Batch(*device_put_batch(item, self._sharding))
+                try:
+                    item = Batch(*device_put_batch(item, self._sharding))
+                except BaseException as e:  # dlcfn: noqa[DLC004] not swallowed: re-raised in the consumer's __iter__
+                    # A transfer that fails (a batch the mesh cannot
+                    # divide, device memory exhausted) ends the stream at
+                    # this position; dying silently here would leave the
+                    # consumer waiting for a batch that never comes.
+                    item, terminal = e, True
+                    with self._src_lock:
+                        self._done = True
                 if self._profiler is not None:
                     self._profiler.fold(
                         "h2d", time.perf_counter() - t_put, critical=False

@@ -42,12 +42,12 @@ PEAK_BF16_FLOPS_PER_CHIP: dict[str, float] = {
 
 
 def peak_flops_per_chip(device=None) -> float | None:
-    """Peak bf16 FLOP/s for a JAX device, or None when unknown (CPU/GPU
-    backends used in tests).  Longest-prefix match so 'TPU v5 lite'
-    wins over 'TPU v5'."""
-    d = device if device is not None else jax.devices()[0]
-    kind = str(getattr(d, "device_kind", ""))
-    return _longest_prefix(PEAK_BF16_FLOPS_PER_CHIP, kind)
+    """Peak bf16 FLOP/s for a JAX device.  Longest-prefix match so
+    'TPU v5 lite' wins over 'TPU v5'.  None off a TPU (the CPU mesh the
+    tests run on has no MXU to be a fraction of); on platform ``tpu`` a
+    kind missing from the table raises — a chip run never logs a silent
+    ``mfu: null``."""
+    return _device_peak(PEAK_BF16_FLOPS_PER_CHIP, device)
 
 
 # Peak HBM bandwidth per chip (bytes/s, public Cloud TPU figures) — the
@@ -68,18 +68,26 @@ PEAK_HBM_BYTES_PER_CHIP: dict[str, float] = {
 
 
 def peak_hbm_bytes_per_chip(device=None) -> float | None:
-    """Peak HBM bytes/s for a JAX device, or None when unknown."""
+    """Peak HBM bytes/s for a JAX device; same contract as
+    :func:`peak_flops_per_chip`."""
+    return _device_peak(PEAK_HBM_BYTES_PER_CHIP, device)
+
+
+def _device_peak(table: dict[str, float], device) -> float | None:
     d = device if device is not None else jax.devices()[0]
     kind = str(getattr(d, "device_kind", ""))
-    return _longest_prefix(PEAK_HBM_BYTES_PER_CHIP, kind)
-
-
-def _longest_prefix(table: dict[str, float], kind: str) -> float | None:
     best: tuple[int, float] | None = None
     for prefix, value in table.items():
         if kind.startswith(prefix) and (best is None or len(prefix) > best[0]):
             best = (len(prefix), value)
-    return best[1] if best is not None else None
+    if best is not None:
+        return best[1]
+    if getattr(d, "platform", None) == "tpu":
+        raise ValueError(
+            f"no published peak for TPU device_kind {kind!r}; add it to the "
+            "tables in train/metrics.py with its source"
+        )
+    return None
 
 
 def utilization(
@@ -87,8 +95,8 @@ def utilization(
 ) -> float | None:
     """``round(numerator / denominator, ndigits)`` with None propagation.
 
-    The MFU/MBU ratio for bench emitters: either side is None when the
-    device peak is unknown (CPU/GPU test backends) or the measurement is
+    The MFU/MBU ratio for bench emitters: either side is None off a TPU
+    (the CPU test backend has no peak) or when the measurement is
     unavailable, and the honest JSON output is ``null`` — never the NaN
     that a ``x or float('nan')`` fallback would smuggle into json.dumps
     as an unparseable bare token.
@@ -277,6 +285,11 @@ class ThroughputLogger:
     def step(self, step: int, loss) -> None:
         if step % self.log_every:
             return
+        # Wait for the step BEFORE reading the clock: dispatch is
+        # asynchronous, and a window closed at enqueue time charges this
+        # step's device time to the next window (on the chip the window
+        # after the first sync read twice the true rate).
+        loss = float(loss)
         now = time.perf_counter()
         dsteps = step - self._last_step
         dt = now - self._t0
@@ -285,7 +298,7 @@ class ThroughputLogger:
         )
         record = {
             "step": step,
-            "loss": float(loss),
+            "loss": loss,
             "examples_per_sec": examples_per_sec,
         }
         if self.flops_per_step and self.peak_flops and dsteps and dt > 0:

@@ -81,6 +81,9 @@ def validate_shards(paths: Sequence[str | Path], spec: RecordSpec) -> None:
 
 
 def _build_library() -> None:
+    """``make`` the loader: a no-op when the library is newer than its
+    source, a rebuild when it is not — so a stale or foreign artefact
+    left in the tree is never what gets loaded."""
     # Bounded: a wedged compiler must fail the build, not hang training.
     proc = subprocess.run(
         ["make", "-C", str(LOADER_DIR)], capture_output=True, text=True,
@@ -94,8 +97,7 @@ def _load_library() -> ctypes.CDLL:
     global _lib
     if _lib is not None:
         return _lib
-    if not LOADER_SO.exists():
-        _build_library()
+    _build_library()
     lib = ctypes.CDLL(str(LOADER_SO))
     lib.dlcfn_loader_open.restype = ctypes.c_void_p
     lib.dlcfn_loader_open.argtypes = [

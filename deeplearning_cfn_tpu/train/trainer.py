@@ -48,6 +48,7 @@ from deeplearning_cfn_tpu.parallel.overlap import (
     plan_buckets,
 )
 from deeplearning_cfn_tpu.parallel.sharding import (
+    bytes_by_device,
     infer_param_sharding,
     replicated,
 )
@@ -58,7 +59,6 @@ from deeplearning_cfn_tpu.train.metrics import (
 )
 from deeplearning_cfn_tpu.obs.tracing import span
 from deeplearning_cfn_tpu.utils.logging import get_logger
-from deeplearning_cfn_tpu.utils.compat import set_mesh
 
 log = get_logger("dlcfn.trainer")
 
@@ -342,6 +342,9 @@ class Trainer:
         # covering data/loader/init setup that precedes fit).
         self.first_step_seconds: float | None = None
         self.first_step_at: float | None = None
+        # Set by fit(): bytes of the first batch's shards per device id,
+        # as placed for the step — the input reached every chip.
+        self.batch_bytes_by_device: dict[int, int] | None = None
 
     # --- loss -----------------------------------------------------------
     def _normalize_input(self, x: jax.Array) -> jax.Array:
@@ -694,7 +697,7 @@ class Trainer:
     def train_step(self, state: TrainState, x: jax.Array, y: jax.Array):
         # Mesh context makes bare-PartitionSpec sharding hints inside model
         # code (e.g. llama._maybe_shard) resolvable during tracing.
-        with set_mesh(self.mesh):
+        with jax.set_mesh(self.mesh):
             return self.step_fn(state, x, y)
 
     # --- evaluation -------------------------------------------------------
@@ -839,7 +842,7 @@ class Trainer:
                     # device_put_batch skips leaves the prefetcher already
                     # placed with an equivalent sharding.
                     x, y = device_put_batch(batch, self.batch_sharding)
-                    with set_mesh(self.mesh):
+                    with jax.set_mesh(self.mesh):
                         metrics = eval_fn(state, x, y)
                     per_batch.append((len(batch.x), metrics))
         finally:
@@ -1047,11 +1050,12 @@ class Trainer:
                         x = device_put_tree(batch.x, self.batch_sharding)
                         y = device_put_tree(batch.y, self.batch_sharding)
                     with prof.phase("dispatch"):
-                        with set_mesh(self.mesh):
+                        with jax.set_mesh(self.mesh):
                             state, metrics = step_fn(state, x, y)
                 gstep += 1
                 pending.append(metrics["loss"])
                 if i == 0:
+                    self.batch_bytes_by_device = bytes_by_device((x, y))
                     # Time-to-first-step (includes compile) — one half of the
                     # driver's template-to-first-step wallclock metric; the
                     # block is one-time and doubles as compile completion.
@@ -1154,8 +1158,10 @@ class Trainer:
                         # stacked sharding — this is an identity check.
                         xs = device_put_tree(stack.x, stacked_sharding)
                         ys = device_put_tree(stack.y, stacked_sharding)
+                    if not first_done:
+                        self.batch_bytes_by_device = bytes_by_device((xs, ys))
                     with prof.phase("dispatch"):
-                        with set_mesh(self.mesh):
+                        with jax.set_mesh(self.mesh):
                             state, kloss = kfn(state, xs, ys)
                     # The stack was built host-side by stack_batches and
                     # placed by this loop/prefetcher, so it is ours to
@@ -1203,7 +1209,7 @@ class Trainer:
                         x = device_put_tree(batch.x, self.batch_sharding)
                         y = device_put_tree(batch.y, self.batch_sharding)
                     with prof.phase("dispatch"):
-                        with set_mesh(self.mesh):
+                        with jax.set_mesh(self.mesh):
                             state, metrics = step_fn(state, x, y)
                 gstep += 1
                 scalar_pending.append(metrics["loss"])
@@ -1224,12 +1230,17 @@ class Trainer:
         """AOT-compile the train step and report cost analysis.  NOTE:
         ``flops_per_step`` is PER-DEVICE for an SPMD-partitioned module
         (each device executes the partitioned program over its batch
-        shard) — pair it with the per-chip peak for MFU.  The compile
-        populates the jit dispatch cache, so it is not paid twice —
-        PROVIDED later dispatches also run under ``set_mesh(self.mesh)``
-        (train_step/fit do): the ambient mesh is part of the jit cache
-        key, so a bare ``step_fn(state, x, y)`` call after this misses
-        the entry and recompiles (scripts/compile_audit.py catches it).
+        shard) — pair it with the per-chip peak for MFU.  The compile is
+        shared with the first dispatch, so it is not paid twice —
+        PROVIDED the program is keyed the way fit() keys it: under
+        ``jax.set_mesh(self.mesh)`` (train_step/fit do; a bare
+        ``step_fn(state, x, y)`` call after this misses the entry and
+        recompiles — scripts/compile_audit.py catches it), and with the
+        batch described by ``self.batch_sharding``.  ``x``/``y`` are
+        therefore read for shape and dtype only: a sample that sits on
+        the default device, lowered as it is, keys a second program and
+        the whole step compiles twice (seen on the chip, CHANGES.md
+        PR 21).
 
         When the model supplies ``analytic_flops_fn``, ``flops_per_step``
         is the analytic estimate (divided down to per-device scope) and
@@ -1246,14 +1257,19 @@ class Trainer:
         # Same mesh context as train_step: without it, in-model sharding
         # hints are dropped and this would measure (and compile) a different
         # program than the one that runs.
-        with set_mesh(self.mesh):
-            lowered = self.step_fn.lower(state, x, y)
+
+        def as_fed(tree):
+            return jax.tree_util.tree_map(
+                lambda a: jax.ShapeDtypeStruct(
+                    a.shape, a.dtype, sharding=self.batch_sharding
+                ),
+                tree,
+            )
+
+        with jax.set_mesh(self.mesh):
+            lowered = self.step_fn.lower(state, as_fed(x), as_fed(y))
             compiled = lowered.compile()
         cost = compiled.cost_analysis() or {}
-        if isinstance(cost, (list, tuple)):
-            # jax 0.4.x returns one dict per computation; modern jax
-            # returns the main computation's dict directly.
-            cost = cost[0] if cost else {}
         out = {
             "compile_seconds": time.perf_counter() - t0,
             "cost_flops_per_step": cost.get("flops"),

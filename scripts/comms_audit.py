@@ -38,7 +38,7 @@ sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 # The audit's question is partitioner-layer, not numerics: CPU answers
 # it, but only with a real mesh to partition over.
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
-os.environ.setdefault("DLCFN_COMPILE_CACHE", "off")
+os.environ.setdefault("JAX_ENABLE_COMPILATION_CACHE", "false")
 if "--xla_force_host_platform_device_count" not in os.environ.get("XLA_FLAGS", ""):
     os.environ["XLA_FLAGS"] = (
         os.environ.get("XLA_FLAGS", "")

@@ -25,8 +25,8 @@ sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 
 # The audit's question is dispatch-layer, not numerics: CPU answers it.
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
-# Honest compile counts need a cold persistent cache.
-os.environ.setdefault("DLCFN_COMPILE_CACHE", "off")
+# Honest compile counts need the persistent cache out of the way.
+os.environ.setdefault("JAX_ENABLE_COMPILATION_CACHE", "false")
 
 
 def main(argv: list[str] | None = None) -> int:

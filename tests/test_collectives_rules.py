@@ -231,11 +231,10 @@ def test_dlc502_quiet_on_unsharded_device_put():
 
 def test_dlc503_fires_on_bare_dispatch_after_set_mesh_dispatch():
     src = """\
-        from deeplearning_cfn_tpu.utils import compat
 
         def bench(trainer, state, x, mesh):
             step = trainer.step_fn
-            with compat.set_mesh(mesh):
+            with jax.set_mesh(mesh):
                 state = step(state, x)
             metrics = step(state, x)
             return metrics
@@ -245,11 +244,10 @@ def test_dlc503_fires_on_bare_dispatch_after_set_mesh_dispatch():
 
 def test_dlc503_quiet_when_every_dispatch_shares_the_mesh():
     src = """\
-        from deeplearning_cfn_tpu.utils import compat
 
         def bench(trainer, state, x, mesh):
             step = trainer.step_fn
-            with compat.set_mesh(mesh):
+            with jax.set_mesh(mesh):
                 state = step(state, x)
                 metrics = step(state, x)
             return metrics

@@ -41,7 +41,7 @@ def test_recipe_arms_order_on_heldout():
     ]
 
     def run(extra):
-        env = dict(os.environ, JAX_PLATFORMS="cpu", DLCFN_COMPILE_CACHE="off")
+        env = dict(os.environ, JAX_PLATFORMS="cpu", JAX_ENABLE_COMPILATION_CACHE="false")
         proc = subprocess.run(
             [sys.executable, "-m", "deeplearning_cfn_tpu.examples.cifar10_train"]
             + common + extra,

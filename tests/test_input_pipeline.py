@@ -126,6 +126,37 @@ def test_prefetcher_raises_at_exact_position(workers):
     pf.close()  # must not hang after an error
 
 
+@pytest.mark.parametrize("workers", [1, 4])
+def test_prefetcher_transfer_failure_reaches_the_consumer(workers):
+    """A device_put that fails on a producer thread (here: a batch of 2
+    the 8-way mesh cannot divide; on a chip, device memory exhausted)
+    must raise in the consumer — a producer dying alone leaves fit()
+    waiting forever for a batch that never comes."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    sharding = NamedSharding(Mesh(np.array(jax.devices()[:8]), ("dp",)), P("dp"))
+    pf = DevicePrefetcher(
+        _identifiable_batches(10), sharding, size=2, workers=workers
+    )
+    caught: list[BaseException] = []
+
+    def consume():
+        try:
+            for _ in pf:
+                pass
+        except ValueError as e:
+            caught.append(e)
+
+    t = threading.Thread(target=consume, daemon=True)
+    t.start()
+    t.join(timeout=30)
+    try:
+        assert not t.is_alive(), "consumer still waiting on a dead producer"
+        assert len(caught) == 1 and "divisible by 8" in str(caught[0])
+    finally:
+        pf.close()
+
+
 def test_prefetcher_workers_close_without_draining():
     # Abandoning a long stream mid-iteration must stop all workers.
     pf = DevicePrefetcher(

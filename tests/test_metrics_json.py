@@ -130,3 +130,22 @@ def test_jsonl_sink_writes_nan_loss_as_null(tmp_path):
     assert record["loss"] is None
     assert record["examples_per_sec"] == 512.0
     assert math.isfinite(record["ts"])
+
+
+def test_throughput_window_closes_when_the_step_has_finished():
+    """Dispatch is asynchronous: the loss handed to the logger is a device
+    scalar that is not ready yet.  The window must close after waiting for
+    it — closed at enqueue time, this step's device time lands in the next
+    window and the rate reads too high."""
+    import time
+
+    from deeplearning_cfn_tpu.train.metrics import ThroughputLogger
+
+    class PendingLoss:
+        def __float__(self):
+            time.sleep(0.05)  # the device finishing the step
+            return 1.0
+
+    logger = ThroughputLogger(global_batch_size=10, log_every=1)
+    logger.step(1, PendingLoss())
+    assert logger.history[0]["examples_per_sec"] <= 10 / 0.05

@@ -37,8 +37,8 @@ def _run_two_processes(model: str, steps: int = 8) -> list[dict]:
             DEEPLEARNING_COORDINATOR=f"127.0.0.1:{port}",
             DLCFN_SMOKE_STEPS=str(steps),
             DLCFN_SMOKE_MODEL=model,
-            # Test isolation: never write the developer's real cache dir.
-            DLCFN_COMPILE_CACHE="off",
+            # Test isolation: no persistent compile cache in the checkout.
+            JAX_ENABLE_COMPILATION_CACHE="false",
         )
         procs.append(
             subprocess.Popen(

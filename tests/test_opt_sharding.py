@@ -20,7 +20,6 @@ from jax.sharding import PartitionSpec as P
 from deeplearning_cfn_tpu.models import llama
 from deeplearning_cfn_tpu.parallel.mesh import MeshSpec, build_mesh
 from deeplearning_cfn_tpu.train.trainer import TrainerConfig
-from deeplearning_cfn_tpu.utils.compat import set_mesh
 
 
 def _assert_moments_match_params(state) -> int:
@@ -101,7 +100,7 @@ def test_transposed_moments_would_add_resharding_collectives():
         tok = np.zeros((4, 16), dtype=np.int32)
         x = jax.device_put(jnp.asarray(tok), trainer.batch_sharding)
         state = trainer.init(jax.random.key(0), x)
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             hlo = trainer.step_fn.lower(state, x, x).compile().as_text()
         return sum(
             len(re.findall(k, hlo))

@@ -1,10 +1,13 @@
-"""Flash attention (Pallas kernel, interpret mode on CPU) vs XLA attention.
+"""Flash attention (Pallas kernel, interpret mode asked for by name) vs XLA
+attention.
 
 Covers: causal/non-causal, GQA, non-divisible sequence lengths (padding +
-masking), and gradients through the custom VJP.
+masking), and gradients through the custom VJP.  The compiled kernel's
+numerics are checked on the chip by chip_smoke.py.
 """
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -12,7 +15,11 @@ import numpy as np
 import pytest
 
 from deeplearning_cfn_tpu.ops.attention import dot_product_attention
-from deeplearning_cfn_tpu.ops.pallas_attention import flash_attention
+from deeplearning_cfn_tpu.ops import pallas_attention
+
+# The kernel compiles through Mosaic unless told otherwise; on the CPU mesh
+# every call here asks for the interpreter.
+flash_attention = functools.partial(pallas_attention.flash_attention, interpret=True)
 
 
 def _qkv(b=2, s=64, hq=4, hkv=2, d=16, seed=0, dtype=jnp.float32):
@@ -116,6 +123,14 @@ def test_llama_attention_dispatch_crossover():
     assert attention_kind(cfg, None, FLASH_CROSSOVER_SEQ, backend="cpu") == "xla"
     off = dataclasses.replace(cfg, use_flash_attention=False)
     assert attention_kind(off, None, FLASH_CROSSOVER_SEQ, backend="tpu") == "xla"
+
+
+def test_compiled_kernel_does_not_fall_back_off_tpu():
+    """No backend guessing: without interpret=True the Mosaic kernel is
+    what runs, and on a CPU that is an error, not a silent interpreter."""
+    q, k, v = _qkv(s=16)
+    with pytest.raises(ValueError, match="interpret mode"):
+        pallas_attention.flash_attention(q, k, v)
 
 
 def test_bad_gqa_ratio_raises():

@@ -1,26 +1,31 @@
-"""Pallas fused dense: bit-parity with the XLA reference, gradients,
-the int8-weights variant, tree quantization, profitability dispatch, and
+"""Pallas fused dense: parity with the XLA reference, gradients, the
+int8-weights variant, tree quantization, profitability dispatch, and
 the flag-gated model wiring (FusedDense / BERT MLP / ResNet head).
 
-The parity contract is BIT-IDENTITY (np.array_equal, not allclose)
-against the JITTED reference: both programs accumulate in f32 on the
-same operand order, so any divergence means the kernel's math drifted
-from the fallback path a model takes with its flag off.  Comparisons
-must be against ``jax.jit(fused_dense_reference)`` — the eager gelu
-differs from its jitted self by ~5e-7, which is XLA fusion, not us.
+The kernel runs in the Pallas interpreter here, asked for by name
+(``interpret=True``, or ``pltpu.force_tpu_interpret_mode()`` around a
+model that calls the compiled kernel); chip_smoke.py checks the compiled
+kernel on the MXU.  The parity contract is a few ulp of the dtype, not
+bit-identity: kernel and reference contract the same operands in one
+``dot_general`` each, but the order a backend sums in is its own.
+Comparisons are against ``jax.jit(fused_dense_reference)`` — the eager
+gelu differs from its jitted self by ~5e-7, which is XLA fusion, not us.
 """
+
+import functools
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from jax.experimental.pallas import tpu as pltpu
+
+from deeplearning_cfn_tpu.ops import pallas_fused
 from deeplearning_cfn_tpu.ops.pallas_fused import (
     _quant_reference,
-    fused_dense,
     fused_dense_bytes,
     fused_dense_profitable,
-    fused_dense_quantized,
     fused_dense_reference,
 )
 from deeplearning_cfn_tpu.ops.quant import (
@@ -30,6 +35,23 @@ from deeplearning_cfn_tpu.ops.quant import (
     quantize_weight,
     tree_nbytes,
 )
+
+
+fused_dense = functools.partial(pallas_fused.fused_dense, interpret=True)
+fused_dense_quantized = functools.partial(
+    pallas_fused.fused_dense_quantized, interpret=True
+)
+
+
+def assert_within_ulps(got, want, ulps=4):
+    """|got - want| <= ulps * eps(dtype) * max(1, |want|), elementwise."""
+    got, want = np.asarray(got), np.asarray(want)
+    assert got.dtype == want.dtype
+    eps = float(jnp.finfo(got.dtype).eps)
+    g, w = got.astype(np.float64), want.astype(np.float64)
+    bound = ulps * eps * np.maximum(1.0, np.abs(w))
+    worst = float(np.max(np.abs(g - w) / bound)) if g.size else 0.0
+    assert worst <= 1.0, f"off by {worst * ulps:.2f} ulp (allowed {ulps})"
 
 
 def _operands(m, k, n, dtype, seed=0):
@@ -53,7 +75,7 @@ def _operands(m, k, n, dtype, seed=0):
         (16, 256, 128),   # two K lanes, one reduction chunk
     ],
 )
-def test_forward_bit_identical_to_jitted_reference(m, k, n, activation):
+def test_forward_matches_jitted_reference(m, k, n, activation):
     for dtype in (jnp.float32, jnp.bfloat16):
         x, w, b = _operands(m, k, n, dtype)
         got = jax.jit(
@@ -63,17 +85,15 @@ def test_forward_bit_identical_to_jitted_reference(m, k, n, activation):
             lambda x, w, b: fused_dense_reference(x, w, b, activation=activation)
         )(x, w, b)
         assert got.dtype == want.dtype == dtype
-        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+        assert_within_ulps(got, want)
 
 
 def test_forward_close_at_thread_partitioned_shapes():
     """At shapes big enough for XLA's CPU backend to partition the dot
     across its intra-op thread pool (partitioning depends on the virtual
     device count, so this shifts under --xla_force_host_platform_device_count),
-    the REFERENCE's own f32 summation order changes and bit-identity
-    with it is no longer defined.  The kernel must still agree to f32
-    accumulation tolerance.  On real TPUs both run the MXU reduction
-    order and the bit contract is checked by the small-shape cases."""
+    the REFERENCE's own f32 summation order changes.  The kernel must
+    still agree to f32 accumulation tolerance."""
     x, w, b = _operands(64, 256, 384, jnp.float32)
     got = jax.jit(lambda x, w, b: fused_dense(x, w, b, activation="gelu"))(x, w, b)
     want = jax.jit(
@@ -82,6 +102,17 @@ def test_forward_close_at_thread_partitioned_shapes():
     np.testing.assert_allclose(
         np.asarray(got), np.asarray(want), rtol=1e-5, atol=1e-5
     )
+
+
+def test_compiled_kernel_does_not_fall_back_off_tpu():
+    """No backend guessing: without interpret=True the Mosaic kernel is
+    what runs, and on a CPU that is an error, not a silent interpreter."""
+    x, w, b = _operands(8, 16, 4, jnp.float32)
+    with pytest.raises(ValueError, match="interpret mode"):
+        pallas_fused.fused_dense(x, w, b)
+    wq, scale = quantize_weight(w)
+    with pytest.raises(ValueError, match="interpret mode"):
+        pallas_fused.fused_dense_quantized(x, wq, scale, b)
 
 
 def test_input_validation():
@@ -117,14 +148,14 @@ def test_grads_match_reference(activation):
 
 
 @pytest.mark.parametrize("activation", [None, "gelu"])
-def test_quantized_bit_identical_to_reference(activation):
+def test_quantized_matches_reference(activation):
     x, w, b = _operands(24, 96, 48, jnp.float32, seed=2)
     wq, scale = quantize_weight(w)
     got = fused_dense_quantized(x, wq, scale, b, activation=activation)
     want = jax.jit(
         lambda x, wq, s, b: _quant_reference(x, wq, s, b, activation, x.dtype)
     )(x, wq, scale, b)
-    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    assert_within_ulps(got, want)
 
 
 def test_quantized_rejects_float_weights():
@@ -219,11 +250,20 @@ def test_profitability_returns_bool_and_bytes_formula():
 # --- model wiring -------------------------------------------------------------
 
 
+@pytest.fixture
+def tpu_interpret():
+    """The models call the compiled kernel; JAX's own switch runs every
+    Mosaic pallas_call traced inside it through the TPU interpreter."""
+    with pltpu.force_tpu_interpret_mode():
+        yield
+
+
+@pytest.mark.usefixtures("tpu_interpret")
 def test_fused_dense_module_matches_nn_dense():
     """FusedDense is checkpoint-compatible with nn.Dense: identical
-    param tree (names, shapes, dtypes, init values) and identical output
-    at f32 — a model can flip its use_pallas_* flag on an existing
-    checkpoint and restore in either direction."""
+    param tree (names, shapes, dtypes, init values) and the same output
+    at f32 to a few ulp — a model can flip its use_pallas_* flag on an
+    existing checkpoint and restore in either direction."""
     import flax.linen as nn
 
     from deeplearning_cfn_tpu.models.fused_layers import FusedDense
@@ -241,10 +281,11 @@ def test_fused_dense_module_matches_nn_dense():
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
     out_ref = jax.jit(ref.apply)(v_ref, x)
     out_fused = jax.jit(fused.apply)(v_ref, x)  # reference params, fused math
-    np.testing.assert_array_equal(np.asarray(out_ref), np.asarray(out_fused))
+    assert_within_ulps(out_fused, out_ref)
 
 
-def test_bert_pallas_mlp_flag_is_a_noop_numerically():
+@pytest.mark.usefixtures("tpu_interpret")
+def test_bert_pallas_mlp_flag_is_a_numerical_noop():
     import dataclasses
 
     from deeplearning_cfn_tpu.models.bert import BertConfig, BertEncoder
@@ -260,10 +301,12 @@ def test_bert_pallas_mlp_flag_is_a_noop_numerically():
     )
     out_off = jax.jit(off.apply)(v, tok)
     out_on = jax.jit(on.apply)(v, tok)
-    np.testing.assert_array_equal(np.asarray(out_off), np.asarray(out_on))
+    # bf16 activations through two layers: a few ulp of bf16.
+    assert_within_ulps(out_on, out_off, ulps=8)
 
 
-def test_resnet_pallas_head_flag_is_a_noop_numerically():
+@pytest.mark.usefixtures("tpu_interpret")
+def test_resnet_pallas_head_flag_is_a_numerical_noop():
     from deeplearning_cfn_tpu.models.resnet import ResNet
 
     rng = np.random.default_rng(7)
@@ -277,4 +320,4 @@ def test_resnet_pallas_head_flag_is_a_noop_numerically():
     )
     out_off = jax.jit(lambda v, x: off.apply(v, x, train=False))(v, x)
     out_on = jax.jit(lambda v, x: on.apply(v, x, train=False))(v, x)
-    np.testing.assert_array_equal(np.asarray(out_off), np.asarray(out_on))
+    assert_within_ulps(out_on, out_off)

@@ -21,17 +21,7 @@ from deeplearning_cfn_tpu.parallel.pipeline import (
     stack_stages,
 )
 from deeplearning_cfn_tpu.train.trainer import TrainerConfig
-from deeplearning_cfn_tpu.utils.compat import set_mesh
 
-
-
-# Partial-manual shard_map (axis_names= with other axes left to GSPMD) is
-# what the pipeline schedule compiles to; jax 0.4.x's SPMD partitioner
-# rejects the resulting PartitionId instruction.  Modern jax runs these.
-partial_manual = pytest.mark.skipif(
-    not hasattr(jax, "shard_map"),
-    reason="partial-manual shard_map unsupported by jax 0.4.x SPMD partitioner",
-)
 
 def _toy(L=8, D=16, seed=0):
     rng = np.random.default_rng(seed)
@@ -48,7 +38,6 @@ def _seq_forward(W, x):
     return out
 
 
-@partial_manual
 def test_pipeline_matches_sequential_forward_and_grad():
     mesh = build_mesh(MeshSpec(dp=2, pp=4), jax.devices()[:8])
     W, x = _toy()
@@ -65,7 +54,7 @@ def test_pipeline_matches_sequential_forward_and_grad():
         out, _ = pipeline_apply(stage_fn, Ws, x, mesh, n_microbatches=4)
         return out
 
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         ref = jax.jit(_seq_forward)(W, x)
         got = jax.jit(pipe)(Ws, x)
         np.testing.assert_allclose(np.asarray(ref), np.asarray(got), atol=1e-5)
@@ -79,7 +68,6 @@ def test_pipeline_matches_sequential_forward_and_grad():
         )
 
 
-@partial_manual
 def test_pipeline_aux_masked_over_bubbles():
     """Aux from warm-up/drain ticks (garbage activations) must not leak in:
     a stage_fn with aux == sum over the activation would differ if bubble
@@ -95,7 +83,7 @@ def test_pipeline_aux_masked_over_bubbles():
         out, _ = jax.lax.scan(body, act, lw)
         return out, jnp.sum(out.astype(jnp.float32))
 
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         out, aux = jax.jit(
             lambda Ws, x: pipeline_apply(stage_fn, Ws, x, mesh, n_microbatches=4)
         )(Ws, x)
@@ -119,7 +107,6 @@ def test_microbatch_and_stacking_validation():
         stack_stages(W, 3)  # 8 layers % 3 != 0
 
 
-@partial_manual
 def test_llama_pp_matches_single_device():
     """Tiny Llama, pp=2 x dp=2 x tp=2 pipeline vs the sequential stack —
     same weights (stage stacking is a reshape), same logits."""
@@ -145,7 +132,7 @@ def test_llama_pp_matches_single_device():
         np.random.default_rng(0).integers(0, 64, size=(4, 16)), jnp.int32
     )
     logits_seq = llama.forward(cfg_seq, params_seq, tokens)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         logits_pp = jax.jit(
             lambda p, t: llama.forward(cfg_pp, p, t, mesh)
         )(params_pp, tokens)
@@ -154,7 +141,6 @@ def test_llama_pp_matches_single_device():
     )
 
 
-@partial_manual
 def test_llama_pp_trainer_learns():
     cfg = llama.LlamaConfig.tiny(vocab_size=32, seq_len=8)
     cfg = dataclasses.replace(cfg, pp_stages=2, pp_microbatches=2)
@@ -200,7 +186,6 @@ def test_llama_pp_config_validation():
         llama.LlamaConfig.tiny_moe(n_experts=1)  # default top_k=2 > 1
 
 
-@partial_manual
 def test_llama_pp_moe_aux_scale_matches_sequential():
     """Regression: the MoE load-balancing aux must not scale with
     pp_microbatches (it is a per-invocation mean; the pipeline averages)."""
@@ -217,7 +202,7 @@ def test_llama_pp_moe_aux_scale_matches_sequential():
         np.random.default_rng(0).integers(0, 64, size=(8, 16)), jnp.int32
     )
     _, aux_seq = llama.forward_with_aux(cfg_seq, params_seq, tokens)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         _, aux_pp = jax.jit(
             lambda p, t: llama.forward_with_aux(cfg_pp, p, t, mesh)
         )(params_pp, tokens)
@@ -237,5 +222,5 @@ def test_stage_count_must_match_mesh_pp():
         return act, jnp.zeros((), jnp.float32)
 
     with pytest.raises(PipelineError, match="stages"):
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             pipeline_apply(stage_fn, Ws, x, mesh, n_microbatches=4)

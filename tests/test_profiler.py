@@ -230,15 +230,12 @@ class _FakeCompiled:
         return self._cost
 
 
-def test_program_cost_normalizes_shapes():
+def test_program_cost_reads_the_cost_model_or_reports_none():
     cost = {"flops": 100.0, "bytes accessed": 50.0}
     assert program_cost(_FakeCompiled(cost)) == {
         "flops": 100.0,
         "bytes_accessed": 50.0,
     }
-    # jax 0.4.x list-of-dicts form.
-    assert program_cost(_FakeCompiled([cost]))["flops"] == 100.0
-    assert program_cost(_FakeCompiled([]))["flops"] is None
     assert program_cost(_FakeCompiled(None))["flops"] is None
     assert program_cost(_FakeCompiled(RuntimeError("no cost model")))[
         "flops"

@@ -233,12 +233,18 @@ def test_baseline_reports_stale_entries(tmp_path):
 
 def test_committed_baseline_carries_only_the_comms_sentinel_debt():
     """The ratchet's floor: every STATIC namespace carries zero
-    suppressed findings.  The one accepted debt is the comms-audit
-    sentinel's DLC511 entries — the tiny audit model's known batch
+    suppressed findings.  The accepted debt is the comms-audit
+    sentinel's: the DLC511 entries — the tiny audit model's known batch
     gathers on the fsdp train path, ratcheted deliberately (see
-    docs/STATIC_ANALYSIS.md, "reading a comms report")."""
+    docs/STATIC_ANALYSIS.md, "reading a comms report") — and one DLC512:
+    on HLO the installed compiler lowers for the CPU, the bucketed dp
+    program no longer beats its monolithic baseline's overlap_score
+    (parallel/overlap.py's fate is decided on chips, ROADMAP S8)."""
     entries = runner.load_baseline(runner.DEFAULT_BASELINE)
-    assert {rule for rule, _, _ in entries} == {"DLC511"}
+    assert {rule for rule, _, _ in entries} == {"DLC511", "DLC512"}
+    assert [m for rule, _, m in entries if rule == "DLC512"] == [
+        m for _, _, m in entries if "train_step_dp_overlap path" in m
+    ]
     assert {path for _, path, _ in entries} == {
         "deeplearning_cfn_tpu/train/trainer.py"
     }

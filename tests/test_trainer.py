@@ -15,7 +15,6 @@ from deeplearning_cfn_tpu.models.lenet import LeNet
 from deeplearning_cfn_tpu.parallel.mesh import MeshSpec, build_mesh
 from deeplearning_cfn_tpu.parallel.sharding import infer_param_sharding
 from deeplearning_cfn_tpu.train.data import SyntheticDataset
-from deeplearning_cfn_tpu.utils.compat import set_mesh
 from deeplearning_cfn_tpu.train.trainer import Trainer, TrainerConfig
 
 
@@ -184,7 +183,7 @@ def test_evaluate_aggregates_weighted_metrics():
     )
     ds = SyntheticDataset(shape=(8, 8, 1), num_classes=4, batch_size=16)
     # 60 steps: enough for LeNet to clear the chance bar by a wide margin
-    # under jax 0.4.x numerics (20 steps lands within noise of 0.25).
+    # (20 steps lands within noise of 0.25).
     batches = list(ds.batches(60))
     state = trainer.init(jax.random.key(0), jnp.asarray(batches[0].x))
     state, _ = trainer.fit(state, iter(batches), steps=60)
@@ -386,26 +385,67 @@ def test_cost_analysis_source_for_dense_models():
     assert stats["flops_per_step"] == stats["cost_flops_per_step"]
 
 
-def test_enable_compile_cache_config_and_off_switch(tmp_path, monkeypatch):
-    """The persistent-cache helper must honor the off switch and set the
-    jax config when enabled (template-to-first-step depends on it)."""
-    from deeplearning_cfn_tpu.examples.common import enable_compile_cache
+def test_compile_stats_shares_its_compile_with_fit():
+    """The examples hand compile_stats a host/default-device sample.  It
+    must lower the step as fit() will dispatch it (batch described by the
+    trainer's batch sharding): lowered from the sample as it sits, the
+    step compiled a second time at the first dispatch."""
+    from deeplearning_cfn_tpu.analysis.compile_audit import CompileWatcher
+    from deeplearning_cfn_tpu.models.lenet import LeNet
 
-    prior_dir = jax.config.jax_compilation_cache_dir
-    prior_min = jax.config.jax_persistent_cache_min_compile_time_secs
-    try:
-        monkeypatch.setenv("DLCFN_COMPILE_CACHE", "off")
-        assert enable_compile_cache() is None
+    mesh = build_mesh(MeshSpec.data_parallel(2), jax.devices()[:2])
+    tr = Trainer(LeNet(num_classes=4), mesh, TrainerConfig())
+    ds = SyntheticDataset(shape=(8, 8, 1), num_classes=4, batch_size=8)
+    b = next(iter(ds.batches(1)))
+    state = tr.init(jax.random.key(0), jnp.asarray(b.x))
+    with CompileWatcher() as w:
+        tr.compile_stats(state, jnp.asarray(b.x), b.y)
+        tr.fit(state, ds.batches(2), steps=2)
+    assert w.compiles.get("step_fn") == 1
 
-        monkeypatch.setenv("DLCFN_COMPILE_CACHE", str(tmp_path / "cc"))
-        got = enable_compile_cache()
-        assert got == str(tmp_path / "cc")
-        assert jax.config.jax_compilation_cache_dir == got
-    finally:
-        # jax.config survives monkeypatch: restore so later tests in this
-        # process don't write a cache rooted in this test's tmp_path.
-        jax.config.update("jax_compilation_cache_dir", prior_dir)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", prior_min)
+
+def test_compile_cache_is_placed_from_outside_or_fixed_in_the_checkout(
+    tmp_path, monkeypatch
+):
+    """JAX_COMPILATION_CACHE_DIR set: the helper touches no cache option
+    (JAX reads the variable itself).  Unset: the same absolute directory
+    in two fresh processes, inside the checkout — the path is part of
+    where JAX looks, so one that moves between runs never hits."""
+    import os
+    import subprocess
+    import sys
+    from pathlib import Path
+
+    from deeplearning_cfn_tpu.utils.compile_cache import enable_compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "placed"))
+    assert enable_compile_cache() == str(tmp_path / "placed")
+    assert jax.config.jax_compilation_cache_dir == before
+
+    repo = Path(__file__).resolve().parents[1]
+    script = (
+        "from deeplearning_cfn_tpu.utils.compile_cache import enable_compile_cache\n"
+        "import jax\n"
+        "print(enable_compile_cache())\n"
+        "print(jax.config.jax_compilation_cache_dir)\n"
+    )
+    env = {k: v for k, v in os.environ.items() if k != "JAX_COMPILATION_CACHE_DIR"}
+    env["PYTHONPATH"] = str(repo)
+    procs = [
+        subprocess.Popen(
+            [sys.executable, "-c", script], env=env, cwd=tmp_path,
+            stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True,
+        )
+        for _ in range(2)
+    ]
+    outs = [proc.communicate(timeout=120) for proc in procs]
+    for proc, (_, err) in zip(procs, outs):
+        assert proc.returncode == 0, err[-2000:]
+    returned, configured = outs[0][0].split()
+    assert outs[1][0].split() == [returned, configured]
+    assert returned == configured
+    assert Path(returned).is_absolute() and repo in Path(returned).parents
 
 
 def test_resnet_group_norm_variant_trains():
@@ -486,7 +526,7 @@ def test_multi_step_fn_matches_sequential_steps():
 
     t2 = make()
     s2 = t2.init(jax.random.key(0), jnp.asarray(batches[0].x))
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         s2, losses = t2.multi_step_fn(4)(s2, jnp.asarray(xs), jnp.asarray(ys))
     np.testing.assert_allclose(
         np.asarray(losses), np.asarray(losses_seq), rtol=1e-5
@@ -542,24 +582,30 @@ def test_fold_batchnorm_matches_eval_forward():
 def test_peak_tables_prefix_match():
     """Device-kind dispatch for the MFU and MBU denominators: known kinds
     resolve, longest prefix wins ('TPU v5 lite' is an 819 GB/s v5e, not a
-    2765 GB/s v5p), unknown kinds return None so test backends report no
-    utilization instead of a wrong one."""
+    2765 GB/s v5p), a non-TPU device returns None so the CPU mesh reports
+    no utilization instead of a wrong one — and a TPU whose kind is not in
+    the table raises, so a chip run never logs a silent ``mfu: null``."""
     from deeplearning_cfn_tpu.train.metrics import (
         peak_flops_per_chip,
         peak_hbm_bytes_per_chip,
     )
 
     class FakeDev:
-        def __init__(self, kind):
+        def __init__(self, kind, platform="tpu"):
             self.device_kind = kind
+            self.platform = platform
 
     assert peak_flops_per_chip(FakeDev("TPU v5 lite")) == 197e12
     assert peak_flops_per_chip(FakeDev("TPU v5")) == 459e12
     assert peak_hbm_bytes_per_chip(FakeDev("TPU v5 lite")) == 819e9
     assert peak_hbm_bytes_per_chip(FakeDev("TPU v5")) == 2765e9
     assert peak_hbm_bytes_per_chip(FakeDev("TPU v4")) == 1228e9
-    assert peak_flops_per_chip(FakeDev("cpu")) is None
-    assert peak_hbm_bytes_per_chip(FakeDev("cpu")) is None
+    assert peak_flops_per_chip(FakeDev("cpu", platform="cpu")) is None
+    assert peak_hbm_bytes_per_chip(FakeDev("cpu", platform="cpu")) is None
+    with pytest.raises(ValueError, match="TPU v9 mega"):
+        peak_flops_per_chip(FakeDev("TPU v9 mega"))
+    with pytest.raises(ValueError, match="TPU v9 mega"):
+        peak_hbm_bytes_per_chip(FakeDev("TPU v9 mega"))
 
 
 class TestGradAccumulation:

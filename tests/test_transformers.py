@@ -17,7 +17,7 @@ from deeplearning_cfn_tpu.train.trainer import Trainer, TrainerConfig
 RING_VS_DENSE_SCRIPT = """
 import os
 os.environ["JAX_PLATFORMS"] = "cpu"
-os.environ["DLCFN_COMPILE_CACHE"] = "off"
+os.environ["JAX_ENABLE_COMPILATION_CACHE"] = "false"
 if "xla_force_host_platform_device_count" not in os.environ.get("XLA_FLAGS", ""):
     os.environ["XLA_FLAGS"] = (
         os.environ.get("XLA_FLAGS", "") + " --xla_force_host_platform_device_count=8"
