@@ -1,0 +1,8 @@
+"""Trainer: device time of the forward pass (under the scope `loss`, neither transposed nor rematerialised), per executed program of the traced window
+on device 0, in milliseconds (`scope_reduce.py` has the rule)."""
+
+from benchmarks import scope_reduce
+
+
+def read(run: dict) -> float | None:
+    return scope_reduce.class_ms_per_step(run, "forward")

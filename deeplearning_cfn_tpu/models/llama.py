@@ -402,53 +402,66 @@ def _block(
     loss, 0 for dense models."""
     B, S, d = x.shape
     hd = cfg.head_dim
-    h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-    if cfg.fused_qkv:
-        nq, nkv = cfg.n_heads * hd, cfg.n_kv_heads * hd
-        qkv = h @ lp["wqkv"]
-        q = qkv[..., :nq].reshape(B, S, cfg.n_heads, hd)
-        k = qkv[..., nq : nq + nkv].reshape(B, S, cfg.n_kv_heads, hd)
-        v = qkv[..., nq + nkv :].reshape(B, S, cfg.n_kv_heads, hd)
-    else:
-        q = (h @ lp["wq"]).reshape(B, S, cfg.n_heads, hd)
-        k = (h @ lp["wk"]).reshape(B, S, cfg.n_kv_heads, hd)
-        v = (h @ lp["wv"]).reshape(B, S, cfg.n_kv_heads, hd)
-    q = rotary_embedding(q, positions, cfg.rope_theta)
-    k = rotary_embedding(k, positions, cfg.rope_theta)
-    kind = attention_kind(cfg, mesh, S)
-    if kind == "ring":
-        from deeplearning_cfn_tpu.parallel.ring_attention import ring_attention
+    # The named scopes are metadata for a profile's op names
+    # (attn_norm / attn{qkv,rope,core,out} / mlp_norm / mlp): the
+    # computation is the same with and without them.
+    with jax.named_scope("attn_norm"):
+        h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
+    with jax.named_scope("attn"):
+        with jax.named_scope("qkv"):
+            if cfg.fused_qkv:
+                nq, nkv = cfg.n_heads * hd, cfg.n_kv_heads * hd
+                qkv = h @ lp["wqkv"]
+                q = qkv[..., :nq].reshape(B, S, cfg.n_heads, hd)
+                k = qkv[..., nq : nq + nkv].reshape(B, S, cfg.n_kv_heads, hd)
+                v = qkv[..., nq + nkv :].reshape(B, S, cfg.n_kv_heads, hd)
+            else:
+                q = (h @ lp["wq"]).reshape(B, S, cfg.n_heads, hd)
+                k = (h @ lp["wk"]).reshape(B, S, cfg.n_kv_heads, hd)
+                v = (h @ lp["wv"]).reshape(B, S, cfg.n_kv_heads, hd)
+        with jax.named_scope("rope"):
+            q = rotary_embedding(q, positions, cfg.rope_theta)
+            k = rotary_embedding(k, positions, cfg.rope_theta)
+        kind = attention_kind(cfg, mesh, S)
+        with jax.named_scope("core"):
+            if kind == "ring":
+                from deeplearning_cfn_tpu.parallel.ring_attention import ring_attention
 
-        attn = ring_attention(q, k, v, mesh, causal=True)
-    elif kind == "flash":
-        from deeplearning_cfn_tpu.ops.pallas_attention import flash_attention
+                attn = ring_attention(q, k, v, mesh, causal=True)
+            elif kind == "flash":
+                from deeplearning_cfn_tpu.ops.pallas_attention import flash_attention
 
-        # attention_kind only answers "flash" on a tpu backend, so this is
-        # always the compiled Mosaic kernel.
-        attn = flash_attention(q, k, v, causal=True, mesh=mesh, interpret=False)
-    else:
-        # "xla" covers use_flash_attention off-TPU (the Pallas kernel needs
-        # Mosaic) AND below-crossover sequences where XLA's fused attention
-        # measures faster than the Pallas kernel (docs/BENCH_NOTES.md):
-        # use_flash means "fastest memory-safe attention", not "always
-        # Pallas".
-        attn = dot_product_attention(q, k, v, causal=True)
-    x = x + attn.reshape(B, S, cfg.n_heads * hd) @ lp["wo"]
-    h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
-    if cfg.moe is not None:
-        from deeplearning_cfn_tpu.ops.moe import moe_mlp
+                # attention_kind only answers "flash" on a tpu backend, so
+                # this is always the compiled Mosaic kernel.
+                attn = flash_attention(
+                    q, k, v, causal=True, mesh=mesh, interpret=False
+                )
+            else:
+                # "xla" covers use_flash_attention off-TPU (the Pallas kernel
+                # needs Mosaic) AND below-crossover sequences where XLA's
+                # fused attention measures faster than the Pallas kernel
+                # (docs/BENCH_NOTES.md): use_flash means "fastest memory-safe
+                # attention", not "always Pallas".
+                attn = dot_product_attention(q, k, v, causal=True)
+        with jax.named_scope("out"):
+            x = x + attn.reshape(B, S, cfg.n_heads * hd) @ lp["wo"]
+    with jax.named_scope("mlp_norm"):
+        h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
+    with jax.named_scope("mlp"):
+        if cfg.moe is not None:
+            from deeplearning_cfn_tpu.ops.moe import moe_mlp
 
-        y, aux = moe_mlp(cfg.moe, lp["moe"], h)
-        return x + y, aux
-    if cfg.fused_qkv:
-        gu = h @ lp["w_gate_up"]
-        gate = jax.nn.silu(
-            gu[..., : cfg.mlp_dim].astype(jnp.float32)
-        ).astype(h.dtype)
-        x = x + (gate * gu[..., cfg.mlp_dim :]) @ lp["w_down"]
-    else:
-        gate = jax.nn.silu((h @ lp["w_gate"]).astype(jnp.float32)).astype(h.dtype)
-        x = x + (gate * (h @ lp["w_up"])) @ lp["w_down"]
+            y, aux = moe_mlp(cfg.moe, lp["moe"], h)
+            return x + y, aux
+        if cfg.fused_qkv:
+            gu = h @ lp["w_gate_up"]
+            gate = jax.nn.silu(
+                gu[..., : cfg.mlp_dim].astype(jnp.float32)
+            ).astype(h.dtype)
+            x = x + (gate * gu[..., cfg.mlp_dim :]) @ lp["w_down"]
+        else:
+            gate = jax.nn.silu((h @ lp["w_gate"]).astype(jnp.float32)).astype(h.dtype)
+            x = x + (gate * (h @ lp["w_up"])) @ lp["w_down"]
     return x, jnp.zeros((), jnp.float32)
 
 
@@ -470,9 +483,10 @@ def forward_with_aux(
     # token sharding (batch over dp/fsdp, seq over sp) plus an unsharded
     # emb axis — exactly the activation layout, so the constraint below is
     # a no-op instead of a blocking reshard.
-    table = _maybe_shard(params["embed"].astype(cfg.dtype), P("tp", None))
-    x = table[tokens]
-    x = _maybe_shard(x, P(("dp", "fsdp"), "sp", None))
+    with jax.named_scope("embed"):
+        table = _maybe_shard(params["embed"].astype(cfg.dtype), P("tp", None))
+        x = table[tokens]
+        x = _maybe_shard(x, P(("dp", "fsdp"), "sp", None))
     positions = jnp.arange(S, dtype=jnp.int32)
 
     block = partial(_block, cfg, mesh)
@@ -517,11 +531,13 @@ def forward_with_aux(
         (x, aux_sum), _ = jax.lax.scan(
             scan_body, (x, jnp.zeros((), jnp.float32)), layer_tree
         )
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    if cfg.tied_embeddings:
-        logits = x @ params["embed"].astype(cfg.dtype).T
-    else:
-        logits = x @ params["output"]
+    with jax.named_scope("final_norm"):
+        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+    with jax.named_scope("head"):
+        if cfg.tied_embeddings:
+            logits = x @ params["embed"].astype(cfg.dtype).T
+        else:
+            logits = x @ params["output"]
     # Logits stay in the COMPUTE dtype: materializing the [B, S, V] f32
     # copy here cost ~1 GB of HBM writes per pass at the 435M bench shape
     # and dominated the out-of-scan step time (round-3 trace,
@@ -589,11 +605,12 @@ def causal_lm_loss(
     # into them) instead of materialized as an f32 copy plus a full-width
     # f32 log_softmax — at V=32k that materialization was ~28% of the
     # 435M training step (docs/BENCH_NOTES.md round-3 trace).
-    lse = jax.scipy.special.logsumexp(logits.astype(jnp.float32), axis=-1)
-    gold = jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
-    nll = lse - gold.astype(jnp.float32)
-    mask = jnp.ones_like(nll).at[:, -1].set(0.0)
-    loss = jnp.sum(nll * mask) / jnp.sum(mask)
+    with jax.named_scope("xent"):
+        lse = jax.scipy.special.logsumexp(logits.astype(jnp.float32), axis=-1)
+        gold = jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
+        nll = lse - gold.astype(jnp.float32)
+        mask = jnp.ones_like(nll).at[:, -1].set(0.0)
+        loss = jnp.sum(nll * mask) / jnp.sum(mask)
     metrics = {"perplexity": jnp.exp(loss)}
     if cfg.moe is not None:
         metrics["moe_aux_loss"] = aux

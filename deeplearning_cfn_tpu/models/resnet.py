@@ -22,6 +22,7 @@ from functools import partial
 from typing import Any, Callable, Sequence
 
 import flax.linen as nn
+import jax
 import jax.numpy as jnp
 
 from deeplearning_cfn_tpu.models.fused_layers import FusedDense
@@ -154,11 +155,15 @@ class ResNet(nn.Module):
                 # (docs/BENCH_NOTES.md).
                 dtype=self.dtype,
             )
-        x = x.astype(self.dtype)
-        x = conv(self.num_filters, (7, 7), (2, 2), padding=[(3, 3), (3, 3)], name="conv_init")(x)
-        x = norm(name="bn_init")(x)
-        x = nn.relu(x)
-        x = nn.max_pool(x, (3, 3), strides=(2, 2), padding="SAME")
+        # The blocks and the classifier carry their module's name
+        # (`stage<n>_block<m>`, `head`) into a profile's op names by
+        # themselves; `stem` and `head` name what no module does.
+        with jax.named_scope("stem"):
+            x = x.astype(self.dtype)
+            x = conv(self.num_filters, (7, 7), (2, 2), padding=[(3, 3), (3, 3)], name="conv_init")(x)
+            x = norm(name="bn_init")(x)
+            x = nn.relu(x)
+            x = nn.max_pool(x, (3, 3), strides=(2, 2), padding="SAME")
         features = {}
         for i, block_count in enumerate(self.stage_sizes):
             for j in range(block_count):
@@ -173,7 +178,8 @@ class ResNet(nn.Module):
             features[f"C{i + 2}"] = x
         if self.return_features:
             return features
-        x = jnp.mean(x, axis=(1, 2))
+        with jax.named_scope("head"):
+            x = jnp.mean(x, axis=(1, 2))
         if self.use_pallas_head:
             x = FusedDense(self.num_classes, dtype=jnp.float32, name="head")(x)
         else:

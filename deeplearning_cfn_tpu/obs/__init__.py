@@ -16,7 +16,14 @@ from deeplearning_cfn_tpu.obs.recorder import (
     get_recorder,
     read_journal,
 )
-from deeplearning_cfn_tpu.obs.tracing import span, span_aggregates, reset_aggregates
+from deeplearning_cfn_tpu.obs.tracing import (
+    counter,
+    counters,
+    recent_spans,
+    reset_aggregates,
+    span,
+    span_aggregates,
+)
 from deeplearning_cfn_tpu.obs.liveness import (
     LivenessConfig,
     LivenessTable,
@@ -61,6 +68,9 @@ __all__ = [
     "read_journal",
     "span",
     "span_aggregates",
+    "recent_spans",
+    "counter",
+    "counters",
     "reset_aggregates",
     "LivenessConfig",
     "LivenessTable",

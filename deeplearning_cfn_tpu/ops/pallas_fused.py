@@ -147,6 +147,7 @@ def _fused_forward(x, w, b, activation, block_m, block_n, interpret):
             dimension_semantics=("parallel", "parallel"),
         ),
         interpret=interpret,
+        name="fused_dense",
     )(xp, wp, bp)
     return out[:M, :N]
 
@@ -299,6 +300,7 @@ def fused_dense_quantized(
             dimension_semantics=("parallel", "parallel"),
         ),
         interpret=bool(interpret),
+        name="fused_dense_quantized",
     )(xp, wp, sp, bp)
     return out[:M, :N]
 

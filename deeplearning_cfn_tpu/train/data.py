@@ -23,6 +23,8 @@ from typing import Iterator
 import jax
 import numpy as np
 
+from deeplearning_cfn_tpu.obs.tracing import span
+
 
 def probe_data_source(candidates: list[str | Path], marker: str = "") -> Path | None:
     """Return the first candidate directory that exists (and contains
@@ -526,7 +528,11 @@ class DevicePrefetcher:
                     self._stats.add_transfer(nbytes_of((item.x, item.y)))
                 t_put = time.perf_counter()
                 try:
-                    item = Batch(*device_put_batch(item, self._sharding))
+                    # A seam of ours on the producer's own line of a profile:
+                    # the runtime's re-tiling of the batch for the transfer
+                    # (Transpose, XlaLinearize) happens under it.
+                    with span("prefetch.h2d", journal=False):
+                        item = Batch(*device_put_batch(item, self._sharding))
                 except BaseException as e:  # dlcfn: noqa[DLC004] not swallowed: re-raised in the consumer's __iter__
                     # A transfer that fails (a batch the mesh cannot
                     # divide, device memory exhausted) ends the stream at

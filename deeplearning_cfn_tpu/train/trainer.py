@@ -57,7 +57,7 @@ from deeplearning_cfn_tpu.train.metrics import (
     ThroughputLogger,
     peak_flops_per_chip,
 )
-from deeplearning_cfn_tpu.obs.tracing import span
+from deeplearning_cfn_tpu.obs.tracing import freeze_counters, span
 from deeplearning_cfn_tpu.utils.logging import get_logger
 
 log = get_logger("dlcfn.trainer")
@@ -257,8 +257,6 @@ def _accumulated_grads(loss_fn, state, x, y, accum: int):
             leaf.reshape((n // accum, accum) + leaf.shape[1:]), 0, 1
         )
 
-    xs = jax.tree_util.tree_map(to_micro, x)
-    ys = jax.tree_util.tree_map(to_micro, y)
     grad_fn = jax.value_and_grad(loss_fn, has_aux=True)
 
     def body(carry, xy):
@@ -270,13 +268,18 @@ def _accumulated_grads(loss_fn, state, x, y, accum: int):
         grads_acc = jax.tree_util.tree_map(jnp.add, grads_acc, grads)
         return (grads_acc, model_state), (loss, aux)
 
-    zeros = jax.tree_util.tree_map(jnp.zeros_like, state.params)
-    (grads_sum, new_model_state), (losses, auxes) = jax.lax.scan(
-        body, (zeros, state.model_state), (xs, ys)
-    )
-    grads = jax.tree_util.tree_map(lambda g: g / accum, grads_sum)
-    aux = jax.tree_util.tree_map(lambda v: jnp.mean(v, axis=0), auxes)
-    return jnp.mean(losses), aux, new_model_state, grads
+    # The step's `loss` scope (see _raw_step_fn): the microbatch views and
+    # the running sum ride with the pass they serve.
+    with jax.named_scope("loss"):
+        xs = jax.tree_util.tree_map(to_micro, x)
+        ys = jax.tree_util.tree_map(to_micro, y)
+        zeros = jax.tree_util.tree_map(jnp.zeros_like, state.params)
+        (grads_sum, new_model_state), (losses, auxes) = jax.lax.scan(
+            body, (zeros, state.model_state), (xs, ys)
+        )
+        grads = jax.tree_util.tree_map(lambda g: g / accum, grads_sum)
+        aux = jax.tree_util.tree_map(lambda v: jnp.mean(v, axis=0), auxes)
+        return jnp.mean(losses), aux, new_model_state, grads
 
 
 def softmax_xent(logits: jax.Array, labels: jax.Array, smoothing: float = 0.0) -> jax.Array:
@@ -286,6 +289,102 @@ def softmax_xent(logits: jax.Array, labels: jax.Array, smoothing: float = 0.0) -
         onehot = onehot * (1.0 - smoothing) + smoothing / num_classes
     logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
     return -jnp.mean(jnp.sum(onehot.astype(jnp.float32) * logp, axis=-1))
+
+
+class _FitSeams:
+    """The seams of the training loop, once, for ``fit`` and ``_fit_multi``.
+
+    A seam is an ``obs.tracing.span`` that is not journalled: it folds into
+    the per-name aggregate, is kept among the recent spans and annotates a
+    ``jax.profiler`` capture, so that what the host was doing lies beside
+    the device's operations under a name.  Everything the training thread
+    does between two dispatches lies under one of them:
+
+    - ``fit.step``: one iteration, ``step_num`` the global step it
+      dispatches; the one seam that is journalled, as ``train_step``, the
+      name the journal's readers know.  It times the host's side of the
+      iteration, not the step on the device.
+    - ``fit.data_wait``: ``next()`` on the batch source.
+    - ``fit.h2d``: ``device_put_tree`` (a prefetched batch moves nothing).
+    - ``fit.dispatch``: the call of the jitted step, which returns when the
+      program is enqueued.
+    - ``fit.sync``: the host waits for the device: the first step's
+      ``block_until_ready`` and the ``device_get`` of the pending losses
+      every ``log_every`` steps.
+    - ``fit.log``: the per-step hooks: the logger, the checkpointer's
+      ``should_save``, ``stop_fn``.
+    - ``fit.checkpoint``: a save; journalled as ``checkpoint``.
+
+    A ``StepProfiler``, when one was passed, gets the same seconds folded
+    into its phases (``PHASES``), so its API and its readers stay as they
+    were; its ``compute`` is the host's wait at a sync point, a lower
+    bound on device time.
+    """
+
+    PHASES = {
+        "fit.data_wait": "data_wait",
+        "fit.h2d": "h2d",
+        "fit.dispatch": "dispatch",
+        "fit.sync": "compute",
+    }
+    _END = object()
+
+    def __init__(self, trainer: "Trainer", profiler: Any):
+        from deeplearning_cfn_tpu.obs.profiler import NULL_PROFILER
+
+        self.trainer = trainer
+        self.prof = profiler if profiler is not None else NULL_PROFILER
+        self.t_fit = time.perf_counter()
+        self.first_done = False
+
+    def __call__(self, name: str, samples: int = 1):
+        """The context for one seam; ``samples`` spreads a sync's seconds
+        over the steps it drained, for the profiler."""
+        phase = self.PHASES.get(name)
+        if phase is None or not self.prof.enabled:
+            return span(name, journal=False)
+        return self._profiled(name, phase, samples)
+
+    @contextlib.contextmanager
+    def _profiled(self, name: str, phase: str, samples: int):
+        t0 = time.perf_counter()
+        try:
+            with span(name, journal=False):
+                yield
+        finally:
+            self.prof.fold(phase, time.perf_counter() - t0, samples=samples)
+
+    def step(self, gstep: int):
+        return span("fit.step", journal="train_step", step_num=gstep)
+
+    def checkpoint(self, gstep: int):
+        return span("fit.checkpoint", journal="checkpoint", step=gstep)
+
+    def source(self, batches):
+        """``batches`` with every ``next()`` under ``fit.data_wait``."""
+        it = iter(batches)
+        while True:
+            with self("fit.data_wait"):
+                item = next(it, self._END)
+            if item is self._END:
+                return
+            yield item
+
+    def first_step(self, value: Any) -> None:
+        """Once per fit, after the first dispatch: wait for it and stamp
+        time-to-first-step (compile included), one half of the driver's
+        template-to-first-step metric; the wait doubles as the compile's
+        completion.  The compile counters are frozen under ``first_step.``
+        here, apart from what the process compiles afterwards."""
+        if self.first_done:
+            return
+        self.first_done = True
+        with self("fit.sync"):
+            jax.block_until_ready(value)
+        now = time.perf_counter()
+        self.trainer.first_step_seconds = now - self.t_fit
+        self.trainer.first_step_at = now
+        freeze_counters("compile.", "first_step.")
 
 
 class Trainer:
@@ -380,8 +479,9 @@ class Trainer:
         else:
             logits = self.model.apply(variables, x, **kwargs)
             new_model_state = model_state
-        loss = softmax_xent(logits, y, self.config.label_smoothing)
-        acc = jnp.mean((jnp.argmax(logits, -1) == y).astype(jnp.float32))
+        with jax.named_scope("xent"):
+            loss = softmax_xent(logits, y, self.config.label_smoothing)
+            acc = jnp.mean((jnp.argmax(logits, -1) == y).astype(jnp.float32))
         return loss, {"accuracy": acc}, new_model_state
 
     def _loss(
@@ -398,6 +498,7 @@ class Trainer:
         return loss, (aux, new_model_state)
 
     # --- init -----------------------------------------------------------
+    @span("trainer.init")
     def init(self, rng: jax.Array, sample_x: jax.Array) -> TrainState:
         """Initialize params/opt-state and place them on the mesh."""
         init_kwargs = {"train": False} if self.config.has_train_arg else {}
@@ -565,7 +666,8 @@ class Trainer:
             compress=compress,
         )
         residuals = state.opt_state.residual if compress else ()
-        return fn(state.params, x, y, residuals)
+        with jax.named_scope("loss"):
+            return fn(state.params, x, y, residuals)
 
     def _raw_step_fn(self):
         """The unjitted single-step body, shared by the jitted step and
@@ -584,7 +686,9 @@ class Trainer:
         overlap = self.config.comms_overlap
         compress = self.config.overlap_compress
 
-        def step_fn(state: TrainState, x: jax.Array, y: jax.Array):
+        # Named `train_step`: a profile's `XLA Modules` line and the
+        # compile cache's entry read `jit_train_step`.
+        def train_step(state: TrainState, x: jax.Array, y: jax.Array):
             ctx = (
                 jax.default_matmul_precision(precision)
                 if precision
@@ -598,35 +702,45 @@ class Trainer:
                 # float inputs exactly like the default objective
                 # (_normalize_input is a no-op for float x, so the
                 # default objective's own call cannot double-normalize).
-                if augment is not None:
-                    x = augment(state.step, x)
-                x = self._normalize_input(x)
+                #
+                # Three named scopes split the step's device time in a
+                # profile (metadata only; the computation is the same):
+                # `input`, `loss` (JAX itself marks the backward half
+                # `transpose(jvp(...))` and a rematerialised forward
+                # `rematted_computation`/`checkpoint` inside it) and
+                # `optimizer`.  Every gradient path carries all three.
+                with jax.named_scope("input"):
+                    if augment is not None:
+                        x = augment(state.step, x)
+                    x = self._normalize_input(x)
                 if overlap:
                     loss, aux, grads, new_residuals = self._overlap_grads(
                         loss_fn, state, x, y, accum
                     )
                     new_model_state = state.model_state
                 elif accum == 1:
-                    (loss, (aux, new_model_state)), grads = jax.value_and_grad(
-                        loss_fn, has_aux=True
-                    )(state.params, state.model_state, x, y)
+                    with jax.named_scope("loss"):
+                        (loss, (aux, new_model_state)), grads = jax.value_and_grad(
+                            loss_fn, has_aux=True
+                        )(state.params, state.model_state, x, y)
                 else:
                     loss, aux, new_model_state, grads = _accumulated_grads(
                         loss_fn, state, x, y, accum
                     )
             metrics = {"loss": loss, **aux}
-            if overlap and compress:
-                updates, new_inner = self.tx.update(
-                    grads, state.opt_state.inner, state.params
-                )
-                new_opt = ErrorFeedbackState(
-                    residual=new_residuals, inner=new_inner
-                )
-            else:
-                updates, new_opt = self.tx.update(
-                    grads, state.opt_state, state.params
-                )
-            new_params = optax.apply_updates(state.params, updates)
+            with jax.named_scope("optimizer"):
+                if overlap and compress:
+                    updates, new_inner = self.tx.update(
+                        grads, state.opt_state.inner, state.params
+                    )
+                    new_opt = ErrorFeedbackState(
+                        residual=new_residuals, inner=new_inner
+                    )
+                else:
+                    updates, new_opt = self.tx.update(
+                        grads, state.opt_state, state.params
+                    )
+                new_params = optax.apply_updates(state.params, updates)
             new_state = TrainState(
                 step=state.step + 1,
                 params=new_params,
@@ -635,7 +749,7 @@ class Trainer:
             )
             return new_state, metrics
 
-        return step_fn
+        return train_step
 
     def _build_step(self):
         assert self.state_shardings is not None, "call init() before train_step"
@@ -937,13 +1051,22 @@ class Trainer:
         exit.  With a prefetcher, already-placed batches are simply
         re-put onto the new mesh by device_put_tree.
 
+        The loop's seams are spans of ``obs.tracing`` (``_FitSeams``:
+        ``fit.step`` around an iteration, ``fit.data_wait``, ``fit.h2d``,
+        ``fit.dispatch``, ``fit.sync``, ``fit.log``, ``fit.checkpoint``
+        inside it), which a ``jax.profiler`` capture shows beside the
+        device's operations.  All of them time the HOST: ``fit.step``
+        (journalled as ``train_step``) is the host's side of one
+        iteration, not the step's time on the device.
+
         ``profiler`` (an obs.profiler.StepProfiler, default None = off)
-        splits each step into data_wait / h2d / dispatch / compute /
-        host phases; device compute is only observed at the loop's
-        existing sync boundaries (amortized over the steps drained
-        there), so nothing about the dispatch pipeline changes when
-        profiling is on.  NOTE: the first step's interval includes
-        compile — read p50, not max, for steady-state.
+        gets the seams' seconds folded into its data_wait / h2d /
+        dispatch / compute / host phases; ``compute`` is the host's wait
+        at the loop's existing sync points (amortized over the steps
+        drained there), a lower bound on device time, so nothing about
+        the dispatch pipeline changes when profiling is on.  NOTE: the
+        first step's interval includes compile — read p50, not max, for
+        steady-state.
 
         ``steps_per_call`` > 1 routes through ``multi_step_fn(k)``: k
         host batches are stacked host-side, prefetched device-resident
@@ -967,7 +1090,6 @@ class Trainer:
         the snapshot happens at the step boundary where fit saves, which
         is a batch boundary of the stream.
         """
-        from deeplearning_cfn_tpu.obs.profiler import NULL_PROFILER
         from deeplearning_cfn_tpu.train.data import DevicePrefetcher
         from deeplearning_cfn_tpu.train.pipeline import PipelineStats
 
@@ -994,13 +1116,13 @@ class Trainer:
                 datastream=datastream,
             )
 
-        prof = profiler if profiler is not None else NULL_PROFILER
+        seams = _FitSeams(self, profiler)
+        prof = seams.prof
 
         losses: list[float] = []
         pending: list[jax.Array] = []  # device scalars awaiting readback
         step_fn = self.step_fn
         sync_every = max(1, int(self.config.log_every))
-        t_fit = time.perf_counter()
         # islice in every mode: fit consumes exactly `steps` items from the
         # caller's iterator (a break-based guard would pull one extra).
         batches = itertools.islice(batches, steps)
@@ -1015,10 +1137,9 @@ class Trainer:
                 stats=stats,
                 profiler=profiler,
             )
-        # data_wait = host blocked pulling the next batch (after the
-        # prefetcher, so a full buffer reads as ~zero wait).  On the
-        # disabled path wrap_source returns `batches` unchanged.
-        batches = prof.wrap_source(batches)
+        # fit.data_wait = host blocked pulling the next batch (after the
+        # prefetcher, so a full buffer reads as ~zero wait).
+        batches = seams.source(batches)
         # Global step tracked host-side (syncing state.step every iteration
         # would stall the dispatch pipeline); resume-aware so checkpoints
         # after a restore are labeled with the true training step.
@@ -1026,63 +1147,64 @@ class Trainer:
         prof.start()
         try:
             for i, batch in enumerate(batches):
-                if reshard is not None and reshard.pending():
-                    # Pause at the step boundary: settle the losses already
-                    # dispatched against the old mesh, then migrate.  The
-                    # batch just pulled is trained on the NEW mesh below —
-                    # the data stream continues unbroken.
-                    losses.extend(float(v) for v in jax.device_get(pending))
-                    pending.clear()
-                    state, action = reshard.execute(self, state, step=gstep)
-                    if action == "stop":
-                        break
-                    step_fn = self.step_fn
-                # Targets may be a pytree (e.g. detection {boxes, classes});
-                # every leaf leads with the batch axis, so one batch sharding
-                # applies uniformly.  device_put_tree skips leaves the
-                # prefetcher already placed with an equivalent sharding —
-                # prefetched batches transfer zero bytes here.
-                # The span clocks HOST time: transfer + async dispatch, not
-                # device execution (docs/OBSERVABILITY.md) — a sudden jump
-                # here means the dispatch queue filled and the host blocked.
-                with span("train_step"):
-                    with prof.phase("h2d"):
+                with seams.step(gstep + 1):
+                    if reshard is not None and reshard.pending():
+                        # Pause at the step boundary: settle the losses already
+                        # dispatched against the old mesh, then migrate.  The
+                        # batch just pulled is trained on the NEW mesh below —
+                        # the data stream continues unbroken.
+                        with seams("fit.sync", len(pending)):
+                            losses.extend(float(v) for v in jax.device_get(pending))
+                        pending.clear()
+                        state, action = reshard.execute(self, state, step=gstep)
+                        if action == "stop":
+                            break
+                        step_fn = self.step_fn
+                    # Targets may be a pytree (e.g. detection {boxes, classes});
+                    # every leaf leads with the batch axis, so one batch sharding
+                    # applies uniformly.  device_put_tree skips leaves the
+                    # prefetcher already placed with an equivalent sharding —
+                    # prefetched batches transfer zero bytes here.
+                    with seams("fit.h2d"):
                         x = device_put_tree(batch.x, self.batch_sharding)
                         y = device_put_tree(batch.y, self.batch_sharding)
-                    with prof.phase("dispatch"):
+                    # HOST time: the call returns when the program is enqueued,
+                    # not when the device has run it (docs/OBSERVABILITY.md) — a
+                    # sudden jump here means the dispatch queue filled and the
+                    # host blocked.
+                    with seams("fit.dispatch"):
                         with jax.set_mesh(self.mesh):
                             state, metrics = step_fn(state, x, y)
-                gstep += 1
-                pending.append(metrics["loss"])
-                if i == 0:
-                    self.batch_bytes_by_device = bytes_by_device((x, y))
-                    # Time-to-first-step (includes compile) — one half of the
-                    # driver's template-to-first-step wallclock metric; the
-                    # block is one-time and doubles as compile completion.
-                    with prof.sync_boundary():
-                        jax.block_until_ready(metrics["loss"])
-                    self.first_step_seconds = time.perf_counter() - t_fit
-                    self.first_step_at = time.perf_counter()
-                if logger:
-                    # The logger converts to float only at its own log_every
-                    # boundaries — passing the device scalar keeps non-log
-                    # steps sync-free.
-                    logger.step(gstep, metrics["loss"])
-                if checkpointer is not None and checkpointer.should_save(gstep):
-                    with span("checkpoint", step=gstep):
-                        self._save_checkpoint(checkpointer, gstep, state, datastream)
-                if gstep % sync_every == 0 or i == steps - 1:
-                    # The host blocks here anyway, so drain the pending device
-                    # scalars — O(log_every) live buffers instead of O(steps).
-                    # For the profiler this is the sync boundary where device
-                    # time surfaces: the blocked seconds are a lower bound on
-                    # compute, amortized over the steps drained.
-                    with prof.sync_boundary(len(pending)):
-                        losses.extend(float(v) for v in jax.device_get(pending))
-                    pending.clear()
-                    if stop_fn is not None and stop_fn(metrics):
-                        break
-                prof.step_done(step=gstep)
+                    gstep += 1
+                    pending.append(metrics["loss"])
+                    if i == 0:
+                        self.batch_bytes_by_device = bytes_by_device((x, y))
+                        seams.first_step(metrics["loss"])
+                    with seams("fit.log"):
+                        if logger:
+                            # The logger converts to float only at its own
+                            # log_every boundaries — passing the device scalar
+                            # keeps non-log steps sync-free.
+                            logger.step(gstep, metrics["loss"])
+                        save = checkpointer is not None and checkpointer.should_save(gstep)
+                    if save:
+                        with seams.checkpoint(gstep):
+                            self._save_checkpoint(checkpointer, gstep, state, datastream)
+                    if gstep % sync_every == 0 or i == steps - 1:
+                        # The host blocks here anyway, so drain the pending device
+                        # scalars — O(log_every) live buffers instead of O(steps).
+                        # This is where device time surfaces on the host: the
+                        # blocked seconds are a lower bound on compute, which the
+                        # profiler spreads over the steps drained.
+                        with seams("fit.sync", len(pending)):
+                            losses.extend(float(v) for v in jax.device_get(pending))
+                        pending.clear()
+                        if stop_fn is not None:
+                            with seams("fit.log"):
+                                stop = stop_fn(metrics)
+                            if stop:
+                                break
+                    prof.step_done(step=gstep)
         finally:
             # Exceptions mid-loop must not leak a live producer thread.
             if prefetcher is not None:
@@ -1114,7 +1236,6 @@ class Trainer:
         live instead of letting dead inputs pile up behind the dispatch
         queue.  Stop/checkpoint/log granularity is the k-step call.
         """
-        from deeplearning_cfn_tpu.obs.profiler import NULL_PROFILER
         from deeplearning_cfn_tpu.train.data import (
             DevicePrefetcher,
             donate_buffers,
@@ -1122,7 +1243,8 @@ class Trainer:
         )
         from deeplearning_cfn_tpu.train.pipeline import PipelineStats
 
-        prof = profiler if profiler is not None else NULL_PROFILER
+        seams = _FitSeams(self, profiler)
+        prof = seams.prof
         kfn = self.multi_step_fn(k)  # built ONCE; call-many below
         stacked_sharding = NamedSharding(
             self.mesh, P(None, *self.batch_sharding.spec)
@@ -1130,8 +1252,6 @@ class Trainer:
         losses: list[float] = []
         pending: list[jax.Array] = []  # device [k] loss vectors
         sync_every = max(1, -(-int(self.config.log_every) // k))  # in calls
-        t_fit = time.perf_counter()
-        first_done = False
         stopped = False
         batches = itertools.islice(batches, steps)
         calls = steps // k
@@ -1147,50 +1267,49 @@ class Trainer:
                 stats=stats,
                 profiler=profiler,
             )
-        stacked = prof.wrap_source(stacked)
+        stacked = seams.source(stacked)
         gstep = int(jax.device_get(state.step))
         prof.start()
         try:
             for i, stack in enumerate(stacked):
-                with span("train_step"):
-                    with prof.phase("h2d"):
+                with seams.step(gstep + k):
+                    with seams("fit.h2d"):
                         # Prefetched stacks are already resident with the
                         # stacked sharding — this is an identity check.
                         xs = device_put_tree(stack.x, stacked_sharding)
                         ys = device_put_tree(stack.y, stacked_sharding)
-                    if not first_done:
-                        self.batch_bytes_by_device = bytes_by_device((xs, ys))
-                    with prof.phase("dispatch"):
+                        if i == 0:
+                            self.batch_bytes_by_device = bytes_by_device((xs, ys))
+                    with seams("fit.dispatch"):
                         with jax.set_mesh(self.mesh):
                             state, kloss = kfn(state, xs, ys)
-                    # The stack was built host-side by stack_batches and
-                    # placed by this loop/prefetcher, so it is ours to
-                    # free.  XLA can't donate it (no same-shaped output to
-                    # alias into), hence the explicit delete — see
-                    # train/data.donate_buffers.
-                    donate_buffers((xs, ys))
-                gstep += k
-                pending.append(kloss)
-                if not first_done:
-                    first_done = True
-                    with prof.sync_boundary():
-                        jax.block_until_ready(kloss)
-                    self.first_step_seconds = time.perf_counter() - t_fit
-                    self.first_step_at = time.perf_counter()
-                if logger:
-                    logger.step(gstep, kloss[-1])
-                if checkpointer is not None and checkpointer.should_save(gstep):
-                    with span("checkpoint", step=gstep):
-                        self._save_checkpoint(checkpointer, gstep, state, datastream)
-                if (i + 1) % sync_every == 0 or i == calls - 1:
-                    with prof.sync_boundary(len(pending) * k):
-                        for vec in jax.device_get(pending):
-                            losses.extend(float(v) for v in vec)
-                    pending.clear()
-                    if stop_fn is not None and stop_fn({"loss": losses[-1]}):
-                        stopped = True
-                        break
-                prof.step_done(step=gstep, steps=k)
+                        # The stack was built host-side by stack_batches and
+                        # placed by this loop/prefetcher, so it is ours to
+                        # free.  XLA can't donate it (no same-shaped output to
+                        # alias into), hence the explicit delete — see
+                        # train/data.donate_buffers.
+                        donate_buffers((xs, ys))
+                    gstep += k
+                    pending.append(kloss)
+                    seams.first_step(kloss)
+                    with seams("fit.log"):
+                        if logger:
+                            logger.step(gstep, kloss[-1])
+                        save = checkpointer is not None and checkpointer.should_save(gstep)
+                    if save:
+                        with seams.checkpoint(gstep):
+                            self._save_checkpoint(checkpointer, gstep, state, datastream)
+                    if (i + 1) % sync_every == 0 or i == calls - 1:
+                        with seams("fit.sync", len(pending) * k):
+                            for vec in jax.device_get(pending):
+                                losses.extend(float(v) for v in vec)
+                        pending.clear()
+                        if stop_fn is not None:
+                            with seams("fit.log"):
+                                stopped = bool(stop_fn({"loss": losses[-1]}))
+                            if stopped:
+                                break
+                    prof.step_done(step=gstep, steps=k)
         finally:
             if prefetcher is not None:
                 prefetcher.close()
@@ -1203,23 +1322,26 @@ class Trainer:
         if not stopped and steps % k:
             step_fn = self.step_fn
             scalar_pending: list[jax.Array] = []
-            for batch in batches:
-                with span("train_step"):
-                    with prof.phase("h2d"):
+            for batch in seams.source(batches):
+                with seams.step(gstep + 1):
+                    with seams("fit.h2d"):
                         x = device_put_tree(batch.x, self.batch_sharding)
                         y = device_put_tree(batch.y, self.batch_sharding)
-                    with prof.phase("dispatch"):
+                    with seams("fit.dispatch"):
                         with jax.set_mesh(self.mesh):
                             state, metrics = step_fn(state, x, y)
-                gstep += 1
-                scalar_pending.append(metrics["loss"])
-                if logger:
-                    logger.step(gstep, metrics["loss"])
-                prof.step_done(step=gstep)
-            losses.extend(float(v) for v in jax.device_get(scalar_pending))
+                    gstep += 1
+                    scalar_pending.append(metrics["loss"])
+                    if logger:
+                        with seams("fit.log"):
+                            logger.step(gstep, metrics["loss"])
+                    prof.step_done(step=gstep)
+            with seams("fit.sync", max(1, len(scalar_pending))):
+                losses.extend(float(v) for v in jax.device_get(scalar_pending))
         return state, losses
 
     # --- compile diagnostics ---------------------------------------------
+    @span("trainer.compile_stats")
     def compile_stats(
         self,
         state: TrainState,

@@ -191,7 +191,7 @@ def test_real_trainer_compile_counts_are_consistent(real_audit):
     report, _ = real_audit
     watcher = report.watcher
     assert watcher["retrace_count"] == 0
-    assert watcher["compiles"].get("step_fn") == 1
+    assert watcher["compiles"].get("train_step") == 1
     assert watcher["compiles"].get("k_steps") == 1
     # The nameless jax.monitoring stream is the independent cross-check.
     assert watcher["backend_compiles"] == watcher["compile_count"]

@@ -401,7 +401,7 @@ def test_compile_stats_shares_its_compile_with_fit():
     with CompileWatcher() as w:
         tr.compile_stats(state, jnp.asarray(b.x), b.y)
         tr.fit(state, ds.batches(2), steps=2)
-    assert w.compiles.get("step_fn") == 1
+    assert w.compiles.get("train_step") == 1
 
 
 def test_compile_cache_is_placed_from_outside_or_fixed_in_the_checkout(
