@@ -78,7 +78,9 @@ SERVE_LOGIT_TOLERANCE = 0.1
 
 # (batch, seq, q heads, kv heads, head dim, dtype): the shapes the repo's
 # configurations produce (m435 8x8x128, a GQA 16/4 at d64, an 8B-like 32/8),
-# each at the flash crossover and above it, one ragged length, one f32.
+# each at the flash crossover and above it, two ragged lengths (600 pads to
+# 640, 2100 to 2176: whole 128-blocks of which the last is mostly padding,
+# where the compiled backward's padded rows would make a NaN), one f32.
 ATTENTION_CASES = (
     (1, 2048, 8, 8, 128, "bfloat16"),
     (1, 4096, 8, 8, 128, "bfloat16"),
@@ -87,6 +89,7 @@ ATTENTION_CASES = (
     (1, 2048, 32, 8, 128, "bfloat16"),
     (1, 4096, 32, 8, 128, "bfloat16"),
     (2, 600, 8, 8, 128, "bfloat16"),
+    (1, 2100, 32, 8, 128, "bfloat16"),
     (1, 2048, 8, 8, 128, "float32"),
 )
 # (M, K, N, activation, dtype): BERT-base MLP in and out, the ResNet head,

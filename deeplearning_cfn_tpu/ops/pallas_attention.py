@@ -15,10 +15,16 @@ transformer flagships (BERT, Llama-3).  Design is TPU-first:
 - Grouped-query attention is handled in the BlockSpec index maps (a kv head
   is fetched for ``group = Hq // Hkv`` query heads) — no materialized
   ``repeat`` anywhere, forward or backward.
-- Backward: ``custom_vjp`` whose backward pass is a blockwise ``lax.scan``
-  recomputation from the saved log-sum-exp — O(S) activation memory,
-  standard flash-attention-2 residual strategy.  It is plain XLA (fuses
-  fine on TPU); the forward hot path is the Pallas kernel.
+- Backward: ``custom_vjp`` with the flash-attention-2 residuals (q, k, v,
+  out and the log-sum-exp: O(S) activation memory) and two Pallas kernels
+  that recompute the probabilities blockwise from the log-sum-exp, so the
+  score-sized tensors s, p, dp, ds live in VMEM only: a dk/dv kernel, grid
+  ``(batch, kv_heads, kv_blocks, group * q_blocks)``, that folds the
+  ``group`` query heads of a kv head into its sequential axis and writes dk
+  and dv once per kv head, and a dq kernel, grid ``(batch, heads, q_blocks,
+  kv_blocks)``.  Pairs above the causal diagonal are skipped and fetch
+  nothing; pairs wholly inside it skip the mask.  Only ``delta = rowsum(out
+  * dout)`` and the layout changes around the kernels are XLA.
 - Mesh-aware: pass ``mesh=`` and the kernel runs under ``shard_map`` with
   batch sharded over (dp, fsdp) and heads over tp — attention is
   independent per (batch, head), so each shard computes locally with no
@@ -53,6 +59,17 @@ NEG_INF = -1e30
 # large defaults cost nothing for short sequences.
 DEFAULT_BLOCK_Q = 1024
 DEFAULT_BLOCK_K = 512
+# The backward kernels' (q block, kv block), from a sweep on v5e at the
+# decoder cell's shape (B 2, S 4096, 32/8 heads of 128, bf16;
+# scripts/chip_attention_backward_sweep.py, PR 25): 1024x1024 runs the dk/dv
+# kernel in 3.98 ms and the dq kernel in 3.47 ms a call, 512x512 in 4.34 and
+# 3.85, 256x256 in 8.95 and 6.74.  At S 2048 / D 64 (16/4 heads) 512x512 is
+# the best by a tenth (0.27 + 0.21 ms against 0.29 + 0.23).
+BWD_DKV_BLOCKS = (1024, 1024)
+BWD_DQ_BLOCKS = (1024, 1024)
+# Four float32 [1024, 1024] tiles (s, p, dp, ds) are 16 MB, the default
+# scoped VMEM limit by themselves; a v5e core has 128 MiB.
+_BWD_VMEM_LIMIT = 64 * 1024 * 1024
 
 # Below this sequence length XLA's fused attention wins on v5e (measured:
 # 3.74 ms XLA vs 4.69 ms flash at S=2048 with 512 blocks; flash pulls
@@ -257,73 +274,302 @@ def _flash_forward(
     return out, lse[:, :, :Sq, 0]  # [B, Hq, Sq]
 
 
-# --- memory-efficient backward (blockwise scan, plain XLA) ---------------
+# --- backward: two Pallas kernels (flash-attention-2) ---------------------
+#
+# Both recompute the probabilities of one (q block, kv block) pair from the
+# saved log-sum-exp, in VMEM: ``p = exp(q k^T * scale - lse)``,
+# ``ds = p * (dout v^T - delta)`` with ``delta = rowsum(out * dout)``.  The
+# dk/dv kernel holds a kv block and walks the q blocks of every query head
+# of its group (dk, dv are written once per kv head); the dq kernel holds a
+# q block and walks the kv blocks.  7 block matmuls where a fused kernel
+# needs 5, in exchange for no accumulation through HBM.  ``sm_scale`` on ds
+# is applied once, to the float32 accumulators, as they are written.
 
 
-def _blockwise_backward(res, g, *, causal: bool, sm_scale: float, block_k: int):
-    """Recompute p blockwise from the saved LSE and accumulate dq/dk/dv with
-    a scan over kv blocks — never materializes [Sq, Sk] and never expands
-    the kv heads: the GQA group lives as an explicit einsum axis."""
-    q, k, v, out, lse = res
-    B, Sq, Hq, D = q.shape
-    _, Sk, Hkv, _ = k.shape
+def _pair_mask(rows, cols, q_axis: int, q_start, k_start, *, causal, q_len, kv_len):
+    """Validity of a [rows, cols] tile of scores whose axis ``q_axis`` runs
+    over query positions and the other over key positions: the key is real
+    (not kv padding), the query is real (not q padding: a padded row's lse
+    is 0 and its scores are, so nothing overflows, but it attends nothing),
+    and the key is not after the query."""
+    q_pos = q_start + jax.lax.broadcasted_iota(jnp.int32, (rows, cols), q_axis)
+    k_pos = k_start + jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 1 - q_axis)
+    mask = jnp.logical_and(k_pos < kv_len, q_pos < q_len)
+    if causal:
+        mask = jnp.logical_and(mask, k_pos <= q_pos)
+    return mask
+
+
+def _run_pair(pair, q_start, k_start, *, causal, block_q, block_k, q_len, kv_len):
+    """Run ``pair(masked)`` for one (q block, kv block): not at all where the
+    pair lies wholly above the causal diagonal, and with the mask only where
+    a score of it is masked, because it holds padding or (causal) its last
+    key lies after its first query.  Pairs wholly inside the causal triangle
+    skip the iotas, compares and selects."""
+    masked = jnp.logical_or(k_start + block_k > kv_len, q_start + block_q > q_len)
+    live = True
+    if causal:
+        masked = jnp.logical_or(masked, k_start + block_k - 1 > q_start)
+        live = k_start <= q_start + block_q - 1
+    pl.when(jnp.logical_and(live, masked))(lambda: pair(True))
+    pl.when(jnp.logical_and(live, jnp.logical_not(masked)))(lambda: pair(False))
+
+
+def _dkv_kernel(
+    q_ref,  # [1, 1, Bq, D]
+    k_ref,  # [1, 1, Bk, D]
+    v_ref,  # [1, 1, Bk, D]
+    do_ref,  # [1, 1, Bq, D]
+    lse_ref,  # [1, 1, 1, Bq] f32: row statistics lie along the lanes
+    delta_ref,  # [1, 1, 1, Bq] f32
+    dk_ref,  # [1, 1, Bk, D]
+    dv_ref,  # [1, 1, Bk, D]
+    dk_acc,  # VMEM [Bk, D] f32
+    dv_acc,  # VMEM [Bk, D] f32
+    *,
+    causal: bool,
+    sm_scale: float,
+    block_q: int,
+    block_k: int,
+    q_len: int,
+    kv_len: int,
+    nq: int,
+):
+    """Works on the transposed tile s^T = k q^T [Bk, Bq]: the per-query
+    statistics then broadcast along sublanes from a compact [1, Bq] row,
+    and both accumulations (p^T dout, ds^T q) are plain [Bk, Bq] x [Bq, D]
+    matmuls with nothing to transpose."""
+    ki = pl.program_id(2)
+    t = pl.program_id(3)  # (query head of the group, q block), q block fastest
+    qi = t % nq
+    q_start = qi * block_q
+    k_start = ki * block_k
+
+    @pl.when(t == 0)
+    def _init():
+        dk_acc[:] = jnp.zeros_like(dk_acc)
+        dv_acc[:] = jnp.zeros_like(dv_acc)
+
+    def pair(masked: bool):
+        q, k, v, do = q_ref[0, 0], k_ref[0, 0], v_ref[0, 0], do_ref[0, 0]
+        nt = (((1,), (1,)), ((), ()))  # contract the head dim of both
+        nn = (((1,), (0,)), ((), ()))
+        st = jax.lax.dot_general(k, q, nt, preferred_element_type=jnp.float32)
+        pt = jnp.exp(st * sm_scale - lse_ref[0, 0])  # [Bk, Bq]
+        if masked:
+            # Select after the exponential: a masked score may be anything
+            # (exp may even overflow), the select never multiplies it.
+            mask = _pair_mask(
+                block_k, block_q, 1, q_start, k_start,
+                causal=causal, q_len=q_len, kv_len=kv_len,
+            )
+            pt = jnp.where(mask, pt, 0.0)
+        dv_acc[:] += jax.lax.dot_general(
+            pt.astype(do.dtype), do, nn, preferred_element_type=jnp.float32
+        )
+        dpt = jax.lax.dot_general(v, do, nt, preferred_element_type=jnp.float32)
+        dst = pt * (dpt - delta_ref[0, 0])
+        dk_acc[:] += jax.lax.dot_general(
+            dst.astype(q.dtype), q, nn, preferred_element_type=jnp.float32
+        )
+
+    _run_pair(
+        pair, q_start, k_start,
+        causal=causal, block_q=block_q, block_k=block_k, q_len=q_len, kv_len=kv_len,
+    )
+
+    @pl.when(t == pl.num_programs(3) - 1)
+    def _finish():
+        dk_ref[0, 0] = (dk_acc[:] * sm_scale).astype(dk_ref.dtype)
+        dv_ref[0, 0] = dv_acc[:].astype(dv_ref.dtype)
+
+
+def _dq_kernel(
+    q_ref,  # [1, 1, Bq, D]
+    k_ref,  # [1, 1, Bk, D]
+    v_ref,  # [1, 1, Bk, D]
+    do_ref,  # [1, 1, Bq, D]
+    lse_ref,  # [1, 1, Bq, 128] f32, lane-replicated as the forward writes it
+    delta_ref,  # [1, 1, Bq, 128] f32
+    dq_ref,  # [1, 1, Bq, D]
+    dq_acc,  # VMEM [Bq, D] f32
+    *,
+    causal: bool,
+    sm_scale: float,
+    block_q: int,
+    block_k: int,
+    q_len: int,
+    kv_len: int,
+):
+    qi = pl.program_id(2)
+    ki = pl.program_id(3)
+    q_start = qi * block_q
+    k_start = ki * block_k
+
+    @pl.when(ki == 0)
+    def _init():
+        dq_acc[:] = jnp.zeros_like(dq_acc)
+
+    def pair(masked: bool):
+        q, k, v, do = q_ref[0, 0], k_ref[0, 0], v_ref[0, 0], do_ref[0, 0]
+        nt = (((1,), (1,)), ((), ()))
+        nn = (((1,), (0,)), ((), ()))
+        s = jax.lax.dot_general(q, k, nt, preferred_element_type=jnp.float32)
+        p = jnp.exp(s * sm_scale - lse_ref[0, 0][:, :1])  # [Bq, Bk]
+        if masked:
+            mask = _pair_mask(
+                block_q, block_k, 0, q_start, k_start,
+                causal=causal, q_len=q_len, kv_len=kv_len,
+            )
+            p = jnp.where(mask, p, 0.0)
+        dp = jax.lax.dot_general(do, v, nt, preferred_element_type=jnp.float32)
+        ds = p * (dp - delta_ref[0, 0][:, :1])
+        dq_acc[:] += jax.lax.dot_general(
+            ds.astype(k.dtype), k, nn, preferred_element_type=jnp.float32
+        )
+
+    _run_pair(
+        pair, q_start, k_start,
+        causal=causal, block_q=block_q, block_k=block_k, q_len=q_len, kv_len=kv_len,
+    )
+
+    @pl.when(ki == pl.num_programs(3) - 1)
+    def _finish():
+        dq_ref[0, 0] = (dq_acc[:] * sm_scale).astype(dq_ref.dtype)
+
+
+def _heads_major(x: jax.Array, block: int) -> jax.Array:
+    """[B, S, H, D] -> [B, H, S', D], S padded with zeros to whole blocks."""
+    return jnp.swapaxes(_pad_seq(x, block), 1, 2)
+
+
+def _pad_rows(x: jax.Array, block: int) -> jax.Array:
+    """A per-row statistic [B, Hq, Sq] padded with zeros to whole q blocks:
+    a padded row then reads p = exp(0 - 0), masked to 0, never
+    exp(NEG_INF - NEG_INF)."""
+    return jnp.pad(x, ((0, 0), (0, 0), (0, (-x.shape[2]) % block)))
+
+
+def _backward_params() -> pltpu.CompilerParams:
+    # batch, head and the held block are independent; the last grid axis
+    # walks the other operand's blocks and carries the VMEM accumulators.
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
+        vmem_limit_bytes=_BWD_VMEM_LIMIT,
+    )
+
+
+def _backward_dkv(q, k, v, dout, lse, delta, *, causal, sm_scale, blocks, interpret):
+    """dk, dv [B, Sk, Hkv, D]: grid (B, Hkv, kv blocks, group * q blocks)."""
+    bq, bk = blocks
+    (B, Sq, Hq, D), (_, Sk, Hkv, _) = q.shape, k.shape
     group = Hq // Hkv
+    qt, dot = _heads_major(q, bq), _heads_major(dout, bq)
+    kt, vt = _heads_major(k, bk), _heads_major(v, bk)
+    nq, nk = qt.shape[2] // bq, kt.shape[2] // bk
 
-    # [B, Sq, Hkv, group, D] views; contractions below run in f32 on the MXU
-    # via preferred_element_type without materializing f32 copies.
-    qg = q.reshape(B, Sq, Hkv, group, D)
-    gg = g.reshape(B, Sq, Hkv, group, D)
-    # delta_i = sum_d out_i * dout_i  (FA2 trick: dp_ij - delta_i term)
-    delta = jnp.einsum(
-        "bqhgd,bqhgd->bqhg",
-        out.reshape(B, Sq, Hkv, group, D),
-        gg,
-        preferred_element_type=jnp.float32,
-    )
-    lse_g = lse.reshape(B, Hkv, group, Sq).transpose(0, 3, 1, 2)  # [B,Sq,Hkv,g]
-
-    kp = _pad_seq(k, block_k)
-    vp = _pad_seq(v, block_k)
-    nk = kp.shape[1] // block_k
-    kb = jnp.moveaxis(kp.reshape(B, nk, block_k, Hkv, D), 1, 0)
-    vb = jnp.moveaxis(vp.reshape(B, nk, block_k, Hkv, D), 1, 0)
-
-    q_pos = jnp.arange(Sq)
-    f32 = jnp.float32
-
-    def kv_block(dq_acc, blk):
-        k_blk, v_blk, j = blk  # [B, Bk, Hkv, D], kv-block index
-        k_pos = j * block_k + jnp.arange(block_k)
-        s = (
-            jnp.einsum("bqhgd,bkhd->bqhgk", qg, k_blk, preferred_element_type=f32)
-            * sm_scale
-        )
-        mask = k_pos[None, :] < Sk
+    def q_index(b, h, j, t):
+        i = t % nq
         if causal:
-            mask = jnp.logical_and(mask, k_pos[None, :] <= q_pos[:, None])
-        mask = mask[None, :, None, None, :]  # [1, Sq, 1, 1, Bk]
-        s = jnp.where(mask, s, NEG_INF)
-        p = jnp.where(mask, jnp.exp(s - lse_g[..., None]), 0.0)  # [B,Sq,Hkv,g,Bk]
-        dv_blk = jnp.einsum("bqhgk,bqhgd->bkhd", p, gg, preferred_element_type=f32)
-        dp = jnp.einsum("bqhgd,bkhd->bqhgk", gg, v_blk, preferred_element_type=f32)
-        ds = p * (dp - delta[..., None]) * sm_scale
-        dq_acc = dq_acc + jnp.einsum(
-            "bqhgk,bkhd->bqhgd", ds, k_blk, preferred_element_type=f32
-        )
-        dk_blk = jnp.einsum("bqhgk,bqhgd->bkhd", ds, qg, preferred_element_type=f32)
-        return dq_acc, (dk_blk, dv_blk)
+            # A skipped step names the block of the next step that runs, so
+            # that nothing is fetched for it.
+            i = jnp.minimum(jnp.maximum(i, (j * bk) // bq), nq - 1)
+        return b, h * group + t // nq, i
 
-    dq0 = jnp.zeros((B, Sq, Hkv, group, D), f32)
-    dq, (dk_blocks, dv_blocks) = jax.lax.scan(
-        kv_block, dq0, (kb, vb, jnp.arange(nk))
+    def row_index(*ids):
+        b, h, i = q_index(*ids)
+        return b, h, 0, i
+
+    q_spec = pl.BlockSpec((1, 1, bq, D), lambda *ids: (*q_index(*ids), 0))
+    row_spec = pl.BlockSpec((1, 1, 1, bq), row_index)
+    kv_spec = pl.BlockSpec((1, 1, bk, D), lambda b, h, j, t: (b, h, j, 0))
+    dk, dv = pl.pallas_call(
+        functools.partial(
+            _dkv_kernel, causal=causal, sm_scale=sm_scale,
+            block_q=bq, block_k=bk, q_len=Sq, kv_len=Sk, nq=nq,
+        ),
+        grid=(B, Hkv, nk, group * nq),
+        in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
+        out_specs=[kv_spec, kv_spec],
+        out_shape=[
+            jax.ShapeDtypeStruct(kt.shape, k.dtype),
+            jax.ShapeDtypeStruct(vt.shape, v.dtype),
+        ],
+        scratch_shapes=[pltpu.VMEM((bk, D), jnp.float32)] * 2,
+        compiler_params=_backward_params(),
+        interpret=interpret,
+        # Not `_flash_forward...`: the benchmark's attention_roofline_share
+        # finds the forward kernel by that prefix, these by `_flash_backward`.
+        name="_flash_backward_dkv",
+    )(
+        qt, kt, vt, dot,
+        # the statistics as one row along the lanes: [B, Hq, 1, S']
+        _pad_rows(lse, bq)[:, :, None, :], _pad_rows(delta, bq)[:, :, None, :],
     )
-    dk = jnp.moveaxis(dk_blocks, 0, 1).reshape(B, nk * block_k, Hkv, D)[:, :Sk]
-    dv = jnp.moveaxis(dv_blocks, 0, 1).reshape(B, nk * block_k, Hkv, D)[:, :Sk]
-    return (
-        dq.reshape(B, Sq, Hq, D).astype(q.dtype),
-        dk.astype(k.dtype),
-        dv.astype(v.dtype),
-    )
+    return jnp.swapaxes(dk, 1, 2)[:, :Sk], jnp.swapaxes(dv, 1, 2)[:, :Sk]
+
+
+def _backward_dq(q, k, v, dout, lse, delta, *, causal, sm_scale, blocks, interpret):
+    """dq [B, Sq, Hq, D]: grid (B, Hq, q blocks, kv blocks)."""
+    bq, bk = blocks
+    (B, Sq, Hq, D), (_, Sk, Hkv, _) = q.shape, k.shape
+    group = Hq // Hkv
+    qt, dot = _heads_major(q, bq), _heads_major(dout, bq)
+    kt, vt = _heads_major(k, bk), _heads_major(v, bk)
+    nq, nk = qt.shape[2] // bq, kt.shape[2] // bk
+
+    def kv_index(b, h, i, j):
+        if causal:  # as q_index above: a skipped step fetches nothing
+            j = jnp.minimum(j, jnp.minimum(((i + 1) * bq - 1) // bk, nk - 1))
+        return b, h // group, j, 0
+
+    def columns(x):  # lane-replicated [B, Hq, S', 128], the forward's layout
+        x = _pad_rows(x, bq)
+        return jnp.broadcast_to(x[..., None], (*x.shape, 128))
+
+    q_spec = pl.BlockSpec((1, 1, bq, D), lambda b, h, i, j: (b, h, i, 0))
+    col_spec = pl.BlockSpec((1, 1, bq, 128), lambda b, h, i, j: (b, h, i, 0))
+    kv_spec = pl.BlockSpec((1, 1, bk, D), kv_index)
+    dq = pl.pallas_call(
+        functools.partial(
+            _dq_kernel, causal=causal, sm_scale=sm_scale,
+            block_q=bq, block_k=bk, q_len=Sq, kv_len=Sk,
+        ),
+        grid=(B, Hq, nq, nk),
+        in_specs=[q_spec, kv_spec, kv_spec, q_spec, col_spec, col_spec],
+        out_specs=q_spec,
+        out_shape=jax.ShapeDtypeStruct(qt.shape, q.dtype),
+        scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
+        compiler_params=_backward_params(),
+        interpret=interpret,
+        name="_flash_backward_dq",
+    )(qt, kt, vt, dot, columns(lse), columns(delta))
+    return jnp.swapaxes(dq, 1, 2)[:, :Sq]
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=("causal", "sm_scale", "dkv_blocks", "dq_blocks", "interpret"),
+)
+def _flash_backward(
+    q: jax.Array,
+    k: jax.Array,
+    v: jax.Array,
+    out: jax.Array,
+    lse: jax.Array,  # [B, Hq, Sq] f32
+    dout: jax.Array,
+    causal: bool,
+    sm_scale: float,
+    dkv_blocks: tuple[int, int],  # (q block, kv block) of the dk/dv kernel
+    dq_blocks: tuple[int, int],
+    interpret: bool,
+):
+    # delta_i = sum_d out_i * dout_i, the softmax backward's row term.
+    delta = jnp.einsum("bqhd,bqhd->bhq", out, dout, preferred_element_type=jnp.float32)
+    kw = dict(causal=causal, sm_scale=sm_scale, interpret=interpret)
+    dk, dv = _backward_dkv(q, k, v, dout, lse, delta, blocks=dkv_blocks, **kw)
+    dq = _backward_dq(q, k, v, dout, lse, delta, blocks=dq_blocks, **kw)
+    return dq, dk, dv
 
 
 # --- custom-vjp core (arrays only; mesh handled by the public wrapper) ---
@@ -394,13 +640,17 @@ def _core_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret):
 
 
 def _core_bwd(causal, sm_scale, block_q, block_k, interpret, res, g):
-    del block_q, interpret
-    bk = _clamp_block(block_k, res[1].shape[1])
-    # The backward is plain XLA: the scope is what tells its fusions from
-    # the rest of the step's in a profile.
+    del block_q, block_k  # the forward's tiles; the backward has its own
+    q, k, v, out, lse = res
+    clamp = lambda blocks: (
+        _clamp_block(blocks[0], q.shape[1]), _clamp_block(blocks[1], k.shape[1])
+    )
+    # The scope is what tells the backward's kernels and the few XLA
+    # operations around them from the rest of the step's in a profile.
     with jax.named_scope("attn_bwd"):
-        return _blockwise_backward(
-            res, g, causal=causal, sm_scale=sm_scale, block_k=bk
+        return _flash_backward(
+            q, k, v, out, lse, g, causal, sm_scale,
+            clamp(BWD_DKV_BLOCKS), clamp(BWD_DQ_BLOCKS), interpret,
         )
 
 

@@ -1,0 +1,101 @@
+"""Block sweep of the flash attention's backward kernels on the chip: device
+time of `_flash_backward_dkv` and `_flash_backward_dq` per call, from a
+`jax.profiler` capture of each (q block, kv block), and the host clock's time
+of the whole backward (the kernels, `delta`, the transposes around them).
+`BWD_DKV_BLOCKS` / `BWD_DQ_BLOCKS` in `ops/pallas_attention.py` are picked from
+its output.  Through chiprun; one JSON line per configuration, the last line
+is the best of each kernel per shape.
+
+    chiprun -- python3 scripts/chip_attention_backward_sweep.py
+"""
+
+from __future__ import annotations
+
+import itertools
+import json
+import shutil
+import sys
+import tempfile
+import time
+from pathlib import Path
+
+ROOT = Path(__file__).resolve().parents[1]
+sys.path.insert(0, str(ROOT))
+
+# (batch, seq, q heads, kv heads, head dim): the decoder cell's attention and
+# the GQA 16/4 at d64 of chip_smoke.py's cases.
+SHAPES = ((2, 4096, 32, 8, 128), (1, 2048, 16, 4, 64))
+BLOCKS = tuple(itertools.product((256, 512, 1024), repeat=2))  # (q block, kv block)
+CALLS = 5
+
+
+def sweep(shape, blocks) -> list[dict]:
+    import jax
+    import jax.numpy as jnp
+
+    from benchmarks import trace_reduce
+    from deeplearning_cfn_tpu.ops import pallas_attention as pa
+
+    B, S, Hq, Hkv, D = shape
+    keys = jax.random.split(jax.random.key(S + Hq), 4)
+    q, dout = (jax.random.normal(k, (B, S, Hq, D), jnp.bfloat16) for k in keys[:2])
+    k, v = (jax.random.normal(k, (B, S, Hkv, D), jnp.bfloat16) for k in keys[2:])
+    scale = D**-0.5
+    out, lse = pa._flash_forward(
+        q, k, v, True, scale, pa._clamp_block(pa.DEFAULT_BLOCK_Q, S),
+        pa._clamp_block(pa.DEFAULT_BLOCK_K, S), False,
+    )
+    rows_out = []
+    for bq, bk in blocks:
+        tiles = (pa._clamp_block(bq, S), pa._clamp_block(bk, S))
+        run = lambda: pa._flash_backward(
+            q, k, v, out, lse, dout, True, scale, tiles, tiles, False
+        )
+        row = {"shape": list(shape), "block_q": tiles[0], "block_k": tiles[1]}
+        try:
+            jax.block_until_ready(run())
+        except Exception as e:  # a tile Mosaic refuses is a row of the sweep too
+            rows_out.append({**row, "error": str(e)[:300]})
+            continue
+        t0 = time.perf_counter()
+        jax.block_until_ready([run() for _ in range(CALLS)])
+        row["backward_ms"] = 1e3 * (time.perf_counter() - t0) / CALLS
+        trace_dir = tempfile.mkdtemp(prefix="bwd_sweep_")
+        with jax.profiler.trace(trace_dir):
+            jax.block_until_ready([run() for _ in range(CALLS)])
+        rows = trace_reduce.load_events(trace_dir)
+        shutil.rmtree(trace_dir, ignore_errors=True)
+        device = trace_reduce.devices(rows)[0]
+        for kernel in ("dkv", "dq"):
+            seconds, calls = trace_reduce.kernel_seconds(
+                rows, device, rf"^_flash_backward_{kernel}"
+            )
+            row[f"{kernel}_ms"] = 1e3 * seconds / calls if calls else None
+        rows_out.append(row)
+        print(json.dumps(row, allow_nan=False), flush=True)
+    return rows_out
+
+
+def main() -> int:
+    import jax
+
+    if jax.devices()[0].platform != "tpu":
+        print("chip_attention_backward_sweep: needs a TPU", file=sys.stderr)
+        return 1
+    best = {}
+    for shape in SHAPES:
+        rows = [r for r in sweep(shape, BLOCKS) if "error" not in r]
+        best["x".join(map(str, shape))] = {
+            kernel: min(
+                ({"block_q": r["block_q"], "block_k": r["block_k"], "ms": r[f"{kernel}_ms"]}
+                 for r in rows if r[f"{kernel}_ms"]),
+                key=lambda r: r["ms"],
+            )
+            for kernel in ("dkv", "dq")
+        }
+    print(json.dumps({"device": jax.devices()[0].device_kind, "best": best}, allow_nan=False))
+    return 0
+
+
+if __name__ == "__main__":
+    raise SystemExit(main())

@@ -2,8 +2,9 @@
 attention.
 
 Covers: causal/non-causal, GQA, non-divisible sequence lengths (padding +
-masking), and gradients through the custom VJP.  The compiled kernel's
-numerics are checked on the chip by chip_smoke.py.
+masking), and gradients through the custom VJP, whose backward pass is two
+Pallas kernels of its own.  The compiled kernels' numerics are checked on
+the chip by chip_smoke.py.
 """
 
 import dataclasses
@@ -63,20 +64,119 @@ def test_bf16_io():
     )
 
 
-@pytest.mark.parametrize("hq,hkv", [(4, 4), (4, 2)])
-def test_gradients_match(hq, hkv):
-    q, k, v = _qkv(s=48, hq=hq, hkv=hkv)
+def _grads(attention, q, k, v, jit=False):
+    """(dq, dk, dv) of sum(attention(q, k, v)**2) in float32."""
 
-    def loss_flash(q, k, v):
-        return jnp.sum(flash_attention(q, k, v, True, None, 16, 16) ** 2)
+    def loss(q, k, v):
+        return jnp.sum(attention(q, k, v).astype(jnp.float32) ** 2)
 
-    def loss_ref(q, k, v):
-        return jnp.sum(dot_product_attention(q, k, v, causal=True) ** 2)
+    grad = jax.grad(loss, argnums=(0, 1, 2))
+    return (jax.jit(grad) if jit else grad)(q, k, v)
 
-    gf = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
-    gr = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
+
+@pytest.fixture
+def backward_blocks(monkeypatch):
+    """Set the backward kernels' (q block, kv block): module constants sized
+    for the chip, which `flash_attention`'s block arguments (the forward's
+    tiles) do not reach."""
+
+    def set_blocks(block_q: int, block_k: int):
+        for name in ("BWD_DKV_BLOCKS", "BWD_DQ_BLOCKS"):
+            monkeypatch.setattr(pallas_attention, name, (block_q, block_k))
+
+    set_blocks(16, 16)
+    return set_blocks
+
+
+# The backward kernels (interpret mode) against the XLA gradient.  Blocks of
+# 16 make every case several (q block, kv block) pairs: skipped, masked and
+# unmasked ones under causal, a padded last block where S is ragged.
+GRADIENT_CASES = {
+    "mha": dict(hq=4, hkv=4),
+    "gqa-4-2": dict(hq=4, hkv=2),
+    "gqa-8-2": dict(hq=8, hkv=2),
+    "non-causal-mha": dict(hq=4, hkv=4, causal=False),
+    "non-causal-gqa-8-2": dict(hq=8, hkv=2, causal=False),
+    "ragged-50": dict(s=50),
+    "ragged-50-non-causal": dict(s=50, causal=False),
+    "ragged-50-gqa-8-2": dict(s=50, hq=8, hkv=2),
+    "uneven-blocks": dict(s=64, block_q=32, block_k=16),
+    "uneven-blocks-wide-k": dict(s=64, block_q=16, block_k=32),
+    "one-block": dict(s=48, block_q=1024, block_k=512),  # the defaults, clamped
+    "jit": dict(jit=True),
+}
+
+
+@pytest.mark.parametrize("case", GRADIENT_CASES)
+def test_gradients_match(case, backward_blocks):
+    kw = dict(s=48, hq=4, hkv=2, causal=True, block_q=16, block_k=16, jit=False)
+    kw.update(GRADIENT_CASES[case])
+    backward_blocks(kw["block_q"], kw["block_k"])
+    q, k, v = _qkv(s=kw["s"], hq=kw["hq"], hkv=kw["hkv"])
+    flash = lambda q, k, v: flash_attention(
+        q, k, v, kw["causal"], None, kw["block_q"], kw["block_k"]
+    )
+    ref = lambda q, k, v: dot_product_attention(q, k, v, causal=kw["causal"])
+    gf, gr = _grads(flash, q, k, v, jit=kw["jit"]), _grads(ref, q, k, v)
     for a, b in zip(gf, gr):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-4, rtol=1e-4)
+
+
+@pytest.mark.parametrize("hq,hkv", [(4, 4), (8, 2)])
+def test_bf16_gradients_match_the_float32_xla_gradient(hq, hkv, backward_blocks):
+    """bf16 operands on the MXU, float32 statistics and accumulators: the
+    forward test's tolerance, relative to the largest gradient."""
+    q, k, v = _qkv(s=50, hq=hq, hkv=hkv, dtype=jnp.bfloat16)
+    gf = _grads(lambda q, k, v: flash_attention(q, k, v, True, None, 16, 16), q, k, v)
+    gr = _grads(
+        lambda q, k, v: dot_product_attention(q, k, v, causal=True),
+        *(x.astype(jnp.float32) for x in (q, k, v)),
+    )
+    for a, b in zip(gf, gr):
+        assert a.dtype == jnp.bfloat16
+        scale = float(jnp.max(jnp.abs(b)))
+        np.testing.assert_allclose(
+            np.asarray(a, np.float32) / scale, np.asarray(b) / scale, atol=3e-2
+        )
+
+
+def test_keys_after_every_query_get_exact_zeros(backward_blocks):
+    """Causal with more keys than queries: the kv blocks past the last query
+    are wholly masked in every row block, so their dk and dv are the
+    accumulators' zeros, and padded rows add nothing (no NaN from
+    exp(NEG_INF - NEG_INF), no inf * 0)."""
+    q, _, _ = _qkv(s=30)
+    _, k, v = _qkv(s=50, seed=1)
+    flash = lambda q, k, v: flash_attention(q, k, v, True, None, 16, 16)
+    ref = lambda q, k, v: dot_product_attention(q, k, v, causal=True)
+    gf, gr = _grads(flash, q, k, v), _grads(ref, q, k, v)
+    for a, b in zip(gf, gr):
+        assert np.isfinite(np.asarray(a)).all()
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-4, rtol=1e-4)
+    assert not np.asarray(gf[1][:, 30:]).any() and not np.asarray(gf[2][:, 30:]).any()
+
+
+def test_backward_kernels_are_named_apart_from_the_forward():
+    """Lowered for the TPU (no chip needed to lower): the step's text carries
+    the scope `attn_bwd`, which attention_backward_ms_per_step reads, and
+    only the forward kernel's name begins `_flash_forward`, which
+    attention_roofline_share matches and divides by the forward's FLOPs."""
+    import re
+
+    def loss(q, k, v):
+        return pallas_attention.flash_attention(q, k, v).astype(jnp.float32).sum()
+
+    q = jax.ShapeDtypeStruct((1, 256, 4, 128), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((1, 256, 2, 128), jnp.bfloat16)
+    lowered = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).trace(q, kv, kv).lower(
+        lowering_platforms=("tpu",)
+    )
+    text = lowered.as_text(debug_info=True)
+    kernels = set(re.findall(r'kernel_name = "([^"]+)"', text))
+    assert kernels == {"_flash_forward", "_flash_backward_dkv", "_flash_backward_dq"}
+    assert [n for n in kernels if n.startswith("_flash_forward")] == ["_flash_forward"]
+    assert "attn_bwd" in text
+    assert not hasattr(pallas_attention, "_blockwise_backward")
 
 
 def test_block_picker_balances_padding_against_block_size():
@@ -139,15 +239,16 @@ def test_bad_gqa_ratio_raises():
         flash_attention(q, k, v)
 
 
-def test_mesh_shard_map_path():
+@pytest.mark.parametrize("causal", [True, False])
+def test_mesh_shard_map_path(causal, backward_blocks):
     from deeplearning_cfn_tpu.parallel.mesh import MeshSpec, build_mesh, virtual_cpu_devices
 
     mesh = build_mesh(MeshSpec(dp=2, tp=2), virtual_cpu_devices(4))
     q, k, v = _qkv(b=4, s=32, hq=4, hkv=2)
-    ref = dot_product_attention(q, k, v, causal=True)
+    ref = dot_product_attention(q, k, v, causal=causal)
 
     def loss_mesh(q, k, v):
-        out = flash_attention(q, k, v, causal=True, block_q=16, block_k=16, mesh=mesh)
+        out = flash_attention(q, k, v, causal=causal, block_q=16, block_k=16, mesh=mesh)
         return jnp.sum(out**2), out
 
     (val, out), grads = jax.value_and_grad(loss_mesh, argnums=(0, 1, 2), has_aux=True)(
@@ -155,10 +256,7 @@ def test_mesh_shard_map_path():
     )
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5, rtol=2e-5)
 
-    def loss_ref(q, k, v):
-        return jnp.sum(dot_product_attention(q, k, v, causal=True) ** 2)
-
-    gr = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
+    gr = _grads(lambda q, k, v: dot_product_attention(q, k, v, causal=causal), q, k, v)
     for a, b in zip(grads, gr):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-4, rtol=1e-4)
 
