@@ -391,6 +391,34 @@ def attention_kind(
     return "xla"
 
 
+def attend(kind: str, q: jax.Array, k: jax.Array, v: jax.Array, mesh: Mesh | None) -> jax.Array:
+    """Causal attention by the implementation `attention_kind` named, on
+    [B, S, H, D] tensors; the scores are scaled by D ** -0.5.  Shared by the
+    decoder blocks (this module's and models/mla_moe.py's)."""
+    if kind == "ring":
+        from deeplearning_cfn_tpu.parallel.ring_attention import ring_attention
+
+        return ring_attention(q, k, v, mesh, causal=True)
+    if kind == "flash":
+        from deeplearning_cfn_tpu.ops.pallas_attention import flash_attention
+
+        # attention_kind only answers "flash" on a tpu backend, so
+        # this is always the compiled Mosaic kernel.
+        return flash_attention(q, k, v, causal=True, mesh=mesh, interpret=False)
+    # "xla" covers use_flash_attention off-TPU (the Pallas kernel
+    # needs Mosaic) AND below-crossover sequences where XLA's
+    # fused attention measures faster than the Pallas kernel
+    # (docs/BENCH_NOTES.md): use_flash means "fastest memory-safe
+    # attention", not "always Pallas".
+    return dot_product_attention(q, k, v, causal=True)
+
+
+def swiglu(h: jax.Array, w_gate: jax.Array, w_up: jax.Array, w_down: jax.Array) -> jax.Array:
+    """The gated feed-forward: silu in float32, the products in h's type."""
+    gate = jax.nn.silu((h @ w_gate).astype(jnp.float32)).astype(h.dtype)
+    return (gate * (h @ w_up)) @ w_down
+
+
 def _block(
     cfg: LlamaConfig,
     mesh: Mesh | None,
@@ -422,27 +450,8 @@ def _block(
         with jax.named_scope("rope"):
             q = rotary_embedding(q, positions, cfg.rope_theta)
             k = rotary_embedding(k, positions, cfg.rope_theta)
-        kind = attention_kind(cfg, mesh, S)
         with jax.named_scope("core"):
-            if kind == "ring":
-                from deeplearning_cfn_tpu.parallel.ring_attention import ring_attention
-
-                attn = ring_attention(q, k, v, mesh, causal=True)
-            elif kind == "flash":
-                from deeplearning_cfn_tpu.ops.pallas_attention import flash_attention
-
-                # attention_kind only answers "flash" on a tpu backend, so
-                # this is always the compiled Mosaic kernel.
-                attn = flash_attention(
-                    q, k, v, causal=True, mesh=mesh, interpret=False
-                )
-            else:
-                # "xla" covers use_flash_attention off-TPU (the Pallas kernel
-                # needs Mosaic) AND below-crossover sequences where XLA's
-                # fused attention measures faster than the Pallas kernel
-                # (docs/BENCH_NOTES.md): use_flash means "fastest memory-safe
-                # attention", not "always Pallas".
-                attn = dot_product_attention(q, k, v, causal=True)
+            attn = attend(attention_kind(cfg, mesh, S), q, k, v, mesh)
         with jax.named_scope("out"):
             x = x + attn.reshape(B, S, cfg.n_heads * hd) @ lp["wo"]
     with jax.named_scope("mlp_norm"):
@@ -460,8 +469,7 @@ def _block(
             ).astype(h.dtype)
             x = x + (gate * gu[..., cfg.mlp_dim :]) @ lp["w_down"]
         else:
-            gate = jax.nn.silu((h @ lp["w_gate"]).astype(jnp.float32)).astype(h.dtype)
-            x = x + (gate * (h @ lp["w_up"])) @ lp["w_down"]
+            x = x + swiglu(h, lp["w_gate"], lp["w_up"], lp["w_down"])
     return x, jnp.zeros((), jnp.float32)
 
 
@@ -559,12 +567,13 @@ class _FunctionalInit:
     """Adapter giving the functional model the tiny surface Trainer.init
     expects (a flax-style ``init`` returning {"params": ...})."""
 
-    def __init__(self, cfg: LlamaConfig):
+    def __init__(self, cfg: Any, init_fn=None):
         self.cfg = cfg
+        self.init_fn = init_fn or init_params
 
     def init(self, rng: jax.Array, sample: jax.Array) -> dict:
         del sample
-        return {"params": init_params(self.cfg, rng)}
+        return {"params": self.init_fn(self.cfg, rng)}
 
 
 def make_trainer(cfg: LlamaConfig, mesh: Mesh, trainer_config) -> Any:

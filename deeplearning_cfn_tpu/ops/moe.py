@@ -22,6 +22,7 @@ from __future__ import annotations
 
 import math
 from dataclasses import dataclass
+from functools import partial
 from typing import Any
 
 import jax
@@ -166,3 +167,252 @@ def moe_mlp(
     p = jnp.mean(probs, axis=1)  # [G, E]
     aux_loss = cfg.aux_loss_weight * E * jnp.mean(jnp.sum(f * p, axis=-1))
     return y.reshape(B, S, d), aux_loss
+
+
+# --- routed experts without dropped tokens ----------------------------------
+#
+# The path a published expert model needs: no capacity, so no token is dropped
+# at any imbalance.  The token-to-expert assignments are sorted by expert, the
+# rows gathered into one [assignments, d] buffer, the three SwiGLU matmuls run
+# as grouped matmuls over it (each expert's rows against its own weights), and
+# the rows are weighted and summed back per token.  Every shape is static: the
+# buffer has a row for each assignment that can go to an expert held here, and
+# only the group sizes are data.  The one-hot path above stays for
+# ``LlamaConfig.n_experts`` and the serving engine until experts are spread
+# over the ``ep`` axis; a model module calls one or the other.
+
+
+@dataclass(frozen=True)
+class RoutedConfig:
+    """A routed-experts layer: the router's variants as data.
+
+    ``held = (first, count)`` is the chip's share under expert parallelism:
+    the layer holds experts ``first .. first + count - 1`` of ``n_routed``,
+    scores, selects and normalises over all ``n_routed``, and computes its own
+    experts' part of the result.  What the absent experts would add is some
+    other chip's to compute and to send; nothing here stands in for it.
+    """
+
+    n_routed: int
+    top_k: int
+    held: tuple[int, int] | None = None  # None: all of them
+    score: str = "sigmoid"  # or "softmax"
+    # A per-expert bias added to the scores for the selection only (the
+    # auxiliary-loss-free balancing of arXiv:2408.15664): params["router_bias"].
+    selection_bias: bool = False
+    renormalize: bool = True  # the selected weights sum to 1 before `scale`
+    scale: float = 1.0
+    # A shared expert every token passes through, of this width; 0: none.
+    shared_dim: int = 0
+
+    def __post_init__(self):
+        first, count = self.span
+        if not (1 <= self.top_k <= self.n_routed):
+            raise ValueError(f"top_k={self.top_k} must be in [1, {self.n_routed}]")
+        if first < 0 or count < 1 or first + count > self.n_routed:
+            raise ValueError(f"held={self.held} is not a span of {self.n_routed} experts")
+        if self.score not in ("sigmoid", "softmax"):
+            raise ValueError(f"unknown score function {self.score!r}")
+
+    @property
+    def span(self) -> tuple[int, int]:
+        return self.held if self.held is not None else (0, self.n_routed)
+
+    def buffer_rows(self, n_tokens: int) -> int:
+        """Rows of the sorted buffer.  A token's ``top_k`` choices are
+        distinct experts, so at most ``min(top_k, count)`` of them are held
+        here: that bounds the buffer, whatever the router does."""
+        return n_tokens * min(self.top_k, self.span[1])
+
+
+def init_routed_params(
+    cfg: RoutedConfig, rng: jax.Array, dim: int, expert_dim: int, dtype: Any = jnp.bfloat16
+) -> dict:
+    """The held experts' SwiGLU weights stacked on a leading axis, the
+    router over all ``n_routed`` (float32: selection is precision-sensitive),
+    and the shared expert."""
+    keys = jax.random.split(rng, 8)
+    count = cfg.span[1]
+
+    def dense(key, shape, fan_in):
+        return (jax.random.normal(key, shape, jnp.float32) / math.sqrt(fan_in)).astype(dtype)
+
+    params = {
+        "router": jax.random.normal(keys[0], (dim, cfg.n_routed), jnp.float32) / math.sqrt(dim),
+        "w_gate": dense(keys[1], (count, dim, expert_dim), dim),
+        "w_up": dense(keys[2], (count, dim, expert_dim), dim),
+        "w_down": dense(keys[3], (count, expert_dim, dim), expert_dim),
+    }
+    if cfg.selection_bias:
+        params["router_bias"] = jnp.zeros((cfg.n_routed,), jnp.float32)
+    if cfg.shared_dim:
+        params["shared_gate"] = dense(keys[4], (dim, cfg.shared_dim), dim)
+        params["shared_up"] = dense(keys[5], (dim, cfg.shared_dim), dim)
+        params["shared_down"] = dense(keys[6], (cfg.shared_dim, dim), cfg.shared_dim)
+    return params
+
+
+def routed_param_specs(cfg: RoutedConfig) -> dict:
+    """fsdp x tp inside an expert, as the dense MLP.  The expert axis is
+    not sharded: ``held`` says which experts this program's chips hold."""
+    specs = {
+        "router": P(None, None),
+        "w_gate": P(None, "fsdp", "tp"),
+        "w_up": P(None, "fsdp", "tp"),
+        "w_down": P(None, "tp", "fsdp"),
+    }
+    if cfg.selection_bias:
+        specs["router_bias"] = P(None)
+    if cfg.shared_dim:
+        specs.update(
+            shared_gate=P("fsdp", "tp"), shared_up=P("fsdp", "tp"), shared_down=P("tp", "fsdp")
+        )
+    return specs
+
+
+def route(cfg: RoutedConfig, params: dict, x: jax.Array) -> tuple[jax.Array, jax.Array]:
+    """x [T, d] -> (experts [T, k] int32, weights [T, k] float32), over all
+    ``n_routed`` experts, in float32 with a full-precision matmul: a score
+    rounded to bfloat16 picks another expert than float32 does."""
+    logits = jnp.matmul(
+        x.astype(jnp.float32), params["router"], precision=jax.lax.Precision.HIGHEST
+    )
+    scores = jax.nn.sigmoid(logits) if cfg.score == "sigmoid" else jax.nn.softmax(logits, axis=-1)
+    choice = scores
+    if cfg.selection_bias:
+        choice = scores + jax.lax.stop_gradient(params["router_bias"])
+    _, experts = jax.lax.top_k(choice, cfg.top_k)
+    weights = jnp.take_along_axis(scores, experts, axis=-1)
+    if cfg.renormalize:
+        weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + 1e-20)
+    return experts.astype(jnp.int32), weights * cfg.scale
+
+
+@jax.custom_vjp
+def _rows_out(x, token, slot):
+    """x [T, d] -> [R, d]: row r is x[token[r]].  ``slot`` [T, j] says where
+    each token's rows went (>= R: nowhere), so the transpose is a gather
+    too and no scatter runs in either pass."""
+    return x[token]
+
+
+def _rows_back(rows, token, slot):
+    """[R, d] -> [T, d]: token t gets the sum of rows[slot[t, :]], added up
+    in float32."""
+    R = rows.shape[0]
+    picked = rows[jnp.minimum(slot, R - 1)]  # [T, j, d]
+    picked = jnp.where((slot < R)[..., None], picked, 0)
+    return jnp.sum(picked, axis=1, dtype=jnp.float32).astype(rows.dtype)
+
+
+_rows_out.defvjp(
+    lambda x, token, slot: (x[token], (token, slot)),
+    lambda res, g: (_rows_back(g, *res), None, None),
+)
+
+
+@jax.custom_vjp
+def _rows_in(rows, token, slot):
+    """The other way: [R, d] -> [T, d], each token the sum of its rows."""
+    return _rows_back(rows, token, slot)
+
+
+_rows_in.defvjp(
+    lambda rows, token, slot: (_rows_back(rows, token, slot), token),
+    lambda token, g: (g[token], None, None),
+)
+
+# (rows, contraction, columns) of the grouped matmul's tiles on the TPU, from
+# a sweep on v5e at the expert layer's shape (scripts/chip_grouped_matmul_sweep.py).
+GROUPED_MATMUL_TILES = (512, 1024, 512)
+
+
+def grouped_matmul_kind(backend: str | None = None) -> str:
+    """``pallas`` on a TPU (the Mosaic grouped matmul), ``xla`` elsewhere
+    (``jax.lax.ragged_dot``, which XLA expands to masked dense products off
+    the TPU: the correctness path, as ``attention_kind``'s ``xla``)."""
+    return "pallas" if (backend or jax.default_backend()) == "tpu" else "xla"
+
+
+def grouped_matmul(
+    rows: jax.Array, weights: jax.Array, group_sizes: jax.Array, kind: str,
+    interpret: bool = False,
+) -> jax.Array:
+    """rows [R, a] sorted by group, weights [G, a, b], group_sizes [G] ->
+    [R, b]: each group's rows times its own matrix.  Rows past the last group
+    come back as zeros."""
+    R = rows.shape[0]
+    valid = (jnp.arange(R) < jnp.sum(group_sizes))[:, None]
+    if kind == "xla":
+        out = jax.lax.ragged_dot(rows, weights, group_sizes)
+    else:
+        from jax.experimental.pallas.ops.tpu.megablox import gmm
+
+        tm, tk, tn = GROUPED_MATMUL_TILES
+        tiles = (min(tm, R), min(tk, weights.shape[1]), min(tn, weights.shape[2]))
+        if R % tiles[0]:
+            raise ValueError(f"{R} rows do not divide into tiles of {tiles[0]}")
+        # The kernel visits the tiles that hold a group's rows and no other:
+        # what it leaves of its output, and of its gradient with respect to
+        # `rows`, is uninitialised.  Both selects are needed.
+        rows = jnp.where(valid, rows, 0)
+        out = gmm(rows, weights, group_sizes, rows.dtype, tiles, interpret=interpret)
+    return jnp.where(valid, out, 0)
+
+
+def routed_experts(
+    cfg: RoutedConfig, params: dict, x: jax.Array, *, kind: str | None = None,
+    interpret: bool = False,
+) -> tuple[jax.Array, dict]:
+    """[B, S, d] -> ([B, S, d], statistics of the routing).
+
+    The result is the held experts' part of sum_i w_i E_i(x), plus the shared
+    expert.  The statistics are scalars for the step's counters (assignments
+    in all and to held experts, the largest held expert's load, and `dropped`:
+    assignments to held experts less rows computed, which is 0) and
+    ``selected`` [T, k], the experts each token chose.
+    """
+    B, S, d = x.shape
+    T, k = B * S, cfg.top_k
+    first, count = cfg.span
+    kind = kind or grouped_matmul_kind()
+    xt = x.reshape(T, d)
+    with jax.named_scope("router"):
+        experts, weights = route(cfg, params, xt)
+    with jax.named_scope("dispatch"):
+        # Assignments to held experts sort to the front by expert, the
+        # others behind them under one more index.
+        local = jnp.where((experts >= first) & (experts < first + count), experts - first, count)
+        local = local.reshape(T * k)
+        order = jnp.argsort(local, stable=True)
+        R = cfg.buffer_rows(T)
+        slot = jnp.argsort(order).astype(jnp.int32).reshape(T, k)  # inverse permutation
+        order = order[:R]
+        token = (order // k).astype(jnp.int32)
+        group_sizes = jnp.sum(
+            local[:, None] == jnp.arange(count, dtype=local.dtype)[None, :], axis=0, dtype=jnp.int32
+        )
+        rows = _rows_out(xt, token, slot)
+    with jax.named_scope("experts"):
+        mm = partial(grouped_matmul, group_sizes=group_sizes, kind=kind, interpret=interpret)
+        gate = jax.nn.silu(mm(rows, params["w_gate"]).astype(jnp.float32)).astype(x.dtype)
+        out = mm(gate * mm(rows, params["w_up"]), params["w_down"])
+    with jax.named_scope("combine"):
+        # In the activations' type: a float32 copy of the buffer, of its
+        # gather and of both cotangents is 2 GB at 65,536 rows of 2048.
+        row_weight = weights.reshape(T * k)[order].astype(x.dtype)
+        y = _rows_in(out * row_weight[:, None], token, slot)
+    if cfg.shared_dim:
+        with jax.named_scope("shared"):
+            g = jax.nn.silu((xt @ params["shared_gate"]).astype(jnp.float32)).astype(x.dtype)
+            y = y + (g * (xt @ params["shared_up"])) @ params["shared_down"]
+    held = jnp.sum(local < count, dtype=jnp.int32)  # from the selection
+    computed = jnp.sum(local[order] < count, dtype=jnp.int32)  # from the buffer
+    stats = {
+        "assignments": jnp.asarray(T * k, jnp.int32),
+        "assignments_held": held,
+        "load_max": jnp.max(group_sizes),
+        "dropped": held - computed,
+        "selected": experts,
+    }
+    return y.reshape(B, S, d), stats

@@ -57,7 +57,7 @@ from deeplearning_cfn_tpu.train.metrics import (
     ThroughputLogger,
     peak_flops_per_chip,
 )
-from deeplearning_cfn_tpu.obs.tracing import freeze_counters, span
+from deeplearning_cfn_tpu.obs.tracing import counter, freeze_counters, span
 from deeplearning_cfn_tpu.utils.logging import get_logger
 
 log = get_logger("dlcfn.trainer")
@@ -280,6 +280,15 @@ def _accumulated_grads(loss_fn, state, x, y, accum: int):
         grads = jax.tree_util.tree_map(lambda g: g / accum, grads_sum)
         aux = jax.tree_util.tree_map(lambda v: jnp.mean(v, axis=0), auxes)
         return jnp.mean(losses), aux, new_model_state, grads
+
+
+def _fold_counters(pending: list[dict[str, jax.Array]]) -> None:
+    """Each pending step's ``metrics["counters"]`` into ``obs.tracing``'s
+    counters (one observation per step and name), and the list emptied."""
+    for step_counters in jax.device_get(pending):
+        for name, value in step_counters.items():
+            counter(name, float(value))
+    pending.clear()
 
 
 def softmax_xent(logits: jax.Array, labels: jax.Array, smoothing: float = 0.0) -> jax.Array:
@@ -970,6 +979,8 @@ class Trainer:
         totals: dict[str, float] = {}
         for n, metrics in zip(counts, materialized):
             for k, v in metrics.items():
+                if isinstance(v, dict):  # counters, not a mean over examples
+                    continue
                 totals[k] = totals.get(k, 0.0) + float(v) * n
         out = {k: v / examples for k, v in totals.items()}
         out["examples"] = examples
@@ -1121,6 +1132,11 @@ class Trainer:
 
         losses: list[float] = []
         pending: list[jax.Array] = []  # device scalars awaiting readback
+        # A loss may count things beside its metrics (metrics["counters"],
+        # name -> scalar: a routed layer's assignments, say); they wait for
+        # the drain with the losses and are folded into obs.tracing counters
+        # there, one observation a step.
+        pending_counters: list[dict[str, jax.Array]] = []
         step_fn = self.step_fn
         sync_every = max(1, int(self.config.log_every))
         # islice in every mode: fit consumes exactly `steps` items from the
@@ -1177,6 +1193,8 @@ class Trainer:
                             state, metrics = step_fn(state, x, y)
                     gstep += 1
                     pending.append(metrics["loss"])
+                    if "counters" in metrics:
+                        pending_counters.append(metrics["counters"])
                     if i == 0:
                         self.batch_bytes_by_device = bytes_by_device((x, y))
                         seams.first_step(metrics["loss"])
@@ -1199,6 +1217,9 @@ class Trainer:
                         with seams("fit.sync", len(pending)):
                             losses.extend(float(v) for v in jax.device_get(pending))
                         pending.clear()
+                        if pending_counters:
+                            with seams("fit.log"):
+                                _fold_counters(pending_counters)
                         if stop_fn is not None:
                             with seams("fit.log"):
                                 stop = stop_fn(metrics)
@@ -1210,6 +1231,7 @@ class Trainer:
             if prefetcher is not None:
                 prefetcher.close()
         losses.extend(float(v) for v in jax.device_get(pending))
+        _fold_counters(pending_counters)
         return state, losses
 
     def _fit_multi(

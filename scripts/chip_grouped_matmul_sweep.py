@@ -1,0 +1,158 @@
+"""Sweep of the routed experts' grouped matmuls on the chip, at the shape of
+one expert layer of the `glm-4.7-flash.train-s8192` cell: 16,384 tokens, top 4
+of 64, 16 experts held (a buffer of 65,536 rows of which about a quarter hold
+an assignment), d 2048, expert width 1536, bf16.  `GROUPED_MATMUL_TILES` in
+`ops/moe.py` is picked from its output.
+
+For each candidate (XLA's `ragged_dot`, the Pallas grouped matmul at several
+tilings) the host clock's time of one forward and backward pass of the three
+matmuls with their SwiGLU, and from a `jax.profiler` capture the device time of
+the operations that took most of it.  Then the flash attention's forward and
+backward kernels at the cell's 20 heads of 256, by tile.  Through chiprun; one
+JSON line per row.
+
+    chiprun -- python3 scripts/chip_grouped_matmul_sweep.py
+"""
+
+from __future__ import annotations
+
+import json
+import shutil
+import sys
+import tempfile
+import time
+from pathlib import Path
+
+ROOT = Path(__file__).resolve().parents[1]
+sys.path.insert(0, str(ROOT))
+sys.path.insert(0, str(ROOT / "scripts"))
+
+TOKENS, TOP_K, N_ROUTED, HELD, D, WIDTH = 16384, 4, 64, 16, 2048, 1536
+# Tilings (rows, contraction, columns) that fit the kernel's default scoped
+# VMEM; 1024 x 1024 x 1024 and anything 1536 wide do not (compiled for v5e).
+TILES = (
+    (512, 1024, 1024), (512, 512, 512), (256, 1024, 1024), (1024, 512, 512),
+    (512, 1024, 512), (512, 512, 1024), (1024, 512, 1024), (256, 512, 512),
+)
+ATTENTION = (2, 8192, 20, 20, 256)
+CALLS = 5
+
+
+def top_operations(trace_dir: str, n: int = 6) -> list:
+    import re
+
+    from benchmarks import trace_reduce
+
+    rows = trace_reduce.load_events(trace_dir)
+    device = trace_reduce.devices(rows)[0]
+    by_kind: dict[str, list] = {}
+    for s, e, name in trace_reduce.op_intervals(rows, device):
+        kind = re.sub(r"\.\d+$", "", trace_reduce.short_name(name))
+        entry = by_kind.setdefault(kind, [0, 0])
+        entry[0] += e - s
+        entry[1] += 1
+    top = sorted(by_kind.items(), key=lambda kv: -kv[1][0])[:n]
+    return [[k, round(ns / 1e6 / CALLS, 3), c // CALLS] for k, (ns, c) in top]
+
+
+def experts_sweep() -> None:
+    import jax
+    import jax.numpy as jnp
+
+    from deeplearning_cfn_tpu.ops import moe
+
+    keys = jax.random.split(jax.random.key(0), 6)
+    rows_n = TOKENS * TOP_K
+    rows = jax.random.normal(keys[0], (rows_n, D), jnp.bfloat16)
+    w_gate, w_up = (
+        jax.random.normal(k, (HELD, D, WIDTH), jnp.bfloat16) / D**0.5 for k in keys[1:3]
+    )
+    w_down = jax.random.normal(keys[3], (HELD, WIDTH, D), jnp.bfloat16) / WIDTH**0.5
+    # Uniform routing: each assignment is held with probability 1/4.
+    experts = jax.random.randint(keys[4], (rows_n,), 0, N_ROUTED)
+    sizes = jnp.sum(experts[:, None] == jnp.arange(HELD)[None, :], axis=0, dtype=jnp.int32)
+    held = int(jnp.sum(sizes))
+    flops = 3 * 3 * 2 * held * D * WIDTH  # three matmuls, forward and twice backward
+
+    def chain(kind):
+        def loss(rows, w_gate, w_up, w_down):
+            mm = lambda a, w: moe.grouped_matmul(a, w, sizes, kind)
+            gate = jax.nn.silu(mm(rows, w_gate).astype(jnp.float32)).astype(rows.dtype)
+            return jnp.sum(mm(gate * mm(rows, w_up), w_down).astype(jnp.float32))
+
+        return jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3)))
+
+    candidates = [("xla", None)] + [("pallas", t) for t in TILES]
+    for kind, tiles in candidates:
+        row = {"grouped_matmul": kind, "tiles": tiles, "held_rows": held, "rows": rows_n}
+        if tiles:
+            moe.GROUPED_MATMUL_TILES = tiles
+        run = chain(kind)
+        try:
+            jax.block_until_ready(run(rows, w_gate, w_up, w_down))
+        except Exception as e:  # a tiling Mosaic refuses is a row too
+            print(json.dumps({**row, "error": str(e)[:300]}, allow_nan=False), flush=True)
+            continue
+        t0 = time.perf_counter()
+        jax.block_until_ready([run(rows, w_gate, w_up, w_down) for _ in range(CALLS)])
+        seconds = (time.perf_counter() - t0) / CALLS
+        row["forward_backward_ms"] = 1e3 * seconds
+        row["share_of_bf16_peak"] = flops / seconds / 197e12
+        trace_dir = tempfile.mkdtemp(prefix="gmm_sweep_")
+        with jax.profiler.trace(trace_dir):
+            jax.block_until_ready([run(rows, w_gate, w_up, w_down) for _ in range(CALLS)])
+        row["top_operations_ms_and_calls"] = top_operations(trace_dir)
+        shutil.rmtree(trace_dir, ignore_errors=True)
+        print(json.dumps(row, allow_nan=False), flush=True)
+
+
+def attention_sweep() -> None:
+    import itertools
+
+    import jax
+    import jax.numpy as jnp
+
+    import chip_attention_backward_sweep as backward
+    from benchmarks import trace_reduce
+    from deeplearning_cfn_tpu.ops import pallas_attention as pa
+
+    B, S, H, KV, hd = ATTENTION
+    keys = jax.random.split(jax.random.key(1), 3)
+    q = jax.random.normal(keys[0], (B, S, H, hd), jnp.bfloat16)
+    k, v = (jax.random.normal(kk, (B, S, KV, hd), jnp.bfloat16) for kk in keys[1:])
+    for bq, bk in itertools.product((512, 1024, 2048), (512, 1024)):
+        row = {"attention_forward": list(ATTENTION), "block_q": bq, "block_k": bk}
+        run = lambda: pa._flash_forward(q, k, v, True, hd**-0.5, bq, bk, False)
+        try:
+            jax.block_until_ready(run())
+        except Exception as e:
+            print(json.dumps({**row, "error": str(e)[:300]}, allow_nan=False), flush=True)
+            continue
+        trace_dir = tempfile.mkdtemp(prefix="fwd_sweep_")
+        with jax.profiler.trace(trace_dir):
+            jax.block_until_ready([run() for _ in range(CALLS)])
+        rows = trace_reduce.load_events(trace_dir)
+        shutil.rmtree(trace_dir, ignore_errors=True)
+        seconds, calls = trace_reduce.kernel_seconds(
+            rows, trace_reduce.devices(rows)[0], r"^_flash_forward"
+        )
+        row["forward_ms"] = 1e3 * seconds / calls if calls else None
+        print(json.dumps(row, allow_nan=False), flush=True)
+    blocks = ((512, 512), (512, 1024), (1024, 512), (1024, 1024))
+    backward.sweep(ATTENTION, blocks)
+
+
+def main() -> int:
+    import jax
+
+    if jax.devices()[0].platform != "tpu":
+        print("chip_grouped_matmul_sweep: needs a TPU", file=sys.stderr)
+        return 1
+    experts_sweep()
+    attention_sweep()
+    print(json.dumps({"device": jax.devices()[0].device_kind}, allow_nan=False))
+    return 0
+
+
+if __name__ == "__main__":
+    raise SystemExit(main())

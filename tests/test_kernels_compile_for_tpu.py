@@ -1,5 +1,6 @@
-"""The flash attention's kernels compiled for a TPU v5e that is described, not
-attached, at the widths the chip runs them: what the interpreter cannot show
+"""The flash attention's kernels and the routed experts' grouped matmuls
+compiled for a TPU v5e that is described, not attached, at the widths the chip
+runs them: what the interpreter cannot show
 (a tile Mosaic refuses, more VMEM than a kernel may use).  Nothing runs, so
 nothing here says anything about results or times; chip_smoke.py does, on the
 chip.  All of these in this one file: the worker that gets it loads the TPU's
@@ -25,10 +26,12 @@ def one_chip():
 
 
 # (batch, seq, q heads, kv heads, head dim, dtype): the decoder cell's
-# attention, the ragged case of chip_smoke.py (2100 pads to 2176 in blocks of
-# 128), GQA 16/4 at d64, and float32.
+# attention, the latent-attention cell's (20 heads of 192 + 64 = 256, the value
+# head 256 too), the ragged case of chip_smoke.py (2100 pads to 2176 in blocks
+# of 128), GQA 16/4 at d64, and float32.
 CASES = {
     "cell-s4096": (2, 4096, 32, 8, 128, jnp.bfloat16),
+    "mla-cell-s8192": (2, 8192, 20, 20, 256, jnp.bfloat16),
     "ragged-s2100": (1, 2100, 32, 8, 128, jnp.bfloat16),
     "d64-s2048": (1, 2048, 16, 4, 64, jnp.bfloat16),
     "float32-s2048": (1, 2048, 8, 8, 128, jnp.float32),
@@ -52,3 +55,34 @@ def test_forward_and_backward_compile_for_v5e(case, one_chip):
     # No score-sized tensor outside the kernels: all temporaries together
     # stay under one float32 [B, Hq, S, 512] slab of the old XLA backward.
     assert compiled.memory_analysis().temp_size_in_bytes < B * Hq * S * 512 * 4
+
+
+def test_routed_experts_layer_compiles_for_v5e_without_a_scatter(one_chip):
+    """One expert layer of `glm-4.7-flash.train-s8192`, forward and backward:
+    16,384 tokens, top 4 of 64, 16 experts of 2048 x 1536 held and a shared
+    one.  The grouped matmuls are the Pallas kernel at the tiles of
+    `GROUPED_MATMUL_TILES` (forward, and both of its transposes), and neither
+    pass scatters rows: dispatch and combine are gathers both ways (the
+    scatters left are of scalars: a token's four weights, the kernel's own
+    tile tables)."""
+    import re
+
+    from deeplearning_cfn_tpu.ops import moe
+
+    cfg = moe.RoutedConfig(
+        n_routed=64, top_k=4, held=(0, 16), selection_bias=True, scale=1.8, shared_dim=1536
+    )
+    shapes = jax.eval_shape(lambda: moe.init_routed_params(cfg, jax.random.key(0), 2048, 1536))
+    on_chip = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+    params = jax.tree_util.tree_map(on_chip, shapes)
+    x = jax.ShapeDtypeStruct((2, 8192, 2048), jnp.bfloat16, sharding=one_chip)
+    assert cfg.buffer_rows(2 * 8192) == 65536
+
+    def grads(params, x):
+        loss = lambda p, x: moe.routed_experts(cfg, p, x, kind="pallas")[0].astype(jnp.float32).sum()
+        return jax.grad(loss, argnums=(0, 1))(params, x)
+
+    text = jax.jit(grads).lower(params, x).compile().as_text()
+    assert text.count("tgmm") >= 3 and text.count("gmm") - text.count("tgmm") >= 6
+    assert "ragged-dot" not in text
+    assert not re.search(r"= \w+\[\d+,\d+\]\S* scatter\(", text)

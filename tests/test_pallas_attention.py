@@ -179,6 +179,35 @@ def test_backward_kernels_are_named_apart_from_the_forward():
     assert not hasattr(pallas_attention, "_blockwise_backward")
 
 
+def test_twenty_heads_of_256_forward_and_gradients(backward_blocks):
+    """The latent attention's core as models/mla_moe.py runs it: 20 query and
+    20 key/value heads of 256 (192 un-rotated + 64 rotary; the value head is
+    256 too, so nothing is padded), scores scaled by 256 ** -0.5, the
+    forward's and the backward's tiles clamped from the defaults."""
+    backward_blocks(*pallas_attention.BWD_DKV_BLOCKS)
+    q, k, v = _qkv(b=1, s=160, hq=20, hkv=20, d=256, seed=3)
+    flash = lambda q, k, v: flash_attention(q, k, v, True, 256**-0.5)
+    ref = lambda q, k, v: dot_product_attention(q, k, v, causal=True)
+    np.testing.assert_allclose(
+        np.asarray(flash(q, k, v)), np.asarray(ref(q, k, v)), atol=2e-5, rtol=2e-5
+    )
+    for a, b in zip(_grads(flash, q, k, v), _grads(ref, q, k, v)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-4, rtol=2e-4)
+
+
+def test_tiles_at_the_decoder_cells_shapes_are_the_sweeps():
+    """Head size is not an input of the tile choice: at S 4096 (heads of 128)
+    and S 8192 (heads of 256) the forward runs 1024 x 512 and the backward
+    1024 x 1024, the best of both sweeps on the chip (PERF.md, PR 25 and 26)."""
+    from deeplearning_cfn_tpu.ops.pallas_attention import _clamp_block
+
+    for seq in (4096, 8192):
+        assert _clamp_block(pallas_attention.DEFAULT_BLOCK_Q, seq) == 1024
+        assert _clamp_block(pallas_attention.DEFAULT_BLOCK_K, seq) == 512
+        assert [_clamp_block(b, seq) for b in pallas_attention.BWD_DKV_BLOCKS] == [1024, 1024]
+        assert [_clamp_block(b, seq) for b in pallas_attention.BWD_DQ_BLOCKS] == [1024, 1024]
+
+
 def test_block_picker_balances_padding_against_block_size():
     """Effective block selection: keep the big (fast) block for aligned
     sequences, step down for ragged ones instead of paying up to 2.5x in
