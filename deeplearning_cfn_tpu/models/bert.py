@@ -15,7 +15,6 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from deeplearning_cfn_tpu.models.fused_layers import FusedDense
 from deeplearning_cfn_tpu.ops.attention import dot_product_attention
 
 
@@ -30,13 +29,6 @@ class BertConfig:
     type_vocab_size: int = 2
     dropout: float = 0.1
     dtype: Any = jnp.bfloat16
-    # Route the MLP hot block (mlp_in+gelu, mlp_out) through the fused
-    # Pallas dense kernel (ops/pallas_fused).  Parameter trees are
-    # IDENTICAL either way (same names, shapes, inits), so the flag can
-    # flip on an existing checkpoint.  Off by default; turn on where
-    # ops.pallas_fused.fused_dense_profitable says XLA loses at your
-    # (B*S, dim, mlp_dim) shape.
-    use_pallas_mlp: bool = False
 
     @classmethod
     def base(cls) -> "BertConfig":
@@ -74,15 +66,9 @@ class BertLayer(nn.Module):
         attn = nn.Dense(cfg.dim, dtype=cfg.dtype, name="attn_out")(attn)
         attn = nn.Dropout(cfg.dropout, deterministic=deterministic)(attn)
         x = nn.LayerNorm(dtype=jnp.float32, name="attn_ln")(x + attn)
-        if cfg.use_pallas_mlp:
-            mlp = FusedDense(
-                cfg.mlp_dim, activation="gelu", dtype=cfg.dtype, name="mlp_in"
-            )(x)
-            mlp = FusedDense(cfg.dim, dtype=cfg.dtype, name="mlp_out")(mlp)
-        else:
-            mlp = nn.Dense(cfg.mlp_dim, dtype=cfg.dtype, name="mlp_in")(x)
-            mlp = nn.gelu(mlp)
-            mlp = nn.Dense(cfg.dim, dtype=cfg.dtype, name="mlp_out")(mlp)
+        mlp = nn.Dense(cfg.mlp_dim, dtype=cfg.dtype, name="mlp_in")(x)
+        mlp = nn.gelu(mlp)
+        mlp = nn.Dense(cfg.dim, dtype=cfg.dtype, name="mlp_out")(mlp)
         mlp = nn.Dropout(cfg.dropout, deterministic=deterministic)(mlp)
         return nn.LayerNorm(dtype=jnp.float32, name="mlp_ln")(x + mlp)
 

@@ -25,8 +25,6 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from deeplearning_cfn_tpu.models.fused_layers import FusedDense
-
 ModuleDef = Any
 
 
@@ -110,12 +108,6 @@ class ResNet(nn.Module):
     # sample, trading BN's global-batch statistics for a reduce that
     # needs no cross-batch traffic.
     norm: str = "batch"
-    # Route the classifier head's dense through the fused Pallas kernel
-    # (ops/pallas_fused).  Same parameter tree either way ("head" with
-    # kernel/bias, lecun_normal/zeros), so checkpoints transfer across
-    # the flag.  Off by default; see fused_dense_profitable for the
-    # cost_analysis-based dispatch check at a given (batch, C5, classes).
-    use_pallas_head: bool = False
 
     @nn.compact
     def __call__(self, x: jnp.ndarray, train: bool = True):
@@ -180,10 +172,7 @@ class ResNet(nn.Module):
             return features
         with jax.named_scope("head"):
             x = jnp.mean(x, axis=(1, 2))
-        if self.use_pallas_head:
-            x = FusedDense(self.num_classes, dtype=jnp.float32, name="head")(x)
-        else:
-            x = nn.Dense(self.num_classes, dtype=jnp.float32, name="head")(x)
+        x = nn.Dense(self.num_classes, dtype=jnp.float32, name="head")(x)
         return x
 
 

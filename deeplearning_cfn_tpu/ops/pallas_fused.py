@@ -37,7 +37,7 @@ dequantize materializes the full upcast weight matrix in HBM first.
 
 Layout contract: ``x [M, K]``, ``w [K, N]``, ``b [N]`` → ``[M, N]``.
 Callers with leading batch/seq axes flatten to 2D around the call
-(models/fused_layers.py FusedDense does).
+and reshape the result back.
 """
 
 from __future__ import annotations

@@ -6,6 +6,7 @@ platform, so every sharding/collective path compiles and executes exactly
 as it would over an 8-chip slice.
 """
 
+import faulthandler
 import os
 
 os.environ["JAX_PLATFORMS"] = "cpu"
@@ -22,6 +23,53 @@ if "xla_force_host_platform_device_count" not in _flags:
     ).strip()
 
 import pytest  # noqa: E402
+
+#: Seconds one test may take, set-up and tear-down included.  The slowest
+#: test takes 94 s alone; 600 leaves six times that for a loaded worker and
+#: is two fifths of the 1,470 s the whole suite is given.
+TEST_LIMIT_S = 600
+
+_real_stderr_fd = None
+
+
+def pytest_configure(config):
+    # Capture is off while plugins configure (it is already on when this
+    # file is imported), so descriptor 2 is the real stderr here.
+    global _real_stderr_fd
+    _real_stderr_fd = os.dup(2)
+
+
+@pytest.hookimpl(wrapper=True)
+def pytest_runtest_protocol(item):
+    """A limit a test stuck in native code cannot ignore.  Python runs no
+    signal handler while the main thread waits inside XLA, so an alarm or
+    an exception cannot end such a test; faulthandler's watchdog is a C
+    thread that needs no GIL.  At the limit it prints every thread's stack
+    to the real stderr and leaves the process: under xdist that is one
+    worker down and one failure that names the test, run serially it ends
+    the run.  pytest's own ``faulthandler_timeout`` stays unset: it only
+    prints, and it would cancel this timer."""
+    faulthandler.dump_traceback_later(
+        TEST_LIMIT_S, exit=True, file=_real_stderr_fd
+    )
+    try:
+        return (yield)
+    finally:
+        faulthandler.cancel_dump_traceback_later()
+
+
+@pytest.hookimpl(optionalhook=True)
+def pytest_handlecrashitem(crashitem, report, sched):
+    """xdist's ``--dist loadfile`` puts a lost worker's files back in the
+    queue with the test that took the worker down still to run, and the
+    replacement would go the same way until the restarts run out.  Strike
+    the test: it has its failure, the rest of its file still runs."""
+    workqueue = getattr(sched, "workqueue", {})
+    for scope, unit in list(workqueue.items()):
+        if crashitem in unit:
+            unit[crashitem] = True
+            if all(unit.values()):
+                del workqueue[scope]
 
 
 @pytest.fixture()

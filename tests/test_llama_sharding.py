@@ -70,15 +70,15 @@ def test_fused_qkv_matches_unfused():
     cfg = llama.LlamaConfig.tiny(vocab_size=128, seq_len=32)
     cfg_f = llama.LlamaConfig.tiny(vocab_size=128, seq_len=32, fused_qkv=True)
     params = llama.init_params(cfg, jax.random.key(0))
-    fused_layers = dict(params["layers"])
-    fused_layers["wqkv"] = jnp.concatenate(
-        [fused_layers.pop("wq"), fused_layers.pop("wk"), fused_layers.pop("wv")],
+    layers_fused = dict(params["layers"])
+    layers_fused["wqkv"] = jnp.concatenate(
+        [layers_fused.pop("wq"), layers_fused.pop("wk"), layers_fused.pop("wv")],
         axis=-1,
     )
-    fused_layers["w_gate_up"] = jnp.concatenate(
-        [fused_layers.pop("w_gate"), fused_layers.pop("w_up")], axis=-1
+    layers_fused["w_gate_up"] = jnp.concatenate(
+        [layers_fused.pop("w_gate"), layers_fused.pop("w_up")], axis=-1
     )
-    fused_params = {**params, "layers": fused_layers}
+    fused_params = {**params, "layers": layers_fused}
     # Shapes agree with a natively-initialized fused tree.
     native = jax.eval_shape(
         lambda k: llama.init_params(cfg_f, k), jax.random.key(0)

@@ -1,11 +1,10 @@
-"""Pallas fused dense: parity with the XLA reference, gradients, the
-int8-weights variant, tree quantization, profitability dispatch, and
-the flag-gated model wiring (FusedDense / BERT MLP / ResNet head).
+"""Pallas fused dense: parity with the XLA reference and with
+``nn.Dense`` at the shapes models use, gradients, the int8-weights
+variant, tree quantization and profitability dispatch.
 
 The kernel runs in the Pallas interpreter here, asked for by name
-(``interpret=True``, or ``pltpu.force_tpu_interpret_mode()`` around a
-model that calls the compiled kernel); chip_smoke.py checks the compiled
-kernel on the MXU.  The parity contract is a few ulp of the dtype, not
+(``interpret=True``); chip_smoke.py checks the compiled kernel on the
+MXU.  The parity contract is a few ulp of the dtype, not
 bit-identity: kernel and reference contract the same operands in one
 ``dot_general`` each, but the order a backend sums in is its own.
 Comparisons are against ``jax.jit(fused_dense_reference)`` — the eager
@@ -18,8 +17,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-
-from jax.experimental.pallas import tpu as pltpu
 
 from deeplearning_cfn_tpu.ops import pallas_fused
 from deeplearning_cfn_tpu.ops.pallas_fused import (
@@ -247,77 +244,42 @@ def test_profitability_returns_bool_and_bytes_formula():
     assert fused_dense_bytes(4, 8, 16, 2) == 2 * (4 * 8 + 8 * 16 + 16 + 4 * 16)
 
 
-# --- model wiring -------------------------------------------------------------
+# --- the shapes models use, against nn.Dense ------------------------------------
 
 
-@pytest.fixture
-def tpu_interpret():
-    """The models call the compiled kernel; JAX's own switch runs every
-    Mosaic pallas_call traced inside it through the TPU interpreter."""
-    with pltpu.force_tpu_interpret_mode():
-        yield
-
-
-@pytest.mark.usefixtures("tpu_interpret")
-def test_fused_dense_module_matches_nn_dense():
-    """FusedDense is checkpoint-compatible with nn.Dense: identical
-    param tree (names, shapes, dtypes, init values) and the same output
-    at f32 to a few ulp — a model can flip its use_pallas_* flag on an
-    existing checkpoint and restore in either direction."""
+@pytest.mark.parametrize(
+    "m,k,n,activation,dtype,ulps",
+    [
+        # BertConfig.tiny's MLP at 2 x 16 tokens, in BertConfig's own bfloat16.
+        pytest.param(32, 64, 128, "gelu", jnp.bfloat16, 4, id="bert_tiny_mlp_in"),
+        pytest.param(32, 128, 64, None, jnp.bfloat16, 4, id="bert_tiny_mlp_out"),
+        # ResNet-50's classifier head: pooled C5 features to 1000 classes.
+        # 2,048 float32 terms summed in two different orders: kernel and
+        # nn.Dense each sit 55-100 ulp from the float64 answer here and
+        # 36-112 ulp from each other (five seeds, 2 to 128 rows).
+        pytest.param(8, 2048, 1000, None, jnp.float32, 256, id="resnet50_head"),
+    ],
+)
+def test_forward_matches_nn_dense(m, k, n, activation, dtype, ulps):
+    """The kernel gives ``nn.Dense``'s numbers (float32 parameters cast to
+    the layer's dtype, then ``nn.gelu`` where the layer has it) on the
+    same parameters: a kernel/bias pair trained under one runs under the
+    other."""
     import flax.linen as nn
 
-    from deeplearning_cfn_tpu.models.fused_layers import FusedDense
+    x, w, b = _operands(m, k, n, jnp.float32, seed=5)
+    x = x.astype(dtype)
+    dense = nn.Dense(n, dtype=dtype)
 
-    x = jnp.asarray(np.random.default_rng(5).standard_normal((4, 32)), jnp.float32)
-    ref = nn.Dense(16)
-    fused = FusedDense(16)
-    v_ref = ref.init(jax.random.key(0), x)
-    v_fused = fused.init(jax.random.key(0), x)
-    assert jax.tree_util.tree_structure(v_ref) == jax.tree_util.tree_structure(v_fused)
-    for a, b in zip(
-        jax.tree_util.tree_leaves(v_ref), jax.tree_util.tree_leaves(v_fused)
-    ):
-        assert a.shape == b.shape and a.dtype == b.dtype
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-    out_ref = jax.jit(ref.apply)(v_ref, x)
-    out_fused = jax.jit(fused.apply)(v_ref, x)  # reference params, fused math
-    assert_within_ulps(out_fused, out_ref)
+    def layer(w, b, x):
+        out = dense.apply({"params": {"kernel": w, "bias": b}}, x)
+        return nn.gelu(out) if activation == "gelu" else out
 
-
-@pytest.mark.usefixtures("tpu_interpret")
-def test_bert_pallas_mlp_flag_is_a_numerical_noop():
-    import dataclasses
-
-    from deeplearning_cfn_tpu.models.bert import BertConfig, BertEncoder
-
-    rng = np.random.default_rng(6)
-    tok = jnp.asarray(rng.integers(0, 64, (2, 16)), jnp.int32)
-    cfg = BertConfig.tiny(vocab_size=64, seq_len=16)
-    off = BertEncoder(cfg)
-    on = BertEncoder(dataclasses.replace(cfg, use_pallas_mlp=True))
-    v = off.init(jax.random.key(0), tok)
-    assert jax.tree_util.tree_structure(v) == jax.tree_util.tree_structure(
-        on.init(jax.random.key(0), tok)
-    )
-    out_off = jax.jit(off.apply)(v, tok)
-    out_on = jax.jit(on.apply)(v, tok)
-    # bf16 activations through two layers: a few ulp of bf16.
-    assert_within_ulps(out_on, out_off, ulps=8)
-
-
-@pytest.mark.usefixtures("tpu_interpret")
-def test_resnet_pallas_head_flag_is_a_numerical_noop():
-    from deeplearning_cfn_tpu.models.resnet import ResNet
-
-    rng = np.random.default_rng(7)
-    x = jnp.asarray(rng.standard_normal((2, 32, 32, 3)), jnp.float32)
-    kwargs = dict(stage_sizes=(1,), num_filters=8, num_classes=4)
-    off = ResNet(**kwargs)
-    on = ResNet(**kwargs, use_pallas_head=True)
-    v = off.init(jax.random.key(0), x, train=False)
-    assert jax.tree_util.tree_structure(v["params"]) == jax.tree_util.tree_structure(
-        on.init(jax.random.key(0), x, train=False)["params"]
-    )
-    out_off = jax.jit(lambda v, x: off.apply(v, x, train=False))(v, x)
-    out_on = jax.jit(lambda v, x: on.apply(v, x, train=False))(v, x)
-    assert_within_ulps(out_on, out_off)
+    want = jax.jit(layer)(w, b, x)
+    got = jax.jit(
+        lambda w, b, x: fused_dense(
+            x, w.astype(dtype), b.astype(dtype), activation=activation
+        )
+    )(w, b, x)
+    assert got.dtype == want.dtype == dtype
+    assert_within_ulps(got, want, ulps=ulps)
