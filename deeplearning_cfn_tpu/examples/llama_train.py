@@ -75,9 +75,6 @@ def main(argv: list[str] | None = None) -> dict:
     p.add_argument("--tp", type=int, default=1)
     p.add_argument("--sp", type=int, default=1)
     p.add_argument("--ring_attention", action="store_true")
-    p.add_argument("--fused_qkv", action="store_true",
-                   help="fuse q/k/v and gate/up projections into single "
-                        "wider matmuls (measured lever, BENCH_NOTES r4)")
     p.add_argument("--pp", type=int, default=1, help="pipeline stages (GPipe)")
     p.add_argument("--pp_microbatches", type=int, default=0)
     p.add_argument("--experts", type=int, default=0, help="MoE experts (0 = dense)")
@@ -98,8 +95,6 @@ def main(argv: list[str] | None = None) -> dict:
     cfg = size_config(args.size, args.seq_len)
     if args.ring_attention:
         cfg = dataclasses.replace(cfg, use_ring_attention=True)
-    if args.fused_qkv:
-        cfg = dataclasses.replace(cfg, fused_qkv=True)
     if args.experts:
         cfg = dataclasses.replace(cfg, n_experts=args.experts)
     if pp > 1:

@@ -25,7 +25,7 @@ from __future__ import annotations
 
 from dataclasses import dataclass
 from functools import partial
-from typing import Any
+from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
@@ -76,15 +76,6 @@ class LlamaConfig:
     moe_top_k: int = 2
     moe_capacity_factor: float = 1.25
     moe_aux_weight: float = 0.01
-    # Fuse the q/k/v projections into one [d, (H+2*KV)*hd] matmul and the
-    # MLP gate/up into one [d, 2*mlp_dim] matmul: fewer, wider MXU passes
-    # and one HBM read of h per pair instead of two/three.  Measured
-    # on-chip at the 435M bench shape before being kept (BENCH_NOTES) —
-    # the round-3 deferral recorded it as an unmeasured estimate.  With
-    # tp > 1 the fused output axis shards across q/k/v (or gate/up)
-    # boundaries, which is still correct under GSPMD but may reshard at
-    # the split; the import/decode paths keep the unfused layout.
-    fused_qkv: bool = False
     # Pipeline parallelism (parallel/pipeline.py): pp_stages > 1 splits the
     # decoder stack into stages sharded over the ``pp`` mesh axis and runs a
     # GPipe microbatch schedule.  n_layers must divide evenly; ring
@@ -240,17 +231,12 @@ def init_params(cfg: LlamaConfig, rng: jax.Array) -> dict:
 
     layers: dict = {
         "attn_norm": jnp.ones((L, d), jnp.float32),
+        "wq": dense_init(keys[1], (L, d, cfg.n_heads * hd), d),
+        "wk": dense_init(keys[2], (L, d, cfg.n_kv_heads * hd), d),
+        "wv": dense_init(keys[3], (L, d, cfg.n_kv_heads * hd), d),
         "wo": dense_init(keys[4], (L, cfg.n_heads * hd, d), cfg.n_heads * hd),
         "mlp_norm": jnp.ones((L, d), jnp.float32),
     }
-    if cfg.fused_qkv:
-        layers["wqkv"] = dense_init(
-            keys[1], (L, d, (cfg.n_heads + 2 * cfg.n_kv_heads) * hd), d
-        )
-    else:
-        layers["wq"] = dense_init(keys[1], (L, d, cfg.n_heads * hd), d)
-        layers["wk"] = dense_init(keys[2], (L, d, cfg.n_kv_heads * hd), d)
-        layers["wv"] = dense_init(keys[3], (L, d, cfg.n_kv_heads * hd), d)
     if cfg.moe is not None:
         from deeplearning_cfn_tpu.ops.moe import init_moe_params
 
@@ -261,9 +247,6 @@ def init_params(cfg: LlamaConfig, rng: jax.Array) -> dict:
         layers["moe"] = jax.tree_util.tree_map(
             lambda *xs: jnp.stack(xs), *stacked
         )
-    elif cfg.fused_qkv:
-        layers["w_gate_up"] = dense_init(keys[5], (L, d, 2 * cfg.mlp_dim), d)
-        layers["w_down"] = dense_init(keys[7], (L, cfg.mlp_dim, d), cfg.mlp_dim)
     else:
         layers["w_gate"] = dense_init(keys[5], (L, d, cfg.mlp_dim), d)
         layers["w_up"] = dense_init(keys[6], (L, d, cfg.mlp_dim), d)
@@ -289,15 +272,12 @@ def param_specs(cfg: LlamaConfig) -> dict:
     stacking) is never sharded."""
     layers: dict = {
         "attn_norm": P(None, None),
+        "wq": P(None, "fsdp", "tp"),
+        "wk": P(None, "fsdp", "tp"),
+        "wv": P(None, "fsdp", "tp"),
         "wo": P(None, "tp", "fsdp"),
         "mlp_norm": P(None, None),
     }
-    if cfg.fused_qkv:
-        layers["wqkv"] = P(None, "fsdp", "tp")
-    else:
-        layers["wq"] = P(None, "fsdp", "tp")
-        layers["wk"] = P(None, "fsdp", "tp")
-        layers["wv"] = P(None, "fsdp", "tp")
     if cfg.moe is not None:
         from deeplearning_cfn_tpu.ops.moe import moe_param_specs
 
@@ -307,9 +287,6 @@ def param_specs(cfg: LlamaConfig) -> dict:
             moe_param_specs(),
             is_leaf=lambda x: isinstance(x, P),
         )
-    elif cfg.fused_qkv:
-        layers["w_gate_up"] = P(None, "fsdp", "tp")
-        layers["w_down"] = P(None, "tp", "fsdp")
     else:
         layers["w_gate"] = P(None, "fsdp", "tp")
         layers["w_up"] = P(None, "fsdp", "tp")
@@ -419,15 +396,26 @@ def swiglu(h: jax.Array, w_gate: jax.Array, w_up: jax.Array, w_down: jax.Array) 
     return (gate * (h @ w_up)) @ w_down
 
 
-def _block(
+def decoder_block(
     cfg: LlamaConfig,
-    mesh: Mesh | None,
+    kv_context: Callable[[jax.Array, jax.Array, jax.Array], tuple[jax.Array, Any]],
     x: jax.Array,
     lp: dict,
     positions: jax.Array,
-) -> tuple[jax.Array, jax.Array]:
-    """One decoder block: (x, aux_loss) — aux is the MoE load-balancing
-    loss, 0 for dense models."""
+) -> tuple[jax.Array, jax.Array, Any]:
+    """One decoder block of the Llama family, the only one: the trainer
+    (`forward_with_aux`), the cached decoder (models/llama_decode.py) and
+    the serving engine (serve/engine.py) all run this function.
+
+    They differ in where keys and values live, so that is the argument.
+    ``kv_context(q, k, v)`` gets the rotated q [B, S, H, D] and this
+    call's k, v [B, S, Hkv, D] and returns (the attention output
+    [B, S, H, D], what its owner carries on to the next call: nothing, the
+    written cache buffers, the fresh k and v).
+
+    Returns (x, aux, carried): aux is the MoE load-balancing loss, 0 for
+    dense models; carried is the context's second result, untouched.
+    """
     B, S, d = x.shape
     hd = cfg.head_dim
     # The named scopes are metadata for a profile's op names
@@ -437,21 +425,14 @@ def _block(
         h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
     with jax.named_scope("attn"):
         with jax.named_scope("qkv"):
-            if cfg.fused_qkv:
-                nq, nkv = cfg.n_heads * hd, cfg.n_kv_heads * hd
-                qkv = h @ lp["wqkv"]
-                q = qkv[..., :nq].reshape(B, S, cfg.n_heads, hd)
-                k = qkv[..., nq : nq + nkv].reshape(B, S, cfg.n_kv_heads, hd)
-                v = qkv[..., nq + nkv :].reshape(B, S, cfg.n_kv_heads, hd)
-            else:
-                q = (h @ lp["wq"]).reshape(B, S, cfg.n_heads, hd)
-                k = (h @ lp["wk"]).reshape(B, S, cfg.n_kv_heads, hd)
-                v = (h @ lp["wv"]).reshape(B, S, cfg.n_kv_heads, hd)
+            q = (h @ lp["wq"]).reshape(B, S, cfg.n_heads, hd)
+            k = (h @ lp["wk"]).reshape(B, S, cfg.n_kv_heads, hd)
+            v = (h @ lp["wv"]).reshape(B, S, cfg.n_kv_heads, hd)
         with jax.named_scope("rope"):
             q = rotary_embedding(q, positions, cfg.rope_theta)
             k = rotary_embedding(k, positions, cfg.rope_theta)
         with jax.named_scope("core"):
-            attn = attend(attention_kind(cfg, mesh, S), q, k, v, mesh)
+            attn, carried = kv_context(q, k, v)
         with jax.named_scope("out"):
             x = x + attn.reshape(B, S, cfg.n_heads * hd) @ lp["wo"]
     with jax.named_scope("mlp_norm"):
@@ -461,22 +442,32 @@ def _block(
             from deeplearning_cfn_tpu.ops.moe import moe_mlp
 
             y, aux = moe_mlp(cfg.moe, lp["moe"], h)
-            return x + y, aux
-        if cfg.fused_qkv:
-            gu = h @ lp["w_gate_up"]
-            gate = jax.nn.silu(
-                gu[..., : cfg.mlp_dim].astype(jnp.float32)
-            ).astype(h.dtype)
-            x = x + (gate * gu[..., cfg.mlp_dim :]) @ lp["w_down"]
-        else:
-            x = x + swiglu(h, lp["w_gate"], lp["w_up"], lp["w_down"])
-    return x, jnp.zeros((), jnp.float32)
+            return x + y, aux, carried
+        x = x + swiglu(h, lp["w_gate"], lp["w_up"], lp["w_down"])
+    return x, jnp.zeros((), jnp.float32), carried
+
+
+def head_logits(cfg: LlamaConfig, params: dict, x: jax.Array) -> jax.Array:
+    """The final norm and the head on x [..., d]: logits in the COMPUTE
+    dtype.  Materializing the [B, S, V] f32 copy here cost ~1 GB of HBM
+    writes per pass at the 435M bench shape and dominated the out-of-scan
+    step time (round-3 trace, docs/BENCH_NOTES.md).  Consumers that reduce
+    over the vocab convert inside their reductions (exact: bf16 -> f32 is
+    lossless), so loss numerics are identical to an f32 materialization;
+    the decode and serve programs cast what they sample from."""
+    with jax.named_scope("final_norm"):
+        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+    with jax.named_scope("head"):
+        if cfg.tied_embeddings:
+            return x @ params["embed"].astype(cfg.dtype).T
+        return x @ params["output"]
 
 
 def forward_with_aux(
     cfg: LlamaConfig, params: dict, tokens: jax.Array, mesh: Mesh | None = None
 ) -> tuple[jax.Array, jax.Array]:
-    """tokens [B, S] int32 -> (logits [B, S, V] f32, aux_loss scalar).
+    """tokens [B, S] int32 -> (logits [B, S, V] in the compute dtype,
+    aux_loss scalar).
 
     aux_loss is the summed MoE load-balancing loss over layers (0 for dense
     configs) — added to the training objective, excluded from perplexity.
@@ -497,7 +488,12 @@ def forward_with_aux(
         x = _maybe_shard(x, P(("dp", "fsdp"), "sp", None))
     positions = jnp.arange(S, dtype=jnp.int32)
 
-    block = partial(_block, cfg, mesh)
+    def own_batch(q, k, v):
+        # The training context: keys and values are the batch's own, and
+        # nothing is carried from one call to the next.
+        return attend(attention_kind(cfg, mesh, S), q, k, v, mesh), None
+
+    block = partial(decoder_block, cfg, own_batch)
     if cfg.remat:
         policy = (
             jax.checkpoint_policies.dots_with_no_batch_dims_saveable
@@ -508,7 +504,7 @@ def forward_with_aux(
 
     def scan_body(carry, lp):
         x, aux_sum = carry
-        x, aux = block(x, lp, positions)
+        x, aux, _ = block(x, lp, positions)
         return (x, aux_sum + aux), None
 
     if cfg.pp_stages > 1 and mesh is not None and mesh.shape.get("pp", 1) > 1:
@@ -539,20 +535,7 @@ def forward_with_aux(
         (x, aux_sum), _ = jax.lax.scan(
             scan_body, (x, jnp.zeros((), jnp.float32)), layer_tree
         )
-    with jax.named_scope("final_norm"):
-        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    with jax.named_scope("head"):
-        if cfg.tied_embeddings:
-            logits = x @ params["embed"].astype(cfg.dtype).T
-        else:
-            logits = x @ params["output"]
-    # Logits stay in the COMPUTE dtype: materializing the [B, S, V] f32
-    # copy here cost ~1 GB of HBM writes per pass at the 435M bench shape
-    # and dominated the out-of-scan step time (round-3 trace,
-    # docs/BENCH_NOTES.md).  Consumers that reduce over the vocab convert
-    # inside their reductions (exact: bf16 -> f32 is lossless), so loss
-    # numerics are identical to an f32 materialization.
-    return logits, aux_sum
+    return head_logits(cfg, params, x), aux_sum
 
 
 def forward(

@@ -12,10 +12,12 @@ for its flagship transformer.  TPU-first design:
   with a single batched forward, then ``lax.scan`` runs the decode steps
   (sample -> embed -> one-token forward -> cache update) with the cache as
   carry.  Python never touches the loop.
-- **Scan over layers with cache carry**: the decode-step block reuses the
-  training weights (scan-stacked [L, ...]) and scans the layer axis with
-  the per-layer cache slice, so parameter layout is identical between
-  training and inference — a checkpoint restores straight into serving.
+- **Scan over layers with cache carry**: the decode step runs the
+  trainer's own block (``llama.decoder_block``) over the training weights
+  (scan-stacked [L, ...]), handing it a KV context that writes and reads
+  the per-layer cache slice, so parameter layout and block math are
+  identical between training and inference — a checkpoint restores
+  straight into serving.
 - Greedy or temperature sampling via ``jax.random.categorical``.
 
 Pipeline checkpoints decode directly (stage-stacked layers fold back to
@@ -35,12 +37,8 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from deeplearning_cfn_tpu.models.llama import LlamaConfig
-from deeplearning_cfn_tpu.ops.attention import (
-    dot_product_attention,
-    rms_norm,
-    rotary_embedding,
-)
+from deeplearning_cfn_tpu.models.llama import LlamaConfig, decoder_block, head_logits
+from deeplearning_cfn_tpu.ops.attention import dot_product_attention
 
 
 @jax.tree_util.register_dataclass
@@ -107,35 +105,6 @@ def _attend_cached(
     )
 
 
-def _block_cached(cfg, x, lp, lk, lv, positions, valid_len, offset):
-    """One decoder block over cached K/V.  Returns (x, new_lk, new_lv).
-
-    Mirrors llama._block (same weights, same math) with the attention
-    context coming from the cache buffer instead of the current batch.
-    """
-    B, S, d = x.shape
-    hd = cfg.head_dim
-    h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-    q = (h @ lp["wq"]).reshape(B, S, cfg.n_heads, hd)
-    k = (h @ lp["wk"]).reshape(B, S, cfg.n_kv_heads, hd)
-    v = (h @ lp["wv"]).reshape(B, S, cfg.n_kv_heads, hd)
-    q = rotary_embedding(q, positions, cfg.rope_theta)
-    k = rotary_embedding(k, positions, cfg.rope_theta)
-    lk = jax.lax.dynamic_update_slice(lk, k.astype(lk.dtype), (0, offset, 0, 0))
-    lv = jax.lax.dynamic_update_slice(lv, v.astype(lv.dtype), (0, offset, 0, 0))
-    attn = _attend_cached(q, lk, lv, valid_len, offset)
-    x = x + attn.reshape(B, S, cfg.n_heads * hd) @ lp["wo"]
-    h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
-    if cfg.moe is not None:
-        from deeplearning_cfn_tpu.ops.moe import moe_mlp
-
-        y, _aux = moe_mlp(cfg.moe, lp["moe"], h)
-        return x + y, lk, lv
-    gate = jax.nn.silu((h @ lp["w_gate"]).astype(jnp.float32)).astype(h.dtype)
-    x = x + (gate * (h @ lp["w_up"])) @ lp["w_down"]
-    return x, lk, lv
-
-
 def _forward_cached(
     cfg: LlamaConfig,
     params: dict,
@@ -153,15 +122,20 @@ def _forward_cached(
 
     def scan_body(x, layer):
         lp, lk, lv = layer
-        x, lk, lv = _block_cached(cfg, x, lp, lk, lv, positions, valid_len, offset)
-        return x, (lk, lv)
+
+        def through_cache(q, k, v):
+            # The decoder's context: this call's k and v are written into
+            # the layer's buffer at `offset` first, so each token attends
+            # to itself through the cache, and the buffers are carried on.
+            new_k = jax.lax.dynamic_update_slice(lk, k.astype(lk.dtype), (0, offset, 0, 0))
+            new_v = jax.lax.dynamic_update_slice(lv, v.astype(lv.dtype), (0, offset, 0, 0))
+            return _attend_cached(q, new_k, new_v, valid_len, offset), (new_k, new_v)
+
+        x, _aux, written = decoder_block(cfg, through_cache, x, lp, positions)
+        return x, written
 
     x, (new_k, new_v) = jax.lax.scan(scan_body, x, (layers, cache.k, cache.v))
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    if cfg.tied_embeddings:
-        logits = x @ params["embed"].astype(cfg.dtype).T
-    else:
-        logits = x @ params["output"]
+    logits = head_logits(cfg, params, x)
     return logits.astype(jnp.float32), KVCache(k=new_k, v=new_v)
 
 

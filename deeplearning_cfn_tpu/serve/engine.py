@@ -18,11 +18,15 @@ Two jitted step functions and one host-side scheduler:
   against an injectable clock so the soak and chaos harnesses run on
   virtual time.
 
-The decode math deliberately mirrors ``models/llama_decode`` op for op
-(same rms_norm/rotary/attention calls, same write-then-attend cache
-order, same ``sample_token``): with a pool shaped so the gathered
-context equals `generate`'s ``max_seq``, greedy outputs are bit-identical
-to the whole-generation ``lax.scan`` path (tests/test_serve.py parity).
+Every layer these programs run is ``models.llama.decoder_block``, the
+block the trainer and ``models/llama_decode`` run, and the tail is
+``llama.head_logits`` and ``llama_decode.sample_token``.  The engine's own
+part is the block's KV context (:func:`_through_pool`): scatter the new
+tokens' K/V to their pages, gather the slot's pages back, mask by position
+and length, in ``llama_decode``'s write-then-attend order.  With a pool
+shaped so the gathered context equals `generate`'s ``max_seq``, greedy
+outputs are bit-identical to the whole-generation ``lax.scan`` path
+(tests/test_serve.py parity).
 
 Prefill/decode disaggregation (where the topology allows — see
 serve/placement.py): :func:`prefill_kv` computes a prompt's K/V on a
@@ -45,13 +49,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from deeplearning_cfn_tpu.models.llama import LlamaConfig
+from deeplearning_cfn_tpu.models.llama import LlamaConfig, decoder_block, head_logits
 from deeplearning_cfn_tpu.models.llama_decode import _flat_layers, sample_token
-from deeplearning_cfn_tpu.ops.attention import (
-    dot_product_attention,
-    rms_norm,
-    rotary_embedding,
-)
+from deeplearning_cfn_tpu.ops.attention import dot_product_attention
 from deeplearning_cfn_tpu.serve.paged_cache import (
     BlockAllocator,
     PagedKVCache,
@@ -115,58 +115,49 @@ class _Slot:
     token_times: list[float]
 
 
-def _paged_block(cfg, x, lp, lk, lv, positions, write_blk, write_off, table, qpos, valid_len):
-    """One decoder block over the paged pool.  Returns (x, lk, lv).
+def _through_pool(cfg, params, cache, x, positions, write_blk, write_off, table, qpos, valid_len):
+    """The decoder's layers over the paged pool: (x, the written pool).
 
     ``x`` is [B, T, d] (prefill: B=1, T=prefill_len; decode: B=num_slots,
-    T=1); ``lk``/``lv`` are one layer's pool [num_blocks, bs, Hkv, D];
-    ``write_blk``/``write_off`` are the flattened [B*T] scatter targets
-    (out-of-range block -> dropped write); ``table`` [B, blocks_per_slot]
-    gathers each row's contiguous context; ``qpos`` [B, T] / ``valid_len``
-    [B] drive the same causal+validity mask as ``_attend_cached``.
+    T=1).  Every layer is ``llama.decoder_block``; what the engine adds
+    is the block's KV context for a layer's pool ``lk``/``lv``
+    [num_blocks, bs, Hkv, D]: scatter this call's k and v to the flattened
+    [B*T] targets ``write_blk``/``write_off`` (an out-of-range block is a
+    dropped write), gather each row's pages by ``table``
+    [B, blocks_per_slot] into a contiguous context, and attend under the
+    causal+validity mask of ``llama_decode._attend_cached`` (``qpos``
+    [B, T], ``valid_len`` [B]).  Write-then-attend, as there: the new
+    tokens' K/V land in the pool first, so each token attends to itself
+    through the cache.
     """
-    B, T, _ = x.shape
-    hd = cfg.head_dim
-    h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-    q = (h @ lp["wq"]).reshape(B, T, cfg.n_heads, hd)
-    k = (h @ lp["wk"]).reshape(B, T, cfg.n_kv_heads, hd)
-    v = (h @ lp["wv"]).reshape(B, T, cfg.n_kv_heads, hd)
-    q = rotary_embedding(q, positions, cfg.rope_theta)
-    k = rotary_embedding(k, positions, cfg.rope_theta)
-    # Write-then-attend, mirroring _block_cached: the new tokens' K/V land
-    # in the pool first, so each token attends to itself through the cache.
-    lk = lk.at[write_blk, write_off].set(
-        k.astype(lk.dtype).reshape(B * T, cfg.n_kv_heads, hd), mode="drop"
-    )
-    lv = lv.at[write_blk, write_off].set(
-        v.astype(lv.dtype).reshape(B * T, cfg.n_kv_heads, hd), mode="drop"
-    )
-    ctx_k = lk[table].reshape(B, -1, cfg.n_kv_heads, hd)  # [B, max_ctx, Hkv, D]
-    ctx_v = lv[table].reshape(B, -1, cfg.n_kv_heads, hd)
-    kpos = jnp.arange(ctx_k.shape[1])
-    mask = (kpos[None, None, :] <= qpos[:, :, None]) & (
-        kpos[None, None, :] < valid_len[:, None, None]
-    )
-    attn = dot_product_attention(q, ctx_k, ctx_v, causal=False, mask=mask[:, None])
-    x = x + attn.reshape(B, T, cfg.n_heads * hd) @ lp["wo"]
-    h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
-    if cfg.moe is not None:
-        from deeplearning_cfn_tpu.ops.moe import moe_mlp
 
-        y, _aux = moe_mlp(cfg.moe, lp["moe"], h)
-        return x + y, lk, lv
-    gate = jax.nn.silu((h @ lp["w_gate"]).astype(jnp.float32)).astype(h.dtype)
-    x = x + (gate * (h @ lp["w_up"])) @ lp["w_down"]
-    return x, lk, lv
+    def scan_body(x, layer):
+        lp, lk, lv = layer
 
+        def pool(q, k, v):
+            B, T, n_kv, hd = k.shape
+            new_k = lk.at[write_blk, write_off].set(
+                k.astype(lk.dtype).reshape(B * T, n_kv, hd), mode="drop"
+            )
+            new_v = lv.at[write_blk, write_off].set(
+                v.astype(lv.dtype).reshape(B * T, n_kv, hd), mode="drop"
+            )
+            ctx_k = new_k[table].reshape(B, -1, n_kv, hd)  # [B, max_ctx, Hkv, D]
+            ctx_v = new_v[table].reshape(B, -1, n_kv, hd)
+            kpos = jnp.arange(ctx_k.shape[1])
+            mask = (kpos[None, None, :] <= qpos[:, :, None]) & (
+                kpos[None, None, :] < valid_len[:, None, None]
+            )
+            attn = dot_product_attention(q, ctx_k, ctx_v, causal=False, mask=mask[:, None])
+            return attn, (new_k, new_v)
 
-def _logits(cfg, params, x):
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    if cfg.tied_embeddings:
-        logits = x @ params["embed"].astype(cfg.dtype).T
-    else:
-        logits = x @ params["output"]
-    return logits.astype(jnp.float32)
+        x, _aux, written = decoder_block(cfg, pool, x, lp, positions)
+        return x, written
+
+    x, (new_k, new_v) = jax.lax.scan(
+        scan_body, x, (_flat_layers(cfg, params), cache.k, cache.v)
+    )
+    return x, PagedKVCache(k=new_k, v=new_v)
 
 
 @partial(jax.jit, static_argnames=("cfg", "temperature"), donate_argnums=(2,))
@@ -197,19 +188,12 @@ def paged_prefill(
     table = blocks[None, :]
     qpos = positions[None, :]
     valid_len = length[None] if length.ndim == 0 else length
-    layers = _flat_layers(cfg, params)
-
-    def scan_body(x, layer):
-        lp, lk, lv = layer
-        x, lk, lv = _paged_block(
-            cfg, x, lp, lk, lv, positions, write_blk, write_off, table, qpos, valid_len
-        )
-        return x, (lk, lv)
-
-    x, (new_k, new_v) = jax.lax.scan(scan_body, x, (layers, cache.k, cache.v))
-    logits = _logits(cfg, params, x)  # [1, S, V]
+    x, cache = _through_pool(
+        cfg, params, cache, x, positions, write_blk, write_off, table, qpos, valid_len
+    )
+    logits = head_logits(cfg, params, x).astype(jnp.float32)  # [1, S, V]
     first = sample_token(logits[0, length - 1], key, temperature)
-    return first, PagedKVCache(k=new_k, v=new_v)
+    return first, cache
 
 
 @partial(jax.jit, static_argnames=("cfg", "temperature"), donate_argnums=(2,))
@@ -243,19 +227,12 @@ def paged_decode_step(
     write_off = lengths % bs
     qpos = positions
     valid_len = lengths + 1
-    layers = _flat_layers(cfg, params)
-
-    def scan_body(x, layer):
-        lp, lk, lv = layer
-        x, lk, lv = _paged_block(
-            cfg, x, lp, lk, lv, positions, write_blk, write_off, tables, qpos, valid_len
-        )
-        return x, (lk, lv)
-
-    x, (new_k, new_v) = jax.lax.scan(scan_body, x, (layers, cache.k, cache.v))
-    logits = _logits(cfg, params, x)  # [S, 1, V]
+    x, cache = _through_pool(
+        cfg, params, cache, x, positions, write_blk, write_off, tables, qpos, valid_len
+    )
+    logits = head_logits(cfg, params, x).astype(jnp.float32)  # [S, 1, V]
     nxt = sample_token(logits[:, 0], key, temperature)
-    return nxt, PagedKVCache(k=new_k, v=new_v)
+    return nxt, cache
 
 
 @partial(jax.jit, static_argnames=("cfg", "temperature"))
@@ -273,35 +250,23 @@ def prefill_kv(
     ks/vs to the decode device and lands them with scatter_prompt_kv.
     """
     _, S = tokens.shape
-    hd = cfg.head_dim
     x = params["embed"].astype(cfg.dtype)[tokens]
     positions = jnp.arange(S, dtype=jnp.int32)
     kpos = jnp.arange(S)
     mask = (kpos[None, :] <= kpos[:, None]) & (kpos[None, :] < length)
     layers = _flat_layers(cfg, params)
 
-    def scan_body(x, lp):
-        B, T, _ = x.shape
-        h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-        q = (h @ lp["wq"]).reshape(B, T, cfg.n_heads, hd)
-        k = (h @ lp["wk"]).reshape(B, T, cfg.n_kv_heads, hd)
-        v = (h @ lp["wv"]).reshape(B, T, cfg.n_kv_heads, hd)
-        q = rotary_embedding(q, positions, cfg.rope_theta)
-        k = rotary_embedding(k, positions, cfg.rope_theta)
-        attn = dot_product_attention(q, k, v, causal=False, mask=mask[None, None])
-        x = x + attn.reshape(B, T, cfg.n_heads * hd) @ lp["wo"]
-        h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
-        if cfg.moe is not None:
-            from deeplearning_cfn_tpu.ops.moe import moe_mlp
+    def own_prompt(q, k, v):
+        # The prefill device's context: the prompt itself under the
+        # causal-and-length mask; the fresh k and v are what it carries out.
+        return dot_product_attention(q, k, v, causal=False, mask=mask[None, None]), (k, v)
 
-            y, _aux = moe_mlp(cfg.moe, lp["moe"], h)
-            return x + y, (k, v)
-        gate = jax.nn.silu((h @ lp["w_gate"]).astype(jnp.float32)).astype(h.dtype)
-        x = x + (gate * (h @ lp["w_up"])) @ lp["w_down"]
-        return x, (k, v)
+    def scan_body(x, lp):
+        x, _aux, fresh = decoder_block(cfg, own_prompt, x, lp, positions)
+        return x, fresh
 
     x, (ks, vs) = jax.lax.scan(scan_body, x, layers)
-    logits = _logits(cfg, params, x)
+    logits = head_logits(cfg, params, x).astype(jnp.float32)
     first = sample_token(logits[0, length - 1], key, temperature)
     return first, ks[:, 0].astype(cfg.dtype), vs[:, 0].astype(cfg.dtype)
 

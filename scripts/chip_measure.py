@@ -1,13 +1,13 @@
 """One-shot on-chip measurement: python chip_measure.py <mode> [args]
 
 Modes:
-  throughput <size> <batch> <seq> [fused|adafactor]  — warmup+timed train steps
+  throughput <size> <batch> <seq> [adafactor]        — warmup+timed train steps
   fit <size> <batch> <seq> [adafactor]               — init + 2 steps; FITS/OOM
   decode <size> <batch> <prompt_len> [new_tokens]    — serving tokens/s + MBU
 
-The optional trailing token selects the qkv-fusion variant or the
-adafactor optimizer (the memory-lean rung that admits --size 3b on the
-16 GiB chip; adamw cannot hold its moment state at that scale).
+The optional trailing token selects the adafactor optimizer (the
+memory-lean rung that admits --size 3b on the 16 GiB chip; adamw cannot
+hold its moment state at that scale).
 
 ``decode`` measures the llama_decode.generate path (prefill + lax.scan
 decode, KV cache, greedy): tokens/s and MBU — model-bandwidth
@@ -39,7 +39,6 @@ from deeplearning_cfn_tpu.train.trainer import TrainerConfig
 enable_compile_cache()
 
 mode, size, batch, seq = sys.argv[1], sys.argv[2], int(sys.argv[3]), int(sys.argv[4])
-fused = "fused" in sys.argv[5:]
 optimizer = "adafactor" if "adafactor" in sys.argv[5:] else "adamw"
 
 new_tokens = int(sys.argv[5]) if mode == "decode" and len(sys.argv) > 5 else 128
@@ -48,9 +47,6 @@ cfg = {"435m": llama.LlamaConfig.m435, "1b": llama.LlamaConfig.b1,
     # decode: seq is the PROMPT length; the cache needs prompt + new room.
     seq_len=seq + new_tokens if mode == "decode" else seq
 )
-if fused:
-    import dataclasses
-    cfg = dataclasses.replace(cfg, fused_qkv=True)
 
 if mode == "decode":
     from deeplearning_cfn_tpu.models.llama_decode import generate
@@ -149,7 +145,7 @@ try:
     )
     print(json.dumps(json_safe({
         "mode": "throughput", "size": size, "batch": batch, "seq": seq,
-        "fused": fused, "optimizer": optimizer, "tokens_per_sec": round(toks, 1),
+        "optimizer": optimizer, "tokens_per_sec": round(toks, 1),
         "ms_per_step": round(1000 * dt / MEAS, 1), "mfu": mfu,
         "loss": round(loss, 3),
     }), allow_nan=False))

@@ -56,51 +56,3 @@ def test_multichip_step_compiles_without_involuntary_remat():
     assert "Involuntary full rematerialization" not in proc.stderr, (
         "GSPMD fell back to replicate-and-reshard:\n" + proc.stderr[-3000:]
     )
-
-
-def test_fused_qkv_matches_unfused():
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-
-    from deeplearning_cfn_tpu.models import llama
-    """cfg.fused_qkv packs wq|wk|wv and w_gate|w_up into single wider
-    matmuls; same weights must give identical logits (pure layout
-    change — the measured-perf lever of BENCH_NOTES round 4)."""
-    cfg = llama.LlamaConfig.tiny(vocab_size=128, seq_len=32)
-    cfg_f = llama.LlamaConfig.tiny(vocab_size=128, seq_len=32, fused_qkv=True)
-    params = llama.init_params(cfg, jax.random.key(0))
-    layers_fused = dict(params["layers"])
-    layers_fused["wqkv"] = jnp.concatenate(
-        [layers_fused.pop("wq"), layers_fused.pop("wk"), layers_fused.pop("wv")],
-        axis=-1,
-    )
-    layers_fused["w_gate_up"] = jnp.concatenate(
-        [layers_fused.pop("w_gate"), layers_fused.pop("w_up")], axis=-1
-    )
-    fused_params = {**params, "layers": layers_fused}
-    # Shapes agree with a natively-initialized fused tree.
-    native = jax.eval_shape(
-        lambda k: llama.init_params(cfg_f, k), jax.random.key(0)
-    )
-    assert jax.tree_util.tree_map(lambda a: a.shape, fused_params) == (
-        jax.tree_util.tree_map(lambda a: a.shape, native)
-    )
-    tokens = jax.random.randint(jax.random.key(1), (2, 32), 0, 128)
-    ref = llama.forward(cfg, params, tokens)
-    got = llama.forward(cfg_f, fused_params, tokens)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(ref), atol=2e-2)
-
-
-def test_fused_qkv_param_specs_cover_tree():
-    import jax
-
-    from deeplearning_cfn_tpu.models import llama
-
-    cfg = llama.LlamaConfig.tiny(fused_qkv=True)
-    params = jax.eval_shape(
-        lambda k: llama.init_params(cfg, k), jax.random.key(0)
-    )
-    specs = llama.param_specs(cfg)
-    # Same tree structure: every fused param has a spec.
-    jax.tree_util.tree_map(lambda p, s: None, params, specs)

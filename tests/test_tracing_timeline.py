@@ -307,6 +307,51 @@ def test_the_lowered_decoder_step_carries_the_steps_and_the_blocks_scopes(accum)
     assert "/attn/qkv/" in text and "/loss/" in text and "/optimizer/" in text
 
 
+def _lower_decode_or_serve_program(program: str):
+    """The decode and serve programs that run `llama.decoder_block`, each
+    lowered at a toy size."""
+    from deeplearning_cfn_tpu.models import llama, llama_decode
+    from deeplearning_cfn_tpu.serve import engine
+    from deeplearning_cfn_tpu.serve.paged_cache import init_paged_cache
+
+    cfg = llama.LlamaConfig.tiny()
+    params = llama.init_params(cfg, jax.random.key(0))
+    key = jax.random.key(1)
+    prompt = jnp.zeros((1, 8), jnp.int32)
+    length = jnp.asarray(5, jnp.int32)
+    if program == "_forward_cached":
+        cache = llama_decode.init_cache(cfg, 1, 16)
+        return jax.jit(llama_decode._forward_cached, static_argnums=0).lower(
+            cfg, params, prompt, cache, jnp.asarray(0, jnp.int32)
+        )
+    if program == "prefill_kv":
+        return engine.prefill_kv.lower(cfg, params, prompt, length, key)
+    pool = init_paged_cache(cfg, 8, 4)
+    if program == "paged_prefill":
+        return engine.paged_prefill.lower(
+            cfg, params, pool, prompt, length, jnp.arange(4, dtype=jnp.int32), key
+        )
+    assert program == "paged_decode_step"
+    return engine.paged_decode_step.lower(
+        cfg, params, pool, jnp.zeros((2,), jnp.int32), jnp.asarray([5, 3], jnp.int32),
+        jnp.zeros((2, 4), jnp.int32), jnp.asarray([True, False]), key,
+    )
+
+
+@pytest.mark.parametrize(
+    "program", ["_forward_cached", "paged_prefill", "paged_decode_step", "prefill_kv"]
+)
+def test_the_decode_and_serve_programs_carry_the_blocks_scopes(program):
+    """They run the trainer's block and tail, so a trace of the cached
+    decoder or of the engine names what a trace of the trainer names."""
+    text = _hlo_text(_lower_decode_or_serve_program(program))
+    for scope in (
+        "attn_norm", "attn", "qkv", "rope", "core", "out", "mlp_norm", "mlp", "final_norm", "head",
+    ):
+        assert _has(text, scope), scope
+    assert "attn/qkv/" in text and "attn/core/" in text
+
+
 @pytest.mark.parametrize("accum", [1, 2])
 def test_the_overlapped_gradient_path_carries_the_steps_scopes(accum):
     """The comms-overlap engine takes stateless models with at most one
