@@ -10,8 +10,13 @@ transformer flagships (BERT, Llama-3).  Design is TPU-first:
   live in VMEM scratch across kv steps and the [S, S] score matrix is never
   materialized in HBM.  Scores/softmax in f32 on the MXU via
   ``preferred_element_type``; inputs stay bf16.
-- Causal blocks that are entirely masked are skipped with ``@pl.when``
-  (compute is predicated off, the MXU never sees them).
+- The causal triangle, the same in all three kernels (forward, dk/dv, dq):
+  a (q block, kv block) pair wholly above the diagonal is neither computed
+  (``_run_pair``) nor fetched (its BlockSpec index names the block of the
+  nearest step that runs, so the pipeline has nothing to copy); a pair
+  wholly inside the triangle, with no padding in it, runs without a mask:
+  no iota, compare or select over the tile; only a pair on the diagonal or
+  over padding builds the mask (``_pair_mask``).
 - Grouped-query attention is handled in the BlockSpec index maps (a kv head
   is fetched for ``group = Hq // Hkv`` query heads) — no materialized
   ``repeat`` anywhere, forward or backward.
@@ -22,9 +27,8 @@ transformer flagships (BERT, Llama-3).  Design is TPU-first:
   ``(batch, kv_heads, kv_blocks, group * q_blocks)``, that folds the
   ``group`` query heads of a kv head into its sequential axis and writes dk
   and dv once per kv head, and a dq kernel, grid ``(batch, heads, q_blocks,
-  kv_blocks)``.  Pairs above the causal diagonal are skipped and fetch
-  nothing; pairs wholly inside it skip the mask.  Only ``delta = rowsum(out
-  * dout)`` and the layout changes around the kernels are XLA.
+  kv_blocks)``.  Only ``delta = rowsum(out * dout)`` and the layout changes
+  around the kernels are XLA.
 - Mesh-aware: pass ``mesh=`` and the kernel runs under ``shard_map`` with
   batch sharded over (dp, fsdp) and heads over tp — attention is
   independent per (batch, head), so each shard computes locally with no
@@ -52,13 +56,26 @@ from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
-# Measured on v5e (S=2048, H=8, D=64, bf16): 512x512 blocks run the
-# forward ~40% faster than 128x128 (4.7 ms vs 6.5 ms), and 1024x512 is
-# the measured best (3.78 ms — docs/BENCH_NOTES.md block sweep), so it is
-# the default.  Small-S inputs clamp down to the sequence length, so
-# large defaults cost nothing for short sequences.
+# The forward kernel's (q block, kv block), from a sweep on v5e at both decoder
+# cells' shapes (scripts/chip_grouped_matmul_sweep.py attention, PR 29; B 2,
+# bf16, causal; ms a call, kv block 512 / 1024 / 2048):
+#   S 4096, 32/8 heads of 128     S 8192, 20/20 heads of 256
+#   q  512: 5.39  3.49  3.75      14.64  12.17  13.12
+#   q 1024: 5.15  3.00  3.68      12.39  11.00  12.44
+#   q 2048: 5.44  3.52  (VMEM)    12.65  11.59  (VMEM)
+# Both shapes want 1024x1024 (the next best is 16% and 5% behind), so the head
+# size is not an input of the choice.  A kv block of 512 is slower here than it
+# was under the kernel that masked every pair (4.65 and 14.00 at 1024x512).
+# Small-S inputs clamp down to the sequence length (`_clamp_block`), so large
+# defaults cost nothing for short sequences.
 DEFAULT_BLOCK_Q = 1024
-DEFAULT_BLOCK_K = 512
+DEFAULT_BLOCK_K = 1024
+# Float32 [1024, 1024] score tiles do not fit the default scoped limit
+# (16 MiB) at heads of 256; 32 MiB holds them up to heads of 512.  Not the
+# backward's 64 MiB: under it the compiler schedules this kernel 5% slower at
+# heads of 128 (3.17 against 3.00 ms a call at S 4096; 24 and 32 MiB read the
+# same, 48 and 100 MiB 3.08), and no differently at heads of 256.
+_FWD_VMEM_LIMIT = 32 * 1024 * 1024
 # The backward kernels' (q block, kv block), from a sweep on v5e at the
 # decoder cell's shape (B 2, S 4096, 32/8 heads of 128, bf16;
 # scripts/chip_attention_backward_sweep.py, PR 25): 1024x1024 runs the dk/dv
@@ -75,6 +92,9 @@ _BWD_VMEM_LIMIT = 64 * 1024 * 1024
 # 3.74 ms XLA vs 4.69 ms flash at S=2048 with 512 blocks; flash pulls
 # ahead from S=2048 with 1024x512 blocks and is 2x faster by S=4096).
 # Dispatchers (models/llama.py) fall back to XLA attention under this.
+# Measured against the forward kernel as it was before PR 29 (at S 2048 the
+# new one takes 0.49 ms where that took 0.70, 32/8 heads of 128); no cell
+# runs below the crossover, so it stays until one can measure a new value.
 FLASH_CROSSOVER_SEQ = 2048
 
 # Sublane tile granularity: 16 covers both f32 (8) and bf16 (16) tiles, so
@@ -84,6 +104,68 @@ _SUBLANE = 16
 
 def _round_up(x: int, m: int) -> int:
     return ((x + m - 1) // m) * m
+
+
+# --- what the three kernels share: which pairs run, which are masked -------
+#
+# Every kernel walks (q block, kv block) pairs.  A pair wholly above the
+# causal diagonal is not computed (`_run_pair`) and not fetched
+# (`_kv_index_map`, `_backward_dkv`'s `q_index`); a pair wholly inside the
+# triangle, with no padding in it, runs without a mask; only a pair on the
+# diagonal or over padding builds one (`_pair_mask`).
+
+
+def _pair_mask(rows, cols, q_axis: int, q_start, k_start, *, causal, q_len, kv_len):
+    """Validity of a [rows, cols] tile of scores whose axis ``q_axis`` runs
+    over query positions and the other over key positions: the key is real
+    (not kv padding), the query is real (not q padding: a padded row's lse
+    is 0 and its scores are, so nothing overflows, but it attends nothing),
+    and the key is not after the query."""
+    q_pos = q_start + jax.lax.broadcasted_iota(jnp.int32, (rows, cols), q_axis)
+    k_pos = k_start + jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 1 - q_axis)
+    mask = jnp.logical_and(k_pos < kv_len, q_pos < q_len)
+    if causal:
+        mask = jnp.logical_and(mask, k_pos <= q_pos)
+    return mask
+
+
+def _run_pair(pair, q_start, k_start, *, causal, block_q, block_k, q_len, kv_len):
+    """Run ``pair(masked)`` for one (q block, kv block): not at all where the
+    pair lies wholly above the causal diagonal, and with the mask only where
+    a score of it is masked, because it holds padding or (causal) its last
+    key lies after its first query.  Pairs wholly inside the causal triangle
+    skip the iotas, compares and selects."""
+    masked = jnp.logical_or(k_start + block_k > kv_len, q_start + block_q > q_len)
+    live = True
+    if causal:
+        masked = jnp.logical_or(masked, k_start + block_k - 1 > q_start)
+        live = k_start <= q_start + block_q - 1
+    pl.when(jnp.logical_and(live, masked))(lambda: pair(True))
+    pl.when(jnp.logical_and(live, jnp.logical_not(masked)))(lambda: pair(False))
+
+
+def _kv_index_map(*, causal: bool, group: int, block_q: int, block_k: int, nk: int):
+    """Index map of a k or v block [1, 1, Bk, D] on a grid (batch, query head,
+    q block, kv block), the kv head being the query head's group.  Under
+    causal a step above the diagonal names the block of the last step that
+    runs, so that nothing is fetched for it."""
+
+    def kv_index(b, h, i, j):
+        if causal:
+            j = jnp.minimum(j, jnp.minimum(((i + 1) * block_q - 1) // block_k, nk - 1))
+        return b, h // group, j, 0
+
+    return kv_index
+
+
+def _compiler_params(vmem_limit: int) -> pltpu.CompilerParams:
+    # batch, head and the held block are independent (megacore-splittable);
+    # the last grid axis walks the other operand's blocks and carries the
+    # VMEM accumulators.
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
+        vmem_limit_bytes=vmem_limit,
+    )
 
 
 def _attn_kernel(
@@ -100,12 +182,13 @@ def _attn_kernel(
     sm_scale: float,
     block_q: int,
     block_k: int,
+    q_len: int,
     kv_len: int,
     need_lse: bool,
 ):
-    qi = pl.program_id(2)
     ki = pl.program_id(3)
-    nk = pl.num_programs(3)
+    q_start = pl.program_id(2) * block_q
+    k_start = ki * block_k
 
     @pl.when(ki == 0)
     def _init():
@@ -113,19 +196,8 @@ def _attn_kernel(
         m_ref[:] = jnp.full_like(m_ref, NEG_INF)
         l_ref[:] = jnp.zeros_like(l_ref)
 
-    q_start = qi * block_q
-    k_start = ki * block_k
-    if causal:
-        # Entire block above the diagonal → skip all compute.
-        run = k_start <= q_start + block_q - 1
-    else:
-        run = qi >= 0  # always true, but traced so @pl.when is uniform
-
-    @pl.when(run)
-    def _step():
-        q = q_ref[0, 0]  # [Bq, D]
-        k = k_ref[0, 0]  # [Bk, D]
-        v = v_ref[0, 0]
+    def pair(masked: bool):
+        q, k, v = q_ref[0, 0], k_ref[0, 0], v_ref[0, 0]
         s = jax.lax.dot_general(
             q,
             k,
@@ -133,26 +205,29 @@ def _attn_kernel(
             preferred_element_type=jnp.float32,
         )  # [Bq, Bk] f32
         s = s * sm_scale
-        # Mask: causal and kv padding.
-        q_pos = q_start + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
-        k_pos = k_start + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-        mask = k_pos < kv_len
-        if causal:
-            mask = jnp.logical_and(mask, k_pos <= q_pos)
-        s = jnp.where(mask, s, NEG_INF)
+        if masked:
+            mask = _pair_mask(
+                block_q, block_k, 0, q_start, k_start,
+                causal=causal, q_len=q_len, kv_len=kv_len,
+            )
+            s = jnp.where(mask, s, NEG_INF)
 
         m_prev = m_ref[:, :1]  # [Bq, 1]
         l_prev = l_ref[:, :1]
-        m_cur = jnp.max(s, axis=1, keepdims=True)  # [Bq, 1]
-        m_new = jnp.maximum(m_prev, m_cur)
-        # Rows with no valid key yet keep m = -inf; exp(NEG_INF - NEG_INF)
-        # would be exp(0) = 1, so clamp the shift for fully-masked rows.
-        shift = jnp.where(m_new <= NEG_INF / 2, 0.0, m_new)
-        p = jnp.exp(s - shift)  # [Bq, Bk]
-        p = jnp.where(mask, p, 0.0)
-        alpha = jnp.where(
-            m_prev <= NEG_INF / 2, jnp.zeros_like(m_prev), jnp.exp(m_prev - shift)
-        )
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        if masked:
+            # Rows with no valid key yet keep m = -inf; exp(NEG_INF - NEG_INF)
+            # would be exp(0) = 1, so clamp the shift for fully-masked rows.
+            shift = jnp.where(m_new <= NEG_INF / 2, 0.0, m_new)
+            p = jnp.where(mask, jnp.exp(s - shift), 0.0)  # [Bq, Bk]
+            alpha = jnp.where(
+                m_prev <= NEG_INF / 2, jnp.zeros_like(m_prev), jnp.exp(m_prev - shift)
+            )
+        else:
+            # Every score is a real one, so m_new is, and the guards above
+            # would select what is computed here: exp(NEG_INF - m_new) is 0.
+            p = jnp.exp(s - m_new)
+            alpha = jnp.exp(m_prev - m_new)
         l_new = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
         acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
             p.astype(v.dtype),
@@ -163,7 +238,12 @@ def _attn_kernel(
         m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
         l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
 
-    @pl.when(ki == nk - 1)
+    _run_pair(
+        pair, q_start, k_start,
+        causal=causal, block_q=block_q, block_k=block_k, q_len=q_len, kv_len=kv_len,
+    )
+
+    @pl.when(ki == pl.num_programs(3) - 1)
     def _finish():
         m = m_ref[:, :1]
         l = l_ref[:, :1]
@@ -211,15 +291,20 @@ def _flash_forward(
     sq_p, sk_p = qt.shape[2], kt.shape[2]
     nq, nk = sq_p // block_q, sk_p // block_k
 
-    grid = (B, Hq, nq, nk)
     kernel = functools.partial(
         _attn_kernel,
         causal=causal,
         sm_scale=sm_scale,
         block_q=block_q,
         block_k=block_k,
+        q_len=Sq,
         kv_len=Sk,
         need_lse=need_lse,
+    )
+    q_spec = pl.BlockSpec((1, 1, block_q, D), lambda b, h, i, j: (b, h, i, 0))
+    kv_spec = pl.BlockSpec(
+        (1, 1, block_k, D),
+        _kv_index_map(causal=causal, group=group, block_q=block_q, block_k=block_k, nk=nk),
     )
     if need_lse:
         # Lane-replicated LSE ([..., 128] f32) — the TPU min-tile layout for
@@ -233,20 +318,9 @@ def _flash_forward(
         lse_shape = jax.ShapeDtypeStruct((1, 1, 8, 128), jnp.float32)
     out, lse = pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1, block_q, D), lambda b, h, i, j: (b, h, i, 0)),
-            pl.BlockSpec(
-                (1, 1, block_k, D), lambda b, h, i, j, g=group: (b, h // g, j, 0)
-            ),
-            pl.BlockSpec(
-                (1, 1, block_k, D), lambda b, h, i, j, g=group: (b, h // g, j, 0)
-            ),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, block_q, D), lambda b, h, i, j: (b, h, i, 0)),
-            lse_spec,
-        ],
+        grid=(B, Hq, nq, nk),
+        in_specs=[q_spec, kv_spec, kv_spec],
+        out_specs=[q_spec, lse_spec],
         out_shape=[
             jax.ShapeDtypeStruct((B, Hq, sq_p, D), q.dtype),
             lse_shape,
@@ -256,11 +330,7 @@ def _flash_forward(
             pltpu.VMEM((block_q, 128), jnp.float32),
             pltpu.VMEM((block_q, 128), jnp.float32),
         ],
-        compiler_params=pltpu.CompilerParams(
-            # batch/head/q blocks are independent (megacore-splittable); only
-            # the kv axis is sequential — it carries the VMEM accumulator.
-            dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
-        ),
+        compiler_params=_compiler_params(_FWD_VMEM_LIMIT),
         interpret=interpret,
         # The kernel's name in the compiled program and in a profile
         # (`_flash_forward.<n>`), said here so that it no longer hangs on
@@ -284,35 +354,6 @@ def _flash_forward(
 # q block and walks the kv blocks.  7 block matmuls where a fused kernel
 # needs 5, in exchange for no accumulation through HBM.  ``sm_scale`` on ds
 # is applied once, to the float32 accumulators, as they are written.
-
-
-def _pair_mask(rows, cols, q_axis: int, q_start, k_start, *, causal, q_len, kv_len):
-    """Validity of a [rows, cols] tile of scores whose axis ``q_axis`` runs
-    over query positions and the other over key positions: the key is real
-    (not kv padding), the query is real (not q padding: a padded row's lse
-    is 0 and its scores are, so nothing overflows, but it attends nothing),
-    and the key is not after the query."""
-    q_pos = q_start + jax.lax.broadcasted_iota(jnp.int32, (rows, cols), q_axis)
-    k_pos = k_start + jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 1 - q_axis)
-    mask = jnp.logical_and(k_pos < kv_len, q_pos < q_len)
-    if causal:
-        mask = jnp.logical_and(mask, k_pos <= q_pos)
-    return mask
-
-
-def _run_pair(pair, q_start, k_start, *, causal, block_q, block_k, q_len, kv_len):
-    """Run ``pair(masked)`` for one (q block, kv block): not at all where the
-    pair lies wholly above the causal diagonal, and with the mask only where
-    a score of it is masked, because it holds padding or (causal) its last
-    key lies after its first query.  Pairs wholly inside the causal triangle
-    skip the iotas, compares and selects."""
-    masked = jnp.logical_or(k_start + block_k > kv_len, q_start + block_q > q_len)
-    live = True
-    if causal:
-        masked = jnp.logical_or(masked, k_start + block_k - 1 > q_start)
-        live = k_start <= q_start + block_q - 1
-    pl.when(jnp.logical_and(live, masked))(lambda: pair(True))
-    pl.when(jnp.logical_and(live, jnp.logical_not(masked)))(lambda: pair(False))
 
 
 def _dkv_kernel(
@@ -450,15 +491,6 @@ def _pad_rows(x: jax.Array, block: int) -> jax.Array:
     return jnp.pad(x, ((0, 0), (0, 0), (0, (-x.shape[2]) % block)))
 
 
-def _backward_params() -> pltpu.CompilerParams:
-    # batch, head and the held block are independent; the last grid axis
-    # walks the other operand's blocks and carries the VMEM accumulators.
-    return pltpu.CompilerParams(
-        dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
-        vmem_limit_bytes=_BWD_VMEM_LIMIT,
-    )
-
-
 def _backward_dkv(q, k, v, dout, lse, delta, *, causal, sm_scale, blocks, interpret):
     """dk, dv [B, Sk, Hkv, D]: grid (B, Hkv, kv blocks, group * q blocks)."""
     bq, bk = blocks
@@ -496,7 +528,7 @@ def _backward_dkv(q, k, v, dout, lse, delta, *, causal, sm_scale, blocks, interp
             jax.ShapeDtypeStruct(vt.shape, v.dtype),
         ],
         scratch_shapes=[pltpu.VMEM((bk, D), jnp.float32)] * 2,
-        compiler_params=_backward_params(),
+        compiler_params=_compiler_params(_BWD_VMEM_LIMIT),
         interpret=interpret,
         # Not `_flash_forward...`: the benchmark's attention_roofline_share
         # finds the forward kernel by that prefix, these by `_flash_backward`.
@@ -518,18 +550,16 @@ def _backward_dq(q, k, v, dout, lse, delta, *, causal, sm_scale, blocks, interpr
     kt, vt = _heads_major(k, bk), _heads_major(v, bk)
     nq, nk = qt.shape[2] // bq, kt.shape[2] // bk
 
-    def kv_index(b, h, i, j):
-        if causal:  # as q_index above: a skipped step fetches nothing
-            j = jnp.minimum(j, jnp.minimum(((i + 1) * bq - 1) // bk, nk - 1))
-        return b, h // group, j, 0
-
     def columns(x):  # lane-replicated [B, Hq, S', 128], the forward's layout
         x = _pad_rows(x, bq)
         return jnp.broadcast_to(x[..., None], (*x.shape, 128))
 
     q_spec = pl.BlockSpec((1, 1, bq, D), lambda b, h, i, j: (b, h, i, 0))
     col_spec = pl.BlockSpec((1, 1, bq, 128), lambda b, h, i, j: (b, h, i, 0))
-    kv_spec = pl.BlockSpec((1, 1, bk, D), kv_index)
+    kv_spec = pl.BlockSpec(
+        (1, 1, bk, D),
+        _kv_index_map(causal=causal, group=group, block_q=bq, block_k=bk, nk=nk),
+    )
     dq = pl.pallas_call(
         functools.partial(
             _dq_kernel, causal=causal, sm_scale=sm_scale,
@@ -540,7 +570,7 @@ def _backward_dq(q, k, v, dout, lse, delta, *, causal, sm_scale, blocks, interpr
         out_specs=q_spec,
         out_shape=jax.ShapeDtypeStruct(qt.shape, q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
-        compiler_params=_backward_params(),
+        compiler_params=_compiler_params(_BWD_VMEM_LIMIT),
         interpret=interpret,
         name="_flash_backward_dq",
     )(qt, kt, vt, dot, columns(lse), columns(delta))

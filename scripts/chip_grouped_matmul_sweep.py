@@ -7,15 +7,18 @@ an assignment), d 2048, expert width 1536, bf16.  `GROUPED_MATMUL_TILES` in
 For each candidate (XLA's `ragged_dot`, the Pallas grouped matmul at several
 tilings) the host clock's time of one forward and backward pass of the three
 matmuls with their SwiGLU, and from a `jax.profiler` capture the device time of
-the operations that took most of it.  Then the flash attention's forward and
-backward kernels at the cell's 20 heads of 256, by tile.  Through chiprun; one
-JSON line per row.
+the operations that took most of it.  Then the flash attention's forward
+kernel by tile at both decoder cells' shapes (`DEFAULT_BLOCK_Q` /
+`DEFAULT_BLOCK_K` in `ops/pallas_attention.py` are picked from that table),
+and the backward kernels at the cell's 20 heads of 256.  Through chiprun; one
+JSON line per row.  Name a sweep to run it alone.
 
-    chiprun -- python3 scripts/chip_grouped_matmul_sweep.py
+    chiprun -- python3 scripts/chip_grouped_matmul_sweep.py [experts] [attention]
 """
 
 from __future__ import annotations
 
+import itertools
 import json
 import shutil
 import sys
@@ -34,7 +37,10 @@ TILES = (
     (512, 1024, 1024), (512, 512, 512), (256, 1024, 1024), (1024, 512, 512),
     (512, 1024, 512), (512, 512, 1024), (1024, 512, 1024), (256, 512, 512),
 )
-ATTENTION = (2, 8192, 20, 20, 256)
+# (batch, seq, q heads, kv heads, head size): the attention of
+# `mistral-7b-v0.3.train-s4096` and of `glm-4.7-flash.train-s8192`.
+ATTENTION_SHAPES = ((2, 4096, 32, 8, 128), (2, 8192, 20, 20, 256))
+FORWARD_BLOCKS = (512, 1024, 2048)  # q block and kv block, every combination
 CALLS = 5
 
 
@@ -106,22 +112,38 @@ def experts_sweep() -> None:
         print(json.dumps(row, allow_nan=False), flush=True)
 
 
-def attention_sweep() -> None:
-    import itertools
+def pair_counts(seq: int, block_q: int, block_k: int) -> dict:
+    """How many (q block, kv block) pairs of one causal (batch, head) are
+    skipped, run under the mask and run without it: `_run_pair`'s rule at a
+    sequence of whole blocks."""
+    counts = {"skipped": 0, "masked": 0, "unmasked": 0}
+    for q_start, k_start in itertools.product(
+        range(0, seq, block_q), range(0, seq, block_k)
+    ):
+        if k_start > q_start + block_q - 1:
+            counts["skipped"] += 1
+        elif k_start + block_k - 1 > q_start:
+            counts["masked"] += 1
+        else:
+            counts["unmasked"] += 1
+    return counts
 
+
+def forward_sweep(shape) -> None:
     import jax
     import jax.numpy as jnp
 
-    import chip_attention_backward_sweep as backward
     from benchmarks import trace_reduce
     from deeplearning_cfn_tpu.ops import pallas_attention as pa
 
-    B, S, H, KV, hd = ATTENTION
+    B, S, H, KV, hd = shape
     keys = jax.random.split(jax.random.key(1), 3)
     q = jax.random.normal(keys[0], (B, S, H, hd), jnp.bfloat16)
     k, v = (jax.random.normal(kk, (B, S, KV, hd), jnp.bfloat16) for kk in keys[1:])
-    for bq, bk in itertools.product((512, 1024, 2048), (512, 1024)):
-        row = {"attention_forward": list(ATTENTION), "block_q": bq, "block_k": bk}
+    best = None
+    for bq, bk in itertools.product(FORWARD_BLOCKS, repeat=2):
+        row = {"attention_forward": list(shape), "block_q": bq, "block_k": bk}
+        row.update(pair_counts(S, bq, bk))
         run = lambda: pa._flash_forward(q, k, v, True, hd**-0.5, bq, bk, False)
         try:
             jax.block_until_ready(run())
@@ -138,21 +160,32 @@ def attention_sweep() -> None:
         )
         row["forward_ms"] = 1e3 * seconds / calls if calls else None
         print(json.dumps(row, allow_nan=False), flush=True)
+        if calls and (best is None or row["forward_ms"] < best["forward_ms"]):
+            best = row
+    print(json.dumps({"best_forward": best}, allow_nan=False), flush=True)
+
+
+def attention_sweep() -> None:
+    import chip_attention_backward_sweep as backward
+
+    for shape in ATTENTION_SHAPES:
+        forward_sweep(shape)
     blocks = ((512, 512), (512, 1024), (1024, 512), (1024, 1024))
-    backward.sweep(ATTENTION, blocks)
+    backward.sweep(ATTENTION_SHAPES[1], blocks)
 
 
-def main() -> int:
+def main(argv: list[str]) -> int:
     import jax
 
+    sweeps = {"experts": experts_sweep, "attention": attention_sweep}
     if jax.devices()[0].platform != "tpu":
         print("chip_grouped_matmul_sweep: needs a TPU", file=sys.stderr)
         return 1
-    experts_sweep()
-    attention_sweep()
+    for name in argv or sweeps:
+        sweeps[name]()
     print(json.dumps({"device": jax.devices()[0].device_kind}, allow_nan=False))
     return 0
 
 
 if __name__ == "__main__":
-    raise SystemExit(main())
+    raise SystemExit(main(sys.argv[1:]))

@@ -64,6 +64,121 @@ def test_bf16_io():
     )
 
 
+def _reference_lse(q, k, causal):
+    """log-sum-exp of each query's valid scores, [B, Hq, Sq], in plain XLA."""
+    k = jnp.repeat(k, q.shape[2] // k.shape[2], axis=2)
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * q.shape[-1] ** -0.5
+    if causal:
+        s = jnp.where(jnp.tril(jnp.ones(s.shape[-2:], bool)), s, -jnp.inf)
+    return jax.nn.logsumexp(s, axis=-1)
+
+
+# The forward kernel (interpret mode): output and log-sum-exp against XLA.
+# Blocks of 16 over 48 positions are 3 skipped, 3 masked (the diagonal) and 3
+# unmasked pairs a (batch, head) under causal; where S is ragged the last q
+# block's pairs hold padded rows though they lie inside the triangle, and with
+# fewer keys than queries a padded kv block does.
+FORWARD_CASES = {
+    "mha": dict(hq=4, hkv=4),
+    "gqa-4-2": dict(hq=4, hkv=2),
+    "gqa-8-2": dict(hq=8, hkv=2),
+    "non-causal": dict(causal=False),
+    "ragged-50": dict(s=50),
+    "ragged-50-non-causal": dict(s=50, causal=False),
+    "wide-q-blocks": dict(s=64, block_q=32, block_k=16),
+    "wide-k-blocks": dict(s=64, block_q=16, block_k=32),
+    "more-keys-than-queries": dict(s=30, sk=50),
+    "more-queries-than-keys": dict(s=64, sk=40),
+    "no-lse": dict(need_lse=False),
+}
+
+
+@pytest.mark.parametrize("case", FORWARD_CASES)
+def test_forward_output_and_lse_match(case):
+    kw = dict(s=48, sk=None, hq=4, hkv=2, causal=True, block_q=16, block_k=16, need_lse=True)
+    kw.update(FORWARD_CASES[case])
+    q, k, v = _qkv(s=kw["s"], hq=kw["hq"], hkv=kw["hkv"])
+    if kw["sk"]:
+        _, k, v = _qkv(s=kw["sk"], hq=kw["hq"], hkv=kw["hkv"], seed=1)
+    out, lse = pallas_attention._flash_forward(
+        q, k, v, kw["causal"], q.shape[-1] ** -0.5, kw["block_q"], kw["block_k"],
+        True, need_lse=kw["need_lse"],
+    )
+    ref = dot_product_attention(q, k, v, causal=kw["causal"])
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5, rtol=2e-5)
+    if not kw["need_lse"]:
+        assert lse is None
+        return
+    assert lse.shape == (q.shape[0], q.shape[2], q.shape[1])
+    np.testing.assert_allclose(
+        np.asarray(lse), np.asarray(_reference_lse(q, k, kw["causal"])), atol=2e-5, rtol=2e-5
+    )
+
+
+def _equations(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs its equations hold."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for inner in jax.core.jaxprs_in_params(eqn.params):
+            yield from _equations(inner)
+
+
+def _named(jaxpr, primitive):
+    return [e for e in _equations(jaxpr) if e.primitive.name == primitive]
+
+
+def _forward_kernel_call(s=64, block_q=16, block_k=16, hq=4, hkv=2):
+    """The forward's `pallas_call` equation, causal, as it is traced."""
+    q, k, v = _qkv(b=1, s=s, hq=hq, hkv=hkv)
+    traced = jax.make_jaxpr(
+        lambda q, k, v: pallas_attention._flash_forward(q, k, v, True, 0.25, block_q, block_k, True)
+    )(q, k, v)
+    (call,) = _named(traced.jaxpr, "pallas_call")
+    assert call.params["name"] == "_flash_forward"
+    return call
+
+
+def test_forward_kernel_has_a_branch_that_builds_no_mask():
+    """A pair wholly inside the triangle runs both matmuls and the softmax
+    with no iota, compare or select over the tile; the masked branch keeps
+    its iotas and both selects."""
+    kernel = _forward_kernel_call().params["jaxpr"]
+    branches = [  # primitives by name, through the `jit`s `jnp.where` leaves
+        [e.primitive.name for e in _equations(branch.jaxpr)]
+        for cond in _named(kernel, "cond")
+        for branch in cond.params["branches"]
+    ]
+    pairs = [names for names in branches if "dot_general" in names]
+    assert len(pairs) == 2
+    masked, unmasked = sorted(pairs, key=lambda names: "iota" not in names)
+    assert masked.count("iota") == 2 and masked.count("dot_general") == 2
+    assert masked.count("select_n") >= 4  # s, p and the guards on shift and alpha
+    assert unmasked.count("dot_general") == 2 and unmasked.count("exp") == 2
+    assert not {"iota", "select_n", "lt", "le", "and"} & set(unmasked)
+
+
+@pytest.mark.parametrize("block_q,block_k", [(16, 16), (32, 16), (16, 32)])
+def test_a_step_above_the_diagonal_names_the_last_kv_block_that_runs(block_q, block_k):
+    """The k and v index maps of the forward's grid (batch, head, q block, kv
+    block): a step whose pair lies above the diagonal returns the block index
+    of the q block's last running pair, so the pipeline fetches nothing for
+    it; a running step returns its own; the kv head is the query head's."""
+    call = _forward_kernel_call(s=64, block_q=block_q, block_k=block_k)
+    mapping = call.params["grid_mapping"]
+    nq, nk = mapping.grid[2:]
+    assert (nq, nk) == (64 // block_q, 64 // block_k)
+    clamped = 0
+    for operand in (1, 2):  # k, v
+        index_map = mapping.block_mappings[operand].index_map_jaxpr
+        for i in range(nq):
+            last = ((i + 1) * block_q - 1) // block_k  # holds the block's last query
+            for j in range(nk):
+                got = jax.core.eval_jaxpr(index_map.jaxpr, index_map.consts, 0, 3, i, j)
+                assert [int(x) for x in got] == [0, 1, min(j, last), 0]
+                clamped += j > last
+    assert clamped > 0
+
+
 def _grads(attention, q, k, v, jit=False):
     """(dq, dk, dv) of sum(attention(q, k, v)**2) in float32."""
 
@@ -102,7 +217,7 @@ GRADIENT_CASES = {
     "ragged-50-gqa-8-2": dict(s=50, hq=8, hkv=2),
     "uneven-blocks": dict(s=64, block_q=32, block_k=16),
     "uneven-blocks-wide-k": dict(s=64, block_q=16, block_k=32),
-    "one-block": dict(s=48, block_q=1024, block_k=512),  # the defaults, clamped
+    "one-block": dict(s=48, block_q=1024, block_k=1024),  # the defaults, clamped
     "jit": dict(jit=True),
 }
 
@@ -197,15 +312,17 @@ def test_twenty_heads_of_256_forward_and_gradients(backward_blocks):
 
 def test_tiles_at_the_decoder_cells_shapes_are_the_sweeps():
     """Head size is not an input of the tile choice: at S 4096 (heads of 128)
-    and S 8192 (heads of 256) the forward runs 1024 x 512 and the backward
-    1024 x 1024, the best of both sweeps on the chip (PERF.md, PR 25 and 26)."""
+    and S 8192 (heads of 256) all three kernels run 1024 x 1024, the best of
+    the sweeps on the chip at both shapes (PERF.md, PR 25, 26 and 29)."""
     from deeplearning_cfn_tpu.ops.pallas_attention import _clamp_block
 
     for seq in (4096, 8192):
-        assert _clamp_block(pallas_attention.DEFAULT_BLOCK_Q, seq) == 1024
-        assert _clamp_block(pallas_attention.DEFAULT_BLOCK_K, seq) == 512
-        assert [_clamp_block(b, seq) for b in pallas_attention.BWD_DKV_BLOCKS] == [1024, 1024]
-        assert [_clamp_block(b, seq) for b in pallas_attention.BWD_DQ_BLOCKS] == [1024, 1024]
+        for blocks in (
+            (pallas_attention.DEFAULT_BLOCK_Q, pallas_attention.DEFAULT_BLOCK_K),
+            pallas_attention.BWD_DKV_BLOCKS,
+            pallas_attention.BWD_DQ_BLOCKS,
+        ):
+            assert [_clamp_block(b, seq) for b in blocks] == [1024, 1024]
 
 
 def test_block_picker_balances_padding_against_block_size():
