@@ -582,3 +582,75 @@ def image_batches(args, image_shape, fallback_ds, eval_mode: bool = False):
         return normalized_batches(batches(steps), mean, std, flip=False)
 
     return host_normalized
+
+
+def train_expert_stage(args, model, cfg, name: str, t_main: float) -> dict:
+    """What the routed-experts decoder examples (`mla_moe_train`,
+    `conv_attn_moe_train`) do once they have a configuration: fsdp over every
+    chip, adamw, synthetic tokens, `Trainer.fit`, and a report with the
+    process's routing counters.  `model` is the model's module
+    (`make_trainer`, `param_count`)."""
+    import jax.numpy as jnp
+
+    from deeplearning_cfn_tpu.models.llama import attention_kind
+    from deeplearning_cfn_tpu.obs.tracing import counters
+    from deeplearning_cfn_tpu.train.data import SyntheticTokenDataset
+    from deeplearning_cfn_tpu.train.trainer import TrainerConfig
+
+    maybe_init_distributed()
+
+    n = len(jax.devices())
+    mesh = build_mesh(MeshSpec.fsdp_parallel(n))
+    batch = args.global_batch_size or n
+    lr = args.learning_rate or 3e-4
+    trainer = model.make_trainer(
+        cfg,
+        mesh,
+        TrainerConfig(
+            strategy="fsdp",
+            optimizer="adamw",
+            learning_rate=lr,
+            lr_schedule=make_lr_schedule(args, lr),
+            weight_decay=args.weight_decay if args.weight_decay is not None else 0.1,
+            grad_clip_norm=1.0,
+            grad_accum_steps=args.grad_accum,
+            log_every=args.log_every,
+        ),
+    )
+    ds = SyntheticTokenDataset(seq_len=args.seq_len, vocab_size=cfg.vocab_size, batch_size=batch)
+    ckpt, _ = open_checkpointer(args)
+    sample = next(iter(ds.batches(1)))
+    state = trainer.init(jax.random.key(0), jnp.asarray(sample.x))
+    if ckpt is not None:
+        restored = ckpt.restore_latest(state)
+        if restored is not None:
+            state, _ = restored
+    logger = trainer.throughput_logger(
+        jnp.asarray(sample.x),
+        examples_per_step=batch * args.seq_len,  # tokens/sec
+        name=name,
+        sink=metrics_sink(args, name),
+        log_every=args.log_every,
+    )
+    probe = param_probe(state)
+    state, losses = trainer.fit(
+        state, ds.batches(args.steps), steps=args.steps, logger=logger, checkpointer=ckpt
+    )
+    if ckpt:
+        ckpt.save(int(state.step), state)
+        ckpt.close()
+    counted = {k: v for k, v in counters().items() if k.startswith("moe.")}
+    return {
+        "final_loss": losses[-1],
+        "steps": len(losses),
+        "mesh": {"fsdp": n},
+        "attention": attention_kind(cfg, mesh, args.seq_len),
+        "params": model.param_count(cfg),
+        "experts_held": list(cfg.routed.span),
+        # Per step: what the routed layers were sent and what they dropped (0).
+        "routing": {k: v["total"] / v["count"] for k, v in counted.items() if v["count"]},
+        "first_step_s": first_step_clock(trainer, t_main),
+        "history": logger.history,
+        **run_report(trainer, state, losses, probe),
+    }
+

@@ -201,6 +201,8 @@ class RoutedConfig:
     # auxiliary-loss-free balancing of arXiv:2408.15664): params["router_bias"].
     selection_bias: bool = False
     renormalize: bool = True  # the selected weights sum to 1 before `scale`
+    # Added to the selected weights' sum before the division.
+    renormalize_eps: float = 1e-20
     scale: float = 1.0
     # A shared expert every token passes through, of this width; 0: none.
     shared_dim: int = 0
@@ -284,7 +286,7 @@ def route(cfg: RoutedConfig, params: dict, x: jax.Array) -> tuple[jax.Array, jax
     _, experts = jax.lax.top_k(choice, cfg.top_k)
     weights = jnp.take_along_axis(scores, experts, axis=-1)
     if cfg.renormalize:
-        weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + 1e-20)
+        weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + cfg.renormalize_eps)
     return experts.astype(jnp.int32), weights * cfg.scale
 
 

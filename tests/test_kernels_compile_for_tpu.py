@@ -27,11 +27,13 @@ def one_chip():
 
 # (batch, seq, q heads, kv heads, head dim, dtype): the decoder cell's
 # attention, the latent-attention cell's (20 heads of 192 + 64 = 256, the value
-# head 256 too), the ragged case of chip_smoke.py (2100 pads to 2176 in blocks
-# of 128), GQA 16/4 at d64, and float32.
+# head 256 too), the convolution-attention cell's (32/8 heads of 64 at S 8192),
+# the ragged case of chip_smoke.py (2100 pads to 2176 in blocks of 128), GQA
+# 16/4 at d64, and float32.
 CASES = {
     "cell-s4096": (2, 4096, 32, 8, 128, jnp.bfloat16),
     "mla-cell-s8192": (2, 8192, 20, 20, 256, jnp.bfloat16),
+    "conv-attn-cell-s8192": (2, 8192, 32, 8, 64, jnp.bfloat16),
     "ragged-s2100": (1, 2100, 32, 8, 128, jnp.bfloat16),
     "d64-s2048": (1, 2048, 16, 4, 64, jnp.bfloat16),
     "float32-s2048": (1, 2048, 8, 8, 128, jnp.float32),

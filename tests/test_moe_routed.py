@@ -3,6 +3,8 @@ grouped path against a plain mask over experts, on both grouped matmuls (XLA's
 `ragged_dot` and the Pallas kernel in interpret mode), at any imbalance, and
 under the chip's share."""
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -100,23 +102,55 @@ def test_fewer_held_experts_than_choices_bound_the_buffer():
     np.testing.assert_allclose(np.asarray(y), np.asarray(dense_masked(cfg, p, x)), atol=2e-5)
 
 
-def test_the_shares_add_up_to_the_whole_layer():
-    """Four chips hold two experts each: their routed parts, with the shared
-    expert counted once, are the layer that holds all eight."""
-    whole_cfg, p, x = _layer(held=None)
+# The router's variants of the two configurations that use the layer: GLM's
+# (a shared expert, scale 1.8, the default epsilon) and LFM2's (32 experts of
+# which a chip holds 8, top 4, no shared expert, scale 1, epsilon 1e-6).
+SHARED_ROUTERS = {
+    "shared-expert-scale-1.8": dict(n_routed=8, top_k=2, scale=1.8, shared_dim=24),
+    "no-shared-expert-eps-1e-6": dict(n_routed=32, top_k=4, scale=1.0, renormalize_eps=1e-6),
+}
+
+
+@pytest.mark.parametrize("router", SHARED_ROUTERS)
+def test_the_shares_add_up_to_the_whole_layer(router):
+    """Four chips hold a quarter of the experts each: their routed parts, with
+    the shared expert (where there is one) counted once, are the layer that
+    holds all of them."""
+    variant = dict(selection_bias=True, **SHARED_ROUTERS[router])
+    n = variant["n_routed"] // 4
+    whole_cfg = RoutedConfig(held=None, **variant)
+    p = init_routed_params(whole_cfg, jax.random.key(0), D, WIDTH, jnp.float32)
+    x = jax.random.normal(jax.random.key(1), (2, 64, D), jnp.float32)
     whole, _ = routed_experts(whole_cfg, p, x, kind="xla")
     parts = 0
     for rank in range(4):
-        cfg = RoutedConfig(
-            n_routed=8, top_k=2, held=(2 * rank, 2), selection_bias=True, scale=1.8, shared_dim=24
-        )
-        share = {**p, **{n: p[n][2 * rank : 2 * rank + 2] for n in ("w_gate", "w_up", "w_down")}}
+        cfg = RoutedConfig(held=(n * rank, n), **variant)
+        share = {**p, **{k: p[k][n * rank : n * (rank + 1)] for k in ("w_gate", "w_up", "w_down")}}
         y, stats = routed_experts(cfg, share, x, kind="xla")
         parts = parts + y
         assert int(stats["dropped"]) == 0
-    xt = x.reshape(-1, D)
-    shared = _swiglu(xt, p["shared_gate"], p["shared_up"], p["shared_down"]).reshape(x.shape)
-    np.testing.assert_allclose(np.asarray(parts - 3 * shared), np.asarray(whole), atol=5e-5)
+    if whole_cfg.shared_dim:
+        xt = x.reshape(-1, D)
+        shared = _swiglu(xt, p["shared_gate"], p["shared_up"], p["shared_down"]).reshape(x.shape)
+        parts = parts - 3 * shared
+    else:
+        assert "shared_gate" not in p
+    np.testing.assert_allclose(np.asarray(parts), np.asarray(whole), atol=5e-5)
+
+
+def test_the_epsilon_is_data_and_its_default_keeps_the_program_text():
+    """The renormalisation's epsilon as a field: left out, the lowered layer
+    is letter for letter the one with 1e-20 written in (GLM's); 1e-6 (LFM2's)
+    is another program, and the weights it gives sum to just under the scale."""
+    cfg, p, x = _layer()
+    text = lambda c: jax.jit(lambda p, x: routed_experts(c, p, x, kind="xla")[0]).lower(p, x).as_text()
+    assert cfg.renormalize_eps == 1e-20
+    assert text(cfg) == text(dataclasses.replace(cfg, renormalize_eps=1e-20))
+    other = dataclasses.replace(cfg, renormalize_eps=1e-6)
+    assert text(cfg) != text(other)
+    _, weights = route(other, p, x.reshape(-1, D))
+    sums = np.asarray(jnp.sum(weights, axis=-1))
+    assert np.all(sums < 1.8) and np.all(sums > 1.8 * (1 - 1e-5))
 
 
 def test_rows_past_the_last_group_are_zero_and_pass_no_gradient():
