@@ -413,16 +413,18 @@ def _head_loss(cfg, norm, output, x, targets, ahead: int) -> jax.Array:
 def _counters(cfg: MlaMoeConfig, stats: list[dict]) -> dict:
     """The step's routing statistics over every routed block (the prediction
     module's too) as the scalars the trainer folds into `obs.tracing`
-    counters: sums of assignments, the largest and the mean load of a held
-    expert, and what was dropped."""
+    counters: sums of assignments and of the buffer's rows the layers' passes
+    ran over, the largest and the mean load of a held expert, and what was
+    dropped."""
     every = {
         k: jnp.concatenate([jnp.atleast_1d(s[k]) for s in stats])
-        for k in ("assignments", "assignments_held", "load_max", "dropped")
+        for k in ("assignments", "assignments_held", "rows_run", "load_max", "dropped")
     }
     held = jnp.sum(every["assignments_held"])
     return {
         "moe.assignments": jnp.sum(every["assignments"]),
         "moe.assignments_held": held,
+        "moe.rows_run": jnp.sum(every["rows_run"]),
         "moe.expert_load_max": jnp.max(every["load_max"]),
         "moe.expert_load_mean": held / (every["load_max"].shape[0] * cfg.routed.span[1]),
         "moe.dropped": jnp.sum(every["dropped"]),

@@ -177,9 +177,20 @@ def moe_mlp(
 # as grouped matmuls over it (each expert's rows against its own weights), and
 # the rows are weighted and summed back per token.  Every shape is static: the
 # buffer has a row for each assignment that can go to an expert held here, and
-# only the group sizes are data.  The one-hot path above stays for
-# ``LlamaConfig.n_experts`` and the serving engine until experts are spread
-# over the ``ep`` axis; a model module calls one or the other.
+# only the group sizes are data.  What depends on them is how far the work
+# goes: the assignments to held experts sort to the front, their count is on
+# the device, and the grouped matmul visits only the tiles that hold a
+# group's rows.  Beside it, backward, every pass over the buffer (the sum of
+# the two matmuls' cotangents, the SwiGLU's gradient, the weighting's with
+# its gather of the result's cotangent) runs over the row tiles that begin
+# before that count and overwrites what it reads (`_over_live_rows`): a tile
+# past them is neither read nor written.  Forward there is one whole pass
+# beside the matmuls, the SwiGLU under a row mask; the gather into the buffer
+# has no select, and the weighting rides the token-major gather back
+# (`_rows_back`), which reads a slot of every token and stays whole.  The
+# one-hot path above stays for ``LlamaConfig.n_experts`` and the serving engine
+# until experts are spread over the ``ep`` axis; a model module calls one or
+# the other.
 
 
 @dataclass(frozen=True)
@@ -290,38 +301,163 @@ def route(cfg: RoutedConfig, params: dict, x: jax.Array) -> tuple[jax.Array, jax
     return experts.astype(jnp.int32), weights * cfg.scale
 
 
+# Rows of a tile of the passes over the sorted buffer, from a sweep on v5e at
+# the expert layer's shape (scripts/chip_grouped_matmul_sweep.py routing): a
+# multiple of the grouped matmul's 512 rows, large enough that a loop's
+# iteration costs little against a tile's megabytes.
+ROW_TILE = 4096
+
+
+def _live_tiles(n_rows: int, held: jax.Array) -> tuple[int, jax.Array]:
+    """(rows of a tile, the tiles that begin before row `held`).  The tiles
+    divide the buffer, so none overlaps another."""
+    tile = n_rows if n_rows <= ROW_TILE else math.gcd(n_rows, ROW_TILE)
+    return tile, jnp.minimum((held + tile - 1) // tile, n_rows // tile).astype(jnp.int32)
+
+
+def _over_live_rows(f, held: jax.Array, tiled: tuple, whole: tuple = (), over: tuple = ()) -> tuple:
+    """``f(*tiles, *whole) -> tuple`` over row tiles of the ``tiled`` arrays
+    [R, ...], for the tiles that begin before row ``held`` and no other: the
+    loop's trip count is that number, on the device.  Rows from ``held`` on
+    come out as zeros within those tiles (the one tile at the boundary is where
+    the mask selects).  ``over[n]`` names the ``tiled`` array that result ``n``
+    overwrites, tile by tile after it is read: what that array held past the
+    live tiles stays, and nobody may read it.  A result with no such array
+    goes into zeros.  Nothing of a tile past the live ones is read, so those
+    may hold what a kernel left uninitialised."""
+    n_rows = tiled[0].shape[0]
+    tile, live_tiles = _live_tiles(n_rows, held)
+    shapes = jax.eval_shape(lambda *t: f(*t, *whole), *(a[:tile] for a in tiled))
+    over = over or (None,) * len(shapes)
+    fresh = tuple(
+        jnp.zeros((n_rows,) + s.shape[1:], s.dtype) for s, j in zip(shapes, over) if j is None
+    )
+
+    def body(i, carry):
+        tiled, fresh = list(carry[0]), list(carry[1])
+        start = i * tile
+        live = start + jnp.arange(tile) < held
+        results = f(*(jax.lax.dynamic_slice_in_dim(a, start, tile) for a in tiled), *whole)
+        n = 0
+        for r, j in zip(results, over):
+            r = jnp.where(live.reshape((tile,) + (1,) * (r.ndim - 1)), r, 0)
+            if j is None:
+                fresh[n] = jax.lax.dynamic_update_slice_in_dim(fresh[n], r, start, 0)
+                n += 1
+            else:
+                tiled[j] = jax.lax.dynamic_update_slice_in_dim(tiled[j], r, start, 0)
+        return tuple(tiled), tuple(fresh)
+
+    tiled, fresh = jax.lax.fori_loop(0, live_tiles, body, (tuple(tiled), fresh))
+    fresh = iter(fresh)
+    return tuple(next(fresh) if j is None else tiled[j] for j in over)
+
+
+def _rows_back(rows, slot, held, weight=None):
+    """[R, d] -> [T, d]: token t gets the sum of rows[slot[t, :]] over its
+    slots before row ``held``, each times ``weight[t, :]`` where given, added
+    up in float32.  A slot of every token is read, whatever the count."""
+    # [j, T]: the tokens on a tiled dimension, or the gather's result is laid out anew.
+    slot = slot.T
+    picked = rows[jnp.minimum(slot, rows.shape[0] - 1)]  # [j, T, d]
+    if weight is not None:
+        # Rounded to the rows' type as a product written to memory is: inside
+        # a fusion the compiler would keep the float32 it computes in.
+        bits = jnp.finfo(rows.dtype)
+        picked = jax.lax.reduce_precision(picked * weight.T[..., None], bits.nexp, bits.nmant)
+    picked = jnp.where((slot < held)[..., None], picked, 0)
+    return jnp.sum(picked, axis=0, dtype=jnp.float32).astype(rows.dtype)
+
+
 @jax.custom_vjp
-def _rows_out(x, token, slot):
-    """x [T, d] -> [R, d]: row r is x[token[r]].  ``slot`` [T, j] says where
-    each token's rows went (>= R: nowhere), so the transpose is a gather
-    too and no scatter runs in either pass."""
-    return x[token]
+def _rows_out(x, token, slot, held):
+    """x [T, d] -> the buffer [R, d], once for each of its two grouped
+    matmuls: row r is x[token[r]].  The gather runs whole: over counted tiles
+    into zeros it took longer on the chip than it saved (PERF.md, PR 32), and
+    the rows past ``held`` are some token's, which the matmuls do not read.
+    ``slot`` [T, j] says where each token's rows went, so the transpose is a
+    gather too and no scatter runs in either pass.  Each matmul's cotangent
+    arrives by itself because the Pallas kernel leaves it uninitialised past
+    the tiles it visits: they are added over the live tiles, not by JAX over
+    the whole buffer."""
+    rows = x[token]
+    return rows, rows
 
 
-def _rows_back(rows, token, slot):
-    """[R, d] -> [T, d]: token t gets the sum of rows[slot[t, :]], added up
-    in float32."""
-    R = rows.shape[0]
-    picked = rows[jnp.minimum(slot, R - 1)]  # [T, j, d]
-    picked = jnp.where((slot < R)[..., None], picked, 0)
-    return jnp.sum(picked, axis=1, dtype=jnp.float32).astype(rows.dtype)
+def _rows_out_bwd(res, g):
+    slot, held = res
+    (g,) = _over_live_rows(lambda a, b: (a + b,), held, g, over=(0,))
+    return _rows_back(g, slot, held), None, None, None
 
 
 _rows_out.defvjp(
-    lambda x, token, slot: (x[token], (token, slot)),
-    lambda res, g: (_rows_back(g, *res), None, None),
+    lambda x, token, slot, held: (_rows_out(x, token, slot, held), (slot, held)), _rows_out_bwd
 )
 
 
+def _swiglu_tile(gate, up):
+    return (jax.nn.silu(gate.astype(jnp.float32)).astype(gate.dtype) * up,)
+
+
 @jax.custom_vjp
-def _rows_in(rows, token, slot):
-    """The other way: [R, d] -> [T, d], each token the sum of its rows."""
-    return _rows_back(rows, token, slot)
+def _swiglu_rows(gate, up, held):
+    """silu(gate) * up, the SiLU in float32, of the rows before ``held`` of
+    two grouped matmuls' results; zeros from it on.  Forward it is one pass
+    with a row mask: a result written tile by tile into zeros is a buffer
+    more in the compiled step's temporaries (PERF.md, PR 32), which the
+    passes backward avoid by overwriting what they read."""
+    valid = (jnp.arange(gate.shape[0]) < held)[:, None]
+    return jnp.where(valid, _swiglu_tile(gate, up)[0], 0)
 
 
-_rows_in.defvjp(
-    lambda rows, token, slot: (_rows_back(rows, token, slot), token),
-    lambda token, g: (g[token], None, None),
+def _swiglu_rows_bwd(res, g):
+    gate, up, held = res
+
+    def tile(g, up, gate):
+        return jax.vjp(_swiglu_tile, gate, up)[1]((g,))
+
+    # Over the cotangent and `up`, which nothing reads after this.
+    d_gate, d_up = _over_live_rows(tile, held, (g, up, gate), over=(0, 1))
+    return d_gate, d_up, None
+
+
+_swiglu_rows.defvjp(
+    lambda gate, up, held: (_swiglu_rows(gate, up, held), (gate, up, held)), _swiglu_rows_bwd
+)
+
+
+def _weigh_tile(out, weight):
+    return (out * weight[:, None],)
+
+
+@jax.custom_vjp
+def _weighted_rows_in(out, weights, row_weight, token, slot, held):
+    """The other way: [R, d] -> [T, d], each token the sum of its rows before
+    ``held``, each row times its weight: ``weights`` [T, j] by token, and
+    ``row_weight`` [R] the same numbers in the buffer's order, which is how
+    the backward pass reads them.  Forward the product rides the token-major
+    gather; backward it runs over the live tiles."""
+    return _rows_back(out, slot, held, weights)
+
+
+def _weighted_rows_in_bwd(res, g):
+    out, row_weight, token, slot, held = res
+
+    def tile(out, weight, token, g):
+        return jax.vjp(_weigh_tile, out, weight)[1]((g[token],))
+
+    # Over `out`, which nothing reads after this.
+    d_out, d_row = _over_live_rows(tile, held, (out, row_weight, token), (g,), over=(0, None))
+    d_weights = jnp.where(slot < held, d_row[jnp.minimum(slot, d_row.shape[0] - 1)], 0)
+    return d_out, d_weights, None, None, None, None
+
+
+_weighted_rows_in.defvjp(
+    lambda out, weights, row_weight, token, slot, held: (
+        _weighted_rows_in(out, weights, row_weight, token, slot, held),
+        (out, row_weight, token, slot, held),
+    ),
+    _weighted_rows_in_bwd,
 )
 
 # (rows, contraction, columns) of the grouped matmul's tiles on the TPU, from
@@ -341,25 +477,22 @@ def grouped_matmul(
     interpret: bool = False,
 ) -> jax.Array:
     """rows [R, a] sorted by group, weights [G, a, b], group_sizes [G] ->
-    [R, b]: each group's rows times its own matrix.  Rows past the last group
-    come back as zeros."""
-    R = rows.shape[0]
-    valid = (jnp.arange(R) < jnp.sum(group_sizes))[:, None]
+    [R, b]: each group's rows times its own matrix.  The Pallas kernel visits
+    the tiles that hold a group's rows and no other: what it leaves of its
+    result past the last group's rows, and of its gradient with respect to
+    ``rows``, is uninitialised (``ragged_dot`` gives zeros there).  A caller
+    reads neither past the counted rows: ``_swiglu_rows`` and ``_rows_back``
+    select by row, ``_over_live_rows`` stops at the counted tiles."""
     if kind == "xla":
-        out = jax.lax.ragged_dot(rows, weights, group_sizes)
-    else:
-        from jax.experimental.pallas.ops.tpu.megablox import gmm
+        return jax.lax.ragged_dot(rows, weights, group_sizes)
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
 
-        tm, tk, tn = GROUPED_MATMUL_TILES
-        tiles = (min(tm, R), min(tk, weights.shape[1]), min(tn, weights.shape[2]))
-        if R % tiles[0]:
-            raise ValueError(f"{R} rows do not divide into tiles of {tiles[0]}")
-        # The kernel visits the tiles that hold a group's rows and no other:
-        # what it leaves of its output, and of its gradient with respect to
-        # `rows`, is uninitialised.  Both selects are needed.
-        rows = jnp.where(valid, rows, 0)
-        out = gmm(rows, weights, group_sizes, rows.dtype, tiles, interpret=interpret)
-    return jnp.where(valid, out, 0)
+    R = rows.shape[0]
+    tm, tk, tn = GROUPED_MATMUL_TILES
+    tiles = (min(tm, R), min(tk, weights.shape[1]), min(tn, weights.shape[2]))
+    if R % tiles[0]:
+        raise ValueError(f"{R} rows do not divide into tiles of {tiles[0]}")
+    return gmm(rows, weights, group_sizes, rows.dtype, tiles, interpret=interpret)
 
 
 def routed_experts(
@@ -370,9 +503,11 @@ def routed_experts(
 
     The result is the held experts' part of sum_i w_i E_i(x), plus the shared
     expert.  The statistics are scalars for the step's counters (assignments
-    in all and to held experts, the largest held expert's load, and `dropped`:
-    assignments to held experts less rows computed, which is 0) and
-    ``selected`` [T, k], the experts each token chose.
+    in all and to held experts, the largest held expert's load, `dropped`:
+    assignments to held experts less rows computed, which is 0, and
+    `rows_run`: the buffer's rows that the backward pass's passes beside the
+    matmuls run over, the live tiles' rows) and ``selected`` [T, k], the
+    experts each token chose.
     """
     B, S, d = x.shape
     T, k = B * S, cfg.top_k
@@ -386,35 +521,40 @@ def routed_experts(
         # others behind them under one more index.
         local = jnp.where((experts >= first) & (experts < first + count), experts - first, count)
         local = local.reshape(T * k)
-        order = jnp.argsort(local, stable=True)
+        # In the activations' type: a float32 copy of the buffer, of its
+        # gather and of both cotangents is 2 GB at 65,536 rows of 2048.
+        weights = weights.astype(x.dtype)
+        # The argsort, with the weights riding it into the buffer's order.
+        by_row = jax.lax.stop_gradient(weights).reshape(T * k)
+        _, order, row_weight = jax.lax.sort(
+            (local, jnp.arange(T * k, dtype=jnp.int32), by_row), num_keys=1, is_stable=True
+        )
         R = cfg.buffer_rows(T)
         slot = jnp.argsort(order).astype(jnp.int32).reshape(T, k)  # inverse permutation
-        order = order[:R]
+        order, row_weight = order[:R], row_weight[:R]
         token = (order // k).astype(jnp.int32)
         group_sizes = jnp.sum(
             local[:, None] == jnp.arange(count, dtype=local.dtype)[None, :], axis=0, dtype=jnp.int32
         )
-        rows = _rows_out(xt, token, slot)
+        held = jnp.sum(group_sizes)  # the rows that hold an assignment: the front of the buffer
+        rows_gate, rows_up = _rows_out(xt, token, slot, held)
     with jax.named_scope("experts"):
         mm = partial(grouped_matmul, group_sizes=group_sizes, kind=kind, interpret=interpret)
-        gate = jax.nn.silu(mm(rows, params["w_gate"]).astype(jnp.float32)).astype(x.dtype)
-        out = mm(gate * mm(rows, params["w_up"]), params["w_down"])
+        gate_up = _swiglu_rows(mm(rows_gate, params["w_gate"]), mm(rows_up, params["w_up"]), held)
+        out = mm(gate_up, params["w_down"])
     with jax.named_scope("combine"):
-        # In the activations' type: a float32 copy of the buffer, of its
-        # gather and of both cotangents is 2 GB at 65,536 rows of 2048.
-        row_weight = weights.reshape(T * k)[order].astype(x.dtype)
-        y = _rows_in(out * row_weight[:, None], token, slot)
+        y = _weighted_rows_in(out, weights, row_weight, token, slot, held)
     if cfg.shared_dim:
         with jax.named_scope("shared"):
             g = jax.nn.silu((xt @ params["shared_gate"]).astype(jnp.float32)).astype(x.dtype)
             y = y + (g * (xt @ params["shared_up"])) @ params["shared_down"]
-    held = jnp.sum(local < count, dtype=jnp.int32)  # from the selection
-    computed = jnp.sum(local[order] < count, dtype=jnp.int32)  # from the buffer
+    tile, live_tiles = _live_tiles(R, held)
     stats = {
         "assignments": jnp.asarray(T * k, jnp.int32),
-        "assignments_held": held,
+        "assignments_held": held,  # from the selection
         "load_max": jnp.max(group_sizes),
-        "dropped": held - computed,
+        "dropped": held - jnp.sum(local[order] < count, dtype=jnp.int32),  # from the buffer
+        "rows_run": live_tiles * tile,
         "selected": experts,
     }
     return y.reshape(B, S, d), stats

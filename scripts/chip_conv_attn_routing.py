@@ -83,6 +83,7 @@ def main(argv: list[str]) -> int:
                     "seed": seed, "step": step + 1, "loss": round(float(loss), 4),
                     "held_share": round(float(c["moe.assignments_held"] / c["moe.assignments"]), 4),
                     "load_max_over_mean": round(float(c["moe.expert_load_max"] / c["moe.expert_load_mean"]), 3),
+                    "rows_run_over_held": round(float(c["moe.rows_run"] / c["moe.assignments_held"]), 3),
                 }, allow_nan=False), flush=True)
         del state, rows
     return 0

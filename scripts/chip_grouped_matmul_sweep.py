@@ -10,10 +10,17 @@ matmuls with their SwiGLU, and from a `jax.profiler` capture the device time of
 the operations that took most of it.  Then the flash attention's forward
 kernel by tile at both decoder cells' shapes (`DEFAULT_BLOCK_Q` /
 `DEFAULT_BLOCK_K` in `ops/pallas_attention.py` are picked from that table),
-and the backward kernels at the cell's 20 heads of 256.  Through chiprun; one
-JSON line per row.  Name a sweep to run it alone.
+and the backward kernels at the cell's 20 heads of 256.  `routing` (run by
+name only) is one whole routed layer, `ops/moe.routed_experts`, forward and
+backward at that shape and at both cells' expert widths, by the tile of its
+passes over the sorted buffer (`ROW_TILE` in `ops/moe.py` is picked from it)
+and by the share of the assignments that go to held experts: device time by
+the layer's scopes from a capture.  In a tree whose `ops/moe.py` has no
+`ROW_TILE` (copy this file there) it measures that tree's layer, which is how
+the code before the tiles is read beside them.  Through chiprun; one JSON line
+per row.  Name a sweep to run it alone.
 
-    chiprun -- python3 scripts/chip_grouped_matmul_sweep.py [experts] [attention]
+    chiprun -- python3 scripts/chip_grouped_matmul_sweep.py [experts] [attention] [routing]
 """
 
 from __future__ import annotations
@@ -42,6 +49,13 @@ TILES = (
 ATTENTION_SHAPES = ((2, 4096, 32, 8, 128), (2, 8192, 20, 20, 256))
 FORWARD_BLOCKS = (512, 1024, 2048)  # q block and kv block, every combination
 CALLS = 5
+# The routing sweep: the expert widths of the GLM and the LFM2 cell, the tiles
+# of the passes over the buffer, and the shares of the assignments held here
+# (an expert-parallel rank of four holds a quarter on average; 1: every expert).
+ROUTING_WIDTHS = (1536, 1792)
+ROUTING_TILES = (2048, 4096, 8192, 16384)
+ROUTING_SHARES = (0.12, 0.25, 0.5, 1.0)
+ROUTING_SCOPES = ("router", "dispatch", "experts", "combine")
 
 
 def top_operations(trace_dir: str, n: int = 6) -> list:
@@ -82,9 +96,11 @@ def experts_sweep() -> None:
 
     def chain(kind):
         def loss(rows, w_gate, w_up, w_down):
+            # As `routed_experts` chains them; the kernel's rows past the
+            # last group's are uninitialised and stay out of the sum.
             mm = lambda a, w: moe.grouped_matmul(a, w, sizes, kind)
-            gate = jax.nn.silu(mm(rows, w_gate).astype(jnp.float32)).astype(rows.dtype)
-            return jnp.sum(mm(gate * mm(rows, w_up), w_down).astype(jnp.float32))
+            out = mm(moe._swiglu_rows(mm(rows, w_gate), mm(rows, w_up), held), w_down)
+            return jnp.sum(jnp.where((jnp.arange(rows_n) < held)[:, None], out, 0), dtype=jnp.float32)
 
         return jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3)))
 
@@ -110,6 +126,80 @@ def experts_sweep() -> None:
         row["top_operations_ms_and_calls"] = top_operations(trace_dir)
         shutil.rmtree(trace_dir, ignore_errors=True)
         print(json.dumps(row, allow_nan=False), flush=True)
+
+
+def routing_input(share: float):
+    """x [1, TOKENS, D] whose first N_ROUTED columns are the router's logits
+    (the router is an identity on them): noise, and per token a push towards
+    the held experts (all four choices held), none (one in four held) or away
+    from them (none held), mixed so that `share` of the assignments are held."""
+    import jax
+    import jax.numpy as jnp
+
+    keys = jax.random.split(jax.random.key(int(share * 100)), 3)
+    towards = max(0.0, (share - 0.25) / 0.75)
+    away = max(0.0, 1 - share / 0.25)
+    u = jax.random.uniform(keys[0], (TOKENS, 1))
+    push = jnp.where(u < towards, 8.0, jnp.where(u > 1 - away, -8.0, 0.0))
+    logits = jax.random.normal(keys[1], (TOKENS, N_ROUTED)) + push * (jnp.arange(N_ROUTED) < HELD)
+    x = jax.random.normal(keys[2], (TOKENS, D)).at[:, :N_ROUTED].set(logits)
+    return x.astype(jnp.bfloat16).reshape(1, TOKENS, D)
+
+
+def scope_ms(trace_dir: str) -> dict:
+    """Device milliseconds a call under each of the layer's scopes, and in all."""
+    from benchmarks import scope_reduce, trace_reduce
+
+    rows = trace_reduce.load_events(trace_dir)
+    device = trace_reduce.devices(rows)[0]
+    names = scope_reduce.load_op_names(trace_dir, device)
+    by_scope = {scope: [] for scope in ROUTING_SCOPES}
+    every = []
+    for start, end, operation in trace_reduce.op_intervals(rows, device):
+        if trace_reduce.CONTAINER.match(operation):
+            continue
+        every.append((start, end))
+        for scope in ROUTING_SCOPES:
+            if scope_reduce.has_scope(names.get(operation, ""), scope):
+                by_scope[scope].append((start, end))
+    ms = lambda spans: round(trace_reduce.total(trace_reduce.union(spans)) / 1e6 / CALLS, 3)
+    return {"all": ms(every), **{scope: ms(spans) for scope, spans in by_scope.items()}}
+
+
+def routing_sweep() -> None:
+    import jax
+    import jax.numpy as jnp
+
+    from deeplearning_cfn_tpu.ops import moe
+
+    cfg = moe.RoutedConfig(n_routed=N_ROUTED, top_k=TOP_K, held=(0, HELD))
+    inputs = {share: routing_input(share) for share in ROUTING_SHARES}
+    tiles = ROUTING_TILES if hasattr(moe, "ROW_TILE") else (None,)
+    for width, tile in itertools.product(ROUTING_WIDTHS, tiles):
+        params = moe.init_routed_params(cfg, jax.random.key(0), D, width)
+        params["router"] = jnp.eye(D, N_ROUTED, dtype=jnp.float32)
+        if tile:
+            moe.ROW_TILE = tile
+
+        def loss(params, x):
+            y, stats = moe.routed_experts(cfg, params, x)
+            stats = {k: v for k, v in stats.items() if k != "selected"}
+            return jnp.sum(y.astype(jnp.float32) ** 2), stats
+
+        run = jax.jit(jax.grad(loss, argnums=(0, 1), has_aux=True))
+        for share, x in inputs.items():
+            _, stats = jax.block_until_ready(run(params, x))
+            row = {"routing": width, "row_tile": tile, "share": share}
+            row.update({k: int(v) for k, v in stats.items()})
+            t0 = time.perf_counter()
+            jax.block_until_ready([run(params, x) for _ in range(CALLS)])
+            row["forward_backward_ms"] = round(1e3 * (time.perf_counter() - t0) / CALLS, 3)
+            trace_dir = tempfile.mkdtemp(prefix="routing_sweep_")
+            with jax.profiler.trace(trace_dir):
+                jax.block_until_ready([run(params, x) for _ in range(CALLS)])
+            row["device_ms"] = scope_ms(trace_dir)
+            shutil.rmtree(trace_dir, ignore_errors=True)
+            print(json.dumps(row, allow_nan=False), flush=True)
 
 
 def pair_counts(seq: int, block_q: int, block_k: int) -> dict:
@@ -177,11 +267,11 @@ def attention_sweep() -> None:
 def main(argv: list[str]) -> int:
     import jax
 
-    sweeps = {"experts": experts_sweep, "attention": attention_sweep}
+    sweeps = {"experts": experts_sweep, "attention": attention_sweep, "routing": routing_sweep}
     if jax.devices()[0].platform != "tpu":
         print("chip_grouped_matmul_sweep: needs a TPU", file=sys.stderr)
         return 1
-    for name in argv or sweeps:
+    for name in argv or ("experts", "attention"):
         sweeps[name]()
     print(json.dumps({"device": jax.devices()[0].device_kind}, allow_nan=False))
     return 0

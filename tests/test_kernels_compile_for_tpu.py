@@ -84,7 +84,15 @@ def test_routed_experts_layer_compiles_for_v5e_without_a_scatter(one_chip):
         loss = lambda p, x: moe.routed_experts(cfg, p, x, kind="pallas")[0].astype(jnp.float32).sum()
         return jax.grad(loss, argnums=(0, 1))(params, x)
 
-    text = jax.jit(grads).lower(params, x).compile().as_text()
+    compiled = jax.jit(grads).lower(params, x).compile()
+    text = compiled.as_text()
     assert text.count("tgmm") >= 3 and text.count("gmm") - text.count("tgmm") >= 6
+    # Each matmul once forward and once each way backward: the passes beside
+    # them (ops/moe._over_live_rows) bring no third one.
+    assert len(re.findall(r"%gmm[.\d]* = ", text)) == 6 and len(re.findall(r"%tgmm[.\d]* = ", text)) == 3
     assert "ragged-dot" not in text
     assert not re.search(r"= \w+\[\d+,\d+\]\S* scatter\(", text)
+    # The backward passes overwrite what they read: 1.36 GB of temporaries,
+    # where a result written tile by tile into zeros is a buffer more each
+    # (1.59 GB with every pass over the whole buffer, before PR 32).
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.45e9

@@ -126,13 +126,18 @@ def test_fit_trains_and_folds_the_routing_counters_at_the_log_seam():
     assert losses[-1] < losses[0]
     counted = {k: v for k, v in tracing.counters().items() if k.startswith("moe.")}
     assert set(counted) == {
-        "moe.assignments", "moe.assignments_held", "moe.expert_load_max",
+        "moe.assignments", "moe.assignments_held", "moe.rows_run", "moe.expert_load_max",
         "moe.expert_load_mean", "moe.dropped",
     }
     assert all(v["count"] == 7 for v in counted.values())  # the odd last step too
     blocks, tokens = cfg.n_routed_layers + cfg.n_predict, 4 * 32
     assert counted["moe.assignments"]["total"] == 7 * blocks * tokens * cfg.top_k
     assert 0 < counted["moe.assignments_held"]["total"] < counted["moe.assignments"]["total"]
+    # the passes over the buffer stop at the tile that holds the last held row
+    assert (
+        counted["moe.assignments_held"]["total"] <= counted["moe.rows_run"]["total"]
+        <= counted["moe.assignments"]["total"]
+    )
     assert counted["moe.dropped"]["total"] == 0
     assert counted["moe.expert_load_max"]["total"] >= counted["moe.expert_load_mean"]["total"]
     assert counted["moe.expert_load_mean"]["total"] == pytest.approx(

@@ -153,21 +153,115 @@ def test_the_epsilon_is_data_and_its_default_keeps_the_program_text():
     assert np.all(sums < 1.8) and np.all(sums > 1.8 * (1 - 1e-5))
 
 
-def test_rows_past_the_last_group_are_zero_and_pass_no_gradient():
+TILE = 32  # rows of a pass's tile in these tests: the layer's 256 rows are eight of them
+
+
+@pytest.fixture
+def row_tile(monkeypatch):
+    monkeypatch.setattr(moe, "ROW_TILE", TILE)
+
+
+def _layer_holding(held_rows: int):
+    """`_layer`'s 128 tokens and 256 rows with the router made an identity on
+    the input's first eight columns, which hold the logits: the first
+    `held_rows // 2` tokens choose two held experts, one more a held and an
+    absent one where the count is odd, the rest two absent ones."""
+    cfg, p, x = _layer()
+    held_of, absent_of = (2, 3, 4, 5), (0, 1, 6, 7)
+    logits = np.asarray(jax.random.normal(jax.random.key(2), (128, 8), jnp.float32)) * 0.3
+    for t in range(128):
+        n_held = min(2, max(0, held_rows - 2 * t))
+        chosen = [held_of[(t + i) % 4] for i in range(n_held)]
+        chosen += [absent_of[(t + i) % 4] for i in range(2 - n_held)]
+        logits[t, chosen] += 4.0
+    x = x.reshape(128, D).at[:, :8].set(jnp.asarray(logits)).reshape(x.shape)
+    p["router"] = jnp.eye(D, 8, dtype=jnp.float32)
+    return cfg, p, x
+
+
+def _assert_layer_matches_the_mask(cfg, p, x, **kw):
+    """Forward and every gradient (x, the router, the experts' three
+    matrices, the shared expert) against `dense_masked`; the statistics."""
+    y, stats = routed_experts(cfg, p, x, **kw)
+    np.testing.assert_allclose(np.asarray(y), np.asarray(dense_masked(cfg, p, x)), atol=2e-5)
+    loss = lambda f: (lambda p, x: jnp.sum(f(p, x) ** 2))
+    got = jax.grad(loss(lambda p, x: routed_experts(cfg, p, x, **kw)[0]), (0, 1))(p, x)
+    want = jax.grad(loss(lambda p, x: dense_masked(cfg, p, x)), (0, 1))(p, x)
+    for name in ("router", "w_gate", "w_up", "w_down"):
+        assert name in got[0]
+    for a, b in zip(jax.tree_util.tree_leaves(got), jax.tree_util.tree_leaves(want)):
+        assert np.all(np.isfinite(np.asarray(a)))
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-4, rtol=2e-4)
+    return stats
+
+
+# No row, one, a tile less one, a tile, a tile and one, the whole buffer.
+HELD_ROWS = (0, 1, TILE - 1, TILE, TILE + 1, 256)
+
+
+@pytest.mark.parametrize("kind", KINDS)
+@pytest.mark.parametrize("held_rows", HELD_ROWS)
+def test_the_passes_stop_at_the_counted_tiles_and_nothing_changes(row_tile, kind, held_rows):
+    cfg, p, x = _layer_holding(held_rows)
+    stats = _assert_layer_matches_the_mask(cfg, p, x, **KINDS[kind])
+    assert int(stats["assignments_held"]) == held_rows and int(stats["dropped"]) == 0
+    assert int(stats["rows_run"]) == -(-held_rows // TILE) * TILE
+    assert int(stats["assignments"]) == cfg.buffer_rows(128) == 256
+
+
+def test_a_buffer_the_tile_does_not_divide_runs_in_tiles_that_do(monkeypatch):
+    monkeypatch.setattr(moe, "ROW_TILE", 96)  # 256 rows: tiles of 32
+    cfg, p, x = _layer_holding(70)
+    stats = _assert_layer_matches_the_mask(cfg, p, x, kind="xla")
+    assert int(stats["rows_run"]) == 96 and int(stats["dropped"]) == 0
+
+
+def _poisoned_matmul(rows, weights, group_sizes, kind, interpret=False):
+    """A stand-in for the TPU kernel: it reads no row past the last group's,
+    and leaves NaN there in its result and in its gradient with respect to
+    `rows`, where the kernel leaves what the memory held."""
+    valid = (jnp.arange(rows.shape[0]) < jnp.sum(group_sizes))[:, None]
+
+    own = lambda a: jnp.where(valid, a, 0)
+    dot = lambda rows, weights: jax.lax.ragged_dot(own(rows), weights, group_sizes)
+
+    @jax.custom_vjp
+    def f(rows, weights):
+        return jnp.where(valid, dot(rows, weights), jnp.nan)
+
+    def bwd(res, g):
+        d_rows, d_weights = jax.vjp(dot, *res)[1](own(g))
+        return jnp.where(valid, d_rows, jnp.nan), d_weights
+
+    f.defvjp(lambda rows, weights: (f(rows, weights), (rows, weights)), bwd)
+    return f(rows, weights)
+
+
+def test_rows_past_the_last_group_are_zero_and_pass_no_gradient(row_tile, monkeypatch):
     """The Pallas grouped matmul leaves what it does not visit uninitialised,
-    in its result and in its gradient with respect to the rows."""
+    in its result and in its gradient with respect to the rows: its own rows
+    are `ragged_dot`'s, and the layer reads neither past the counted rows."""
     rows = jax.random.normal(jax.random.key(0), (256, D), jnp.float32)
     w = jax.random.normal(jax.random.key(1), (3, D, WIDTH), jnp.float32)
     sizes = jnp.asarray([40, 0, 90], jnp.int32)
     f = lambda rows, w: moe.grouped_matmul(rows, w, sizes, "pallas", interpret=True)
-    out = f(rows, w)
     np.testing.assert_allclose(
-        np.asarray(out), np.asarray(moe.grouped_matmul(rows, w, sizes, "xla")), atol=1e-5
+        np.asarray(f(rows, w)[:130]),
+        np.asarray(moe.grouped_matmul(rows, w, sizes, "xla")[:130]), atol=1e-5,
     )
-    assert float(jnp.max(jnp.abs(out[130:]))) == 0.0
-    d_rows, d_w = jax.grad(lambda rows, w: jnp.sum(f(rows, w) ** 2), (0, 1))(rows, w)
-    assert np.all(np.isfinite(np.asarray(d_rows))) and float(jnp.max(jnp.abs(d_rows[130:]))) == 0.0
-    assert float(jnp.max(jnp.abs(d_w[1]))) == 0.0  # an empty group's weights
+    # read through the passes' tiles: zeros from row 130 on, whatever the kernel left
+    held = jnp.sum(sizes)
+    (out,) = moe._over_live_rows(lambda a: (a,), held, (f(rows, w),))
+    assert np.all(np.isfinite(np.asarray(out))) and float(jnp.max(jnp.abs(out[130:]))) == 0.0
+    d_w = jax.grad(lambda w: jnp.sum(moe._over_live_rows(lambda a: (a,), held, (f(rows, w),))[0]))
+    assert float(jnp.max(jnp.abs(d_w(w)[1]))) == 0.0  # an empty group's weights
+    # The layer over a kernel that poisons every row it does not own, in
+    # dead tiles and in the tile at the boundary: finite, and the reference's.
+    monkeypatch.setattr(moe, "grouped_matmul", _poisoned_matmul)
+    for held_rows in (0, TILE + 5, 3 * TILE):
+        cfg, p, x = _layer_holding(held_rows)
+        stats = _assert_layer_matches_the_mask(cfg, p, x, kind="xla")
+        assert int(stats["rows_run"]) == -(-held_rows // TILE) * TILE
 
 
 def test_config_refuses_what_is_not_a_span():
