@@ -67,6 +67,48 @@ MIXERS = ("conv", "full_attention")
 Kind = tuple[str, bool]
 
 
+# --- the pattern as data: what any decoder whose layers differ in kind needs ---
+#
+# A layer's kind is whatever tuple decides its parameters' shapes and its
+# computation.  These four functions are all that knows about runs; this
+# module and models/window_attn_moe.py call them with their own kinds, block
+# parameters and block.
+
+
+def runs_of(kinds) -> tuple:
+    """Consecutive layers of one kind: ((kind, how many), ...)."""
+    return tuple((kind, len(list(group))) for kind, group in groupby(kinds))
+
+
+def init_runs(block_params, runs, key: jax.Array) -> list[dict]:
+    """One dict of stacked weights a run, in forward order;
+    ``block_params(key, kind)`` makes one block's."""
+    stacks = []
+    for (kind, n), run_key in zip(runs, jax.random.split(key, len(runs))):
+        blocks = [block_params(k, kind) for k in jax.random.split(run_key, n)]
+        stacks.append(jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *blocks))
+    return stacks
+
+
+def run_specs(block_specs, runs) -> list[dict]:
+    """``block_specs(kind)`` with a leading, never sharded, layer axis."""
+    is_spec = lambda x: isinstance(x, P)
+    stack = lambda tree: jax.tree_util.tree_map(lambda s: P(None, *s), tree, is_leaf=is_spec)
+    return [stack(block_specs(kind)) for kind, _ in runs]
+
+
+def scan_runs(block_of, runs, stacks: list[dict], x: jax.Array) -> tuple[jax.Array, list[dict]]:
+    """One `scan` a run, the runs in turn: ``block_of(kind)(x, lp)`` gives
+    (x, the routing's statistics or None).  Returns the last block's output
+    and each routed run's statistics stacked on its layer axis."""
+    stats = []
+    for (kind, _), stack in zip(runs, stacks, strict=True):
+        x, run_stats = jax.lax.scan(block_of(kind), x, stack)
+        if run_stats is not None:
+            stats.append(run_stats)
+    return x, stats
+
+
 @dataclass(frozen=True)
 class ConvAttnMoeConfig:
     """Sizes under the names of the published `config.json` keys' meaning."""
@@ -128,7 +170,7 @@ class ConvAttnMoeConfig:
     @property
     def runs(self) -> tuple[tuple[Kind, int], ...]:
         """Consecutive layers of one kind: (kind, how many)."""
-        return tuple((kind, len(list(group))) for kind, group in groupby(self.kinds))
+        return runs_of(self.kinds)
 
     @property
     def routed(self) -> RoutedConfig:
@@ -190,14 +232,10 @@ def _block_params(cfg: ConvAttnMoeConfig, key: jax.Array, kind: Kind) -> dict:
 
 def init_params(cfg: ConvAttnMoeConfig, rng: jax.Array) -> dict:
     k_embed, k_runs = jax.random.split(rng)
-    runs = []
-    for (kind, n), key in zip(cfg.runs, jax.random.split(k_runs, len(cfg.runs))):
-        blocks = [_block_params(cfg, k, kind) for k in jax.random.split(key, n)]
-        runs.append(jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *blocks))
     return {
         "embed": _dense_init(k_embed, (cfg.vocab_size, cfg.dim), cfg.dim, cfg.dtype),
         "final_norm": jnp.ones((cfg.dim,), jnp.float32),
-        "runs": runs,
+        "runs": init_runs(partial(_block_params, cfg), cfg.runs, k_runs),
     }
 
 
@@ -221,12 +259,10 @@ def _block_specs(cfg: ConvAttnMoeConfig, kind: Kind) -> dict:
 def param_specs(cfg: ConvAttnMoeConfig) -> dict:
     """fsdp on a matrix's input axis, tp on its output axis, as llama.py;
     a run's stacked layer axis is never sharded."""
-    is_spec = lambda x: isinstance(x, P)
-    stack = lambda tree: jax.tree_util.tree_map(lambda s: P(None, *s), tree, is_leaf=is_spec)
     return {
         "embed": P("tp", "fsdp"),
         "final_norm": P(None),
-        "runs": [stack(_block_specs(cfg, kind)) for kind, _ in cfg.runs],
+        "runs": run_specs(partial(_block_specs, cfg), cfg.runs),
     }
 
 
@@ -339,12 +375,8 @@ def hidden_states(
         x = _embed(cfg, params, tokens)
     positions = jnp.arange(tokens.shape[1], dtype=jnp.int32)
     block = _checkpointed(cfg, partial(_block, cfg, mesh))
-    stats = []
-    for stack in params["runs"]:
-        x, run_stats = jax.lax.scan(lambda x, lp: block(x, lp, positions), x, stack)
-        if run_stats is not None:
-            stats.append(run_stats)
-    return x, stats
+    # One block for every kind: it reads a layer's kind off the leaves it is given.
+    return scan_runs(lambda kind: lambda x, lp: block(x, lp, positions), cfg.runs, params["runs"], x)
 
 
 def lm_loss(

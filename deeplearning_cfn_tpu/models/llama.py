@@ -368,26 +368,32 @@ def attention_kind(
     return "xla"
 
 
-def attend(kind: str, q: jax.Array, k: jax.Array, v: jax.Array, mesh: Mesh | None) -> jax.Array:
+def attend(
+    kind: str, q: jax.Array, k: jax.Array, v: jax.Array, mesh: Mesh | None,
+    window: int | None = None,
+) -> jax.Array:
     """Causal attention by the implementation `attention_kind` named, on
     [B, S, H, D] tensors; the scores are scaled by D ** -0.5.  Shared by the
-    decoder blocks (this module's and models/mla_moe.py's)."""
+    decoder blocks (this module's, models/mla_moe.py's and the two
+    pattern-as-data decoders').  ``window`` W: query t sees the keys
+    ``t - W < j <= t``, W keys with its own (the one convention, stated in
+    ops/pallas_attention.py); ``ring`` refuses one."""
     if kind == "ring":
         from deeplearning_cfn_tpu.parallel.ring_attention import ring_attention
 
-        return ring_attention(q, k, v, mesh, causal=True)
+        return ring_attention(q, k, v, mesh, causal=True, window=window)
     if kind == "flash":
         from deeplearning_cfn_tpu.ops.pallas_attention import flash_attention
 
         # attention_kind only answers "flash" on a tpu backend, so
         # this is always the compiled Mosaic kernel.
-        return flash_attention(q, k, v, causal=True, mesh=mesh, interpret=False)
+        return flash_attention(q, k, v, causal=True, mesh=mesh, interpret=False, window=window)
     # "xla" covers use_flash_attention off-TPU (the Pallas kernel
     # needs Mosaic) AND below-crossover sequences where XLA's
     # fused attention measures faster than the Pallas kernel
     # (docs/BENCH_NOTES.md): use_flash means "fastest memory-safe
     # attention", not "always Pallas".
-    return dot_product_attention(q, k, v, causal=True)
+    return dot_product_attention(q, k, v, causal=True, window=window)
 
 
 def swiglu(h: jax.Array, w_gate: jax.Array, w_up: jax.Array, w_down: jax.Array) -> jax.Array:

@@ -19,8 +19,11 @@ query aware (kv heads may be fewer than q heads).
 
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 
 from deeplearning_cfn_tpu.ops.pallas_attention import flash_attention  # noqa: F401  (public re-export)
@@ -42,8 +45,13 @@ def dot_product_attention(
     causal: bool = True,
     mask: jax.Array | None = None,  # [B, 1, S, S] additive or bool
     softmax_dtype: jnp.dtype = jnp.float32,
+    window: int | None = None,
 ) -> jax.Array:
-    """Plain XLA attention with f32 softmax (bf16 softmax loses tail mass)."""
+    """Plain XLA attention with f32 softmax (bf16 softmax loses tail mass).
+    ``window``: query t sees the keys ``t - window < j <= t`` (causal only;
+    the convention of ops/pallas_attention.py)."""
+    if window is not None and not causal:
+        raise ValueError("a window is causal")
     *_, seq_q, num_heads, head_dim = q.shape
     k = _repeat_kv(k, num_heads)
     v = _repeat_kv(v, num_heads)
@@ -53,6 +61,8 @@ def dot_product_attention(
     if causal:
         seq_k = k.shape[1]
         causal_mask = jnp.tril(jnp.ones((seq_q, seq_k), dtype=bool))
+        if window is not None:
+            causal_mask = jnp.logical_and(causal_mask, jnp.triu(causal_mask, k=1 - window))
         scores = jnp.where(causal_mask[None, None], scores, jnp.finfo(softmax_dtype).min)
     if mask is not None:
         if mask.dtype == bool:
@@ -81,6 +91,46 @@ def rotary_embedding(
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)
+
+
+def yarn_inv_freq(
+    dim: int, theta: float, factor: float, original_max: int,
+    beta_fast: float = 32.0, beta_slow: float = 1.0,
+) -> np.ndarray:
+    """YaRN's inverse frequencies over ``dim`` rotary dimensions, float32
+    [dim / 2], as `transformers`' `_compute_yarn_parameters` computes them:
+    ``f_i = theta ** (-2i / dim)``; dimensions that turn more than
+    ``beta_fast`` times over the original context keep ``f_i``, those that
+    turn fewer than ``beta_slow`` times get ``f_i / factor``, and a linear ramp
+    ``r_i = clip((i - low) / (high - low), 0, 1)`` blends between:
+    ``(f_i / factor) r_i + f_i (1 - r_i)``."""
+    f = theta ** (-np.arange(0, dim, 2, dtype=np.float64) / dim)
+
+    def correction_dim(rotations: float) -> float:
+        return dim * math.log(original_max / (rotations * 2 * math.pi)) / (2 * math.log(theta))
+
+    low = max(math.floor(correction_dim(beta_fast)), 0)
+    high = min(math.ceil(correction_dim(beta_slow)), dim - 1)
+    ramp = np.clip((np.arange(dim // 2) - low) / max(high - low, 0.001), 0.0, 1.0)
+    return ((f / factor) * ramp + f * (1.0 - ramp)).astype(np.float32)
+
+
+def partial_rotary_embedding(
+    x: jax.Array,  # [B, S, H, D]
+    positions: jax.Array,  # [S]
+    inv_freq: np.ndarray,  # [R / 2]: the first R dimensions of a head rotate
+    scale: float = 1.0,  # on cos and sin (YaRN's attention factor)
+) -> jax.Array:
+    """RoPE over the first ``R = 2 * len(inv_freq)`` dimensions of each head,
+    split halves within them; the other ``D - R`` pass through."""
+    rot = 2 * len(inv_freq)
+    angles = positions[:, None].astype(jnp.float32) * jnp.asarray(inv_freq, jnp.float32)
+    cos = (scale * jnp.cos(angles))[None, :, None, :]
+    sin = (scale * jnp.sin(angles))[None, :, None, :]
+    turned, kept = x[..., :rot].astype(jnp.float32), x[..., rot:]
+    x1, x2 = jnp.split(turned, 2, axis=-1)
+    out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1).astype(x.dtype)
+    return out if rot == x.shape[-1] else jnp.concatenate([out, kept], axis=-1)
 
 
 def rms_norm(x: jax.Array, weight: jax.Array, eps: float = 1e-5) -> jax.Array:

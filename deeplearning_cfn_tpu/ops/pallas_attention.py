@@ -17,6 +17,19 @@ transformer flagships (BERT, Llama-3).  Design is TPU-first:
   wholly inside the triangle, with no padding in it, runs without a mask:
   no iota, compare or select over the tile; only a pair on the diagonal or
   over padding builds the mask (``_pair_mask``).
+- A sliding window (``window=W``, causal only): query t sees the keys j with
+  ``t - W < j <= t``, W keys with its own (the mask ``sliding_window`` gives
+  in `transformers`).  The window is a lower edge in the same rule: a pair
+  wholly behind it is neither computed nor fetched, a pair its edge crosses
+  builds the mask.  Windowed calls also walk a shorter grid: the kv axis has
+  as many steps as the widest q block's band holds kv blocks, counted from
+  the band's first block (the dk/dv kernel's q axis likewise), so that a
+  sequence of 8192 under a window of 512 does not pay a grid step for each of
+  its skipped pairs.  Their kernels carry names of their own
+  (``_window_flash_forward``, ``_window_flash_backward_dkv``,
+  ``_window_flash_backward_dq``): the benchmark's readers find the
+  full-causal kernels by the prefixes ``_flash_forward`` / ``_flash_backward``
+  and divide by a full-causal cost.
 - Grouped-query attention is handled in the BlockSpec index maps (a kv head
   is fetched for ``group = Hq // Hkv`` query heads) — no materialized
   ``repeat`` anywhere, forward or backward.
@@ -87,6 +100,25 @@ BWD_DQ_BLOCKS = (1024, 1024)
 # Four float32 [1024, 1024] tiles (s, p, dp, ds) are 16 MB, the default
 # scoped VMEM limit by themselves; a v5e core has 128 MiB.
 _BWD_VMEM_LIMIT = 64 * 1024 * 1024
+# The tiles of calls with a window, (q block, kv block) of the forward, the
+# dk/dv and the dq kernel, from a sweep on v5e at the window layers' shape of
+# `laguna-xs.2.train-s8192` (scripts/chip_grouped_matmul_sweep.py window, PR 33;
+# B 2, S 8192, 64/8 heads of 128, window 512, bf16; ms a call, kv block 256 /
+# 512 / 1024):
+#             forward               dk/dv                 dq
+#   q  256: 16.23  11.67  11.65    10.34   9.59  11.18    9.45   7.99  11.97
+#   q  512: 18.64  10.27   9.82     9.24   8.02  10.79    9.16   6.49  10.80
+#   q 1024: 24.22  11.51  10.84    14.31  11.94  13.48   10.32   8.82  11.28
+# A call's band holds 0.27 TFLOP forward (1.35 ms at the chip's peak): the
+# forward reaches 14% of it and the backward pair 23%.  Every pair a windowed
+# call runs at these tiles is a masked one (the diagonal, or the window's
+# edge); 1024 x 1024 runs its tiles at the full-causal kernel's rate with a
+# quarter of their scores inside the band, 512 x 512 at half that rate with
+# half inside, which comes to the same.  Beside them the full-causal kernels
+# at the cell's 48/8 heads: 15.13 ms forward, 20.93 + 17.48 backward.
+WINDOW_FWD_BLOCKS = (512, 1024)
+WINDOW_BWD_DKV_BLOCKS = (512, 512)
+WINDOW_BWD_DQ_BLOCKS = (512, 512)
 
 # Below this sequence length XLA's fused attention wins on v5e (measured:
 # 3.74 ms XLA vs 4.69 ms flash at S=2048 with 512 blocks; flash pulls
@@ -115,42 +147,90 @@ def _round_up(x: int, m: int) -> int:
 # diagonal or over padding builds one (`_pair_mask`).
 
 
-def _pair_mask(rows, cols, q_axis: int, q_start, k_start, *, causal, q_len, kv_len):
+def _pair_mask(rows, cols, q_axis: int, q_start, k_start, *, causal, q_len, kv_len, window=None):
     """Validity of a [rows, cols] tile of scores whose axis ``q_axis`` runs
     over query positions and the other over key positions: the key is real
     (not kv padding), the query is real (not q padding: a padded row's lse
     is 0 and its scores are, so nothing overflows, but it attends nothing),
-    and the key is not after the query."""
+    the key is not after the query, and under a window not ``window`` or
+    more positions before it."""
     q_pos = q_start + jax.lax.broadcasted_iota(jnp.int32, (rows, cols), q_axis)
     k_pos = k_start + jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 1 - q_axis)
     mask = jnp.logical_and(k_pos < kv_len, q_pos < q_len)
     if causal:
         mask = jnp.logical_and(mask, k_pos <= q_pos)
+    if window is not None:
+        mask = jnp.logical_and(mask, k_pos > q_pos - window)
     return mask
 
 
-def _run_pair(pair, q_start, k_start, *, causal, block_q, block_k, q_len, kv_len):
+def _run_pair(pair, q_start, k_start, *, causal, block_q, block_k, q_len, kv_len, window=None):
     """Run ``pair(masked)`` for one (q block, kv block): not at all where the
-    pair lies wholly above the causal diagonal, and with the mask only where
-    a score of it is masked, because it holds padding or (causal) its last
-    key lies after its first query.  Pairs wholly inside the causal triangle
-    skip the iotas, compares and selects."""
+    pair lies wholly above the causal diagonal or wholly behind the window,
+    and with the mask only where a score of it is masked, because it holds
+    padding, (causal) its last key lies after its first query or (window) its
+    first key lies ``window`` or more before its last query.  Pairs wholly
+    inside the band skip the iotas, compares and selects.  A windowed grid
+    counts its blocks from the band's edge and may step past the last block:
+    such a step is not live either."""
     masked = jnp.logical_or(k_start + block_k > kv_len, q_start + block_q > q_len)
     live = True
     if causal:
         masked = jnp.logical_or(masked, k_start + block_k - 1 > q_start)
         live = k_start <= q_start + block_q - 1
+    if window is not None:
+        masked = jnp.logical_or(masked, k_start <= q_start + block_q - 1 - window)
+        behind = k_start + block_k - 1 <= q_start - window
+        outside = jnp.logical_or(k_start >= kv_len, q_start >= q_len)
+        live = jnp.logical_and(live, jnp.logical_not(jnp.logical_or(behind, outside)))
     pl.when(jnp.logical_and(live, masked))(lambda: pair(True))
     pl.when(jnp.logical_and(live, jnp.logical_not(masked)))(lambda: pair(False))
 
 
-def _kv_index_map(*, causal: bool, group: int, block_q: int, block_k: int, nk: int):
+def _first_kv_block(q_start, *, window: int, block_k: int):
+    """The kv block that holds the first key a q block starting at ``q_start``
+    sees under the window: where a windowed grid's kv axis starts."""
+    return jnp.maximum(q_start - window + 1, 0) // block_k
+
+
+def _first_q_block(k_start, *, block_q: int):
+    """The first q block that sees a key of the kv block starting at
+    ``k_start``: where a windowed dk/dv grid's q axis starts."""
+    return k_start // block_q
+
+
+def _kv_steps(nq: int, nk: int, block_q: int, block_k: int, window: int) -> int:
+    """Steps of a windowed grid's kv axis: the most kv blocks any q block's
+    band covers, from its first visible key to its last query (static)."""
+    return max(
+        min(((i + 1) * block_q - 1) // block_k, nk - 1)
+        - max(i * block_q - window + 1, 0) // block_k + 1
+        for i in range(nq)
+    )
+
+
+def _q_steps(nq: int, nk: int, block_q: int, block_k: int, window: int) -> int:
+    """Steps a query head takes on a windowed dk/dv grid's q axis: the most q
+    blocks that see any one kv block, from its first key's query to the last
+    query that still sees its last key (static)."""
+    return max(
+        min((j * block_k + block_k + window - 2) // block_q, nq - 1) - (j * block_k) // block_q + 1
+        for j in range(nk)
+    )
+
+
+def _kv_index_map(
+    *, causal: bool, group: int, block_q: int, block_k: int, nk: int, window: int | None = None
+):
     """Index map of a k or v block [1, 1, Bk, D] on a grid (batch, query head,
     q block, kv block), the kv head being the query head's group.  Under
     causal a step above the diagonal names the block of the last step that
-    runs, so that nothing is fetched for it."""
+    runs, so that nothing is fetched for it.  Under a window the grid's kv
+    axis counts from the first block of the q block's band."""
 
     def kv_index(b, h, i, j):
+        if window is not None:
+            j = j + _first_kv_block(i * block_q, window=window, block_k=block_k)
         if causal:
             j = jnp.minimum(j, jnp.minimum(((i + 1) * block_q - 1) // block_k, nk - 1))
         return b, h // group, j, 0
@@ -185,10 +265,14 @@ def _attn_kernel(
     q_len: int,
     kv_len: int,
     need_lse: bool,
+    window: int | None = None,
 ):
     ki = pl.program_id(3)
     q_start = pl.program_id(2) * block_q
-    k_start = ki * block_k
+    if window is None:
+        k_start = ki * block_k
+    else:
+        k_start = (ki + _first_kv_block(q_start, window=window, block_k=block_k)) * block_k
 
     @pl.when(ki == 0)
     def _init():
@@ -208,7 +292,7 @@ def _attn_kernel(
         if masked:
             mask = _pair_mask(
                 block_q, block_k, 0, q_start, k_start,
-                causal=causal, q_len=q_len, kv_len=kv_len,
+                causal=causal, q_len=q_len, kv_len=kv_len, window=window,
             )
             s = jnp.where(mask, s, NEG_INF)
 
@@ -239,8 +323,8 @@ def _attn_kernel(
         l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
 
     _run_pair(
-        pair, q_start, k_start,
-        causal=causal, block_q=block_q, block_k=block_k, q_len=q_len, kv_len=kv_len,
+        pair, q_start, k_start, causal=causal, block_q=block_q, block_k=block_k,
+        q_len=q_len, kv_len=kv_len, window=window,
     )
 
     @pl.when(ki == pl.num_programs(3) - 1)
@@ -267,7 +351,7 @@ def _pad_seq(x: jax.Array, block: int) -> jax.Array:
 @functools.partial(
     jax.jit,
     static_argnames=(
-        "causal", "sm_scale", "block_q", "block_k", "interpret", "need_lse"
+        "causal", "sm_scale", "block_q", "block_k", "interpret", "need_lse", "window"
     ),
 )
 def _flash_forward(
@@ -280,6 +364,7 @@ def _flash_forward(
     block_k: int,
     interpret: bool,
     need_lse: bool = True,
+    window: int | None = None,
 ):
     B, Sq, Hq, D = q.shape
     _, Sk, Hkv, _ = k.shape
@@ -300,12 +385,16 @@ def _flash_forward(
         q_len=Sq,
         kv_len=Sk,
         need_lse=need_lse,
+        window=window,
     )
     q_spec = pl.BlockSpec((1, 1, block_q, D), lambda b, h, i, j: (b, h, i, 0))
     kv_spec = pl.BlockSpec(
         (1, 1, block_k, D),
-        _kv_index_map(causal=causal, group=group, block_q=block_q, block_k=block_k, nk=nk),
+        _kv_index_map(
+            causal=causal, group=group, block_q=block_q, block_k=block_k, nk=nk, window=window
+        ),
     )
+    kv_steps = nk if window is None else _kv_steps(nq, nk, block_q, block_k, window)
     if need_lse:
         # Lane-replicated LSE ([..., 128] f32) — the TPU min-tile layout for
         # per-row stats (same shape jax's own TPU flash kernel uses for l/m).
@@ -318,7 +407,7 @@ def _flash_forward(
         lse_shape = jax.ShapeDtypeStruct((1, 1, 8, 128), jnp.float32)
     out, lse = pl.pallas_call(
         kernel,
-        grid=(B, Hq, nq, nk),
+        grid=(B, Hq, nq, kv_steps),
         in_specs=[q_spec, kv_spec, kv_spec],
         out_specs=[q_spec, lse_spec],
         out_shape=[
@@ -335,8 +424,9 @@ def _flash_forward(
         # The kernel's name in the compiled program and in a profile
         # (`_flash_forward.<n>`), said here so that it no longer hangs on
         # the name of the jitted function around it.  The benchmark's
-        # attention_roofline_share finds the kernel by this name.
-        name="_flash_forward",
+        # attention_roofline_share finds the kernel by this name, and divides
+        # by a full-causal call's cost: a windowed call has a name of its own.
+        name="_flash_forward" if window is None else "_window_flash_forward",
     )(qt, kt, vt)
     out = jnp.swapaxes(out, 1, 2)[:, :Sq]  # [B, Sq, Hq, D]
     if not need_lse:
@@ -374,7 +464,8 @@ def _dkv_kernel(
     block_k: int,
     q_len: int,
     kv_len: int,
-    nq: int,
+    nq: int,  # steps of the q axis a query head takes: every q block, or the window's
+    window: int | None = None,
 ):
     """Works on the transposed tile s^T = k q^T [Bk, Bq]: the per-query
     statistics then broadcast along sublanes from a compact [1, Bq] row,
@@ -383,8 +474,12 @@ def _dkv_kernel(
     ki = pl.program_id(2)
     t = pl.program_id(3)  # (query head of the group, q block), q block fastest
     qi = t % nq
-    q_start = qi * block_q
-    k_start = ki * block_k
+    if window is None:
+        q_start = qi * block_q
+        k_start = ki * block_k
+    else:
+        k_start = ki * block_k
+        q_start = (qi + _first_q_block(k_start, block_q=block_q)) * block_q
 
     @pl.when(t == 0)
     def _init():
@@ -402,7 +497,7 @@ def _dkv_kernel(
             # (exp may even overflow), the select never multiplies it.
             mask = _pair_mask(
                 block_k, block_q, 1, q_start, k_start,
-                causal=causal, q_len=q_len, kv_len=kv_len,
+                causal=causal, q_len=q_len, kv_len=kv_len, window=window,
             )
             pt = jnp.where(mask, pt, 0.0)
         dv_acc[:] += jax.lax.dot_general(
@@ -415,8 +510,8 @@ def _dkv_kernel(
         )
 
     _run_pair(
-        pair, q_start, k_start,
-        causal=causal, block_q=block_q, block_k=block_k, q_len=q_len, kv_len=kv_len,
+        pair, q_start, k_start, causal=causal, block_q=block_q, block_k=block_k,
+        q_len=q_len, kv_len=kv_len, window=window,
     )
 
     @pl.when(t == pl.num_programs(3) - 1)
@@ -441,11 +536,15 @@ def _dq_kernel(
     block_k: int,
     q_len: int,
     kv_len: int,
+    window: int | None = None,
 ):
     qi = pl.program_id(2)
     ki = pl.program_id(3)
     q_start = qi * block_q
-    k_start = ki * block_k
+    if window is None:
+        k_start = ki * block_k
+    else:
+        k_start = (ki + _first_kv_block(q_start, window=window, block_k=block_k)) * block_k
 
     @pl.when(ki == 0)
     def _init():
@@ -460,7 +559,7 @@ def _dq_kernel(
         if masked:
             mask = _pair_mask(
                 block_q, block_k, 0, q_start, k_start,
-                causal=causal, q_len=q_len, kv_len=kv_len,
+                causal=causal, q_len=q_len, kv_len=kv_len, window=window,
             )
             p = jnp.where(mask, p, 0.0)
         dp = jax.lax.dot_general(do, v, nt, preferred_element_type=jnp.float32)
@@ -470,8 +569,8 @@ def _dq_kernel(
         )
 
     _run_pair(
-        pair, q_start, k_start,
-        causal=causal, block_q=block_q, block_k=block_k, q_len=q_len, kv_len=kv_len,
+        pair, q_start, k_start, causal=causal, block_q=block_q, block_k=block_k,
+        q_len=q_len, kv_len=kv_len, window=window,
     )
 
     @pl.when(ki == pl.num_programs(3) - 1)
@@ -491,22 +590,29 @@ def _pad_rows(x: jax.Array, block: int) -> jax.Array:
     return jnp.pad(x, ((0, 0), (0, 0), (0, (-x.shape[2]) % block)))
 
 
-def _backward_dkv(q, k, v, dout, lse, delta, *, causal, sm_scale, blocks, interpret):
-    """dk, dv [B, Sk, Hkv, D]: grid (B, Hkv, kv blocks, group * q blocks)."""
+def _backward_dkv(q, k, v, dout, lse, delta, *, causal, sm_scale, blocks, interpret, window=None):
+    """dk, dv [B, Sk, Hkv, D]: grid (B, Hkv, kv blocks, group * q blocks);
+    under a window, group * the q blocks the widest kv block's band holds."""
     bq, bk = blocks
     (B, Sq, Hq, D), (_, Sk, Hkv, _) = q.shape, k.shape
     group = Hq // Hkv
     qt, dot = _heads_major(q, bq), _heads_major(dout, bq)
     kt, vt = _heads_major(k, bk), _heads_major(v, bk)
     nq, nk = qt.shape[2] // bq, kt.shape[2] // bk
+    q_steps = nq if window is None else _q_steps(nq, nk, bq, bk, window)
 
     def q_index(b, h, j, t):
-        i = t % nq
-        if causal:
+        i = t % q_steps
+        if window is not None:
+            # The q axis counts from the first block that sees this kv block;
+            # a step past the last one that does names that last one.
+            i = i + _first_q_block(j * bk, block_q=bq)
+            i = jnp.minimum(i, jnp.minimum((j * bk + bk + window - 2) // bq, nq - 1))
+        elif causal:
             # A skipped step names the block of the next step that runs, so
             # that nothing is fetched for it.
             i = jnp.minimum(jnp.maximum(i, (j * bk) // bq), nq - 1)
-        return b, h * group + t // nq, i
+        return b, h * group + t // q_steps, i
 
     def row_index(*ids):
         b, h, i = q_index(*ids)
@@ -518,9 +624,9 @@ def _backward_dkv(q, k, v, dout, lse, delta, *, causal, sm_scale, blocks, interp
     dk, dv = pl.pallas_call(
         functools.partial(
             _dkv_kernel, causal=causal, sm_scale=sm_scale,
-            block_q=bq, block_k=bk, q_len=Sq, kv_len=Sk, nq=nq,
+            block_q=bq, block_k=bk, q_len=Sq, kv_len=Sk, nq=q_steps, window=window,
         ),
-        grid=(B, Hkv, nk, group * nq),
+        grid=(B, Hkv, nk, group * q_steps),
         in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
         out_specs=[kv_spec, kv_spec],
         out_shape=[
@@ -532,7 +638,7 @@ def _backward_dkv(q, k, v, dout, lse, delta, *, causal, sm_scale, blocks, interp
         interpret=interpret,
         # Not `_flash_forward...`: the benchmark's attention_roofline_share
         # finds the forward kernel by that prefix, these by `_flash_backward`.
-        name="_flash_backward_dkv",
+        name="_flash_backward_dkv" if window is None else "_window_flash_backward_dkv",
     )(
         qt, kt, vt, dot,
         # the statistics as one row along the lanes: [B, Hq, 1, S']
@@ -541,8 +647,9 @@ def _backward_dkv(q, k, v, dout, lse, delta, *, causal, sm_scale, blocks, interp
     return jnp.swapaxes(dk, 1, 2)[:, :Sk], jnp.swapaxes(dv, 1, 2)[:, :Sk]
 
 
-def _backward_dq(q, k, v, dout, lse, delta, *, causal, sm_scale, blocks, interpret):
-    """dq [B, Sq, Hq, D]: grid (B, Hq, q blocks, kv blocks)."""
+def _backward_dq(q, k, v, dout, lse, delta, *, causal, sm_scale, blocks, interpret, window=None):
+    """dq [B, Sq, Hq, D]: grid (B, Hq, q blocks, kv blocks); under a window,
+    the kv blocks the widest q block's band holds."""
     bq, bk = blocks
     (B, Sq, Hq, D), (_, Sk, Hkv, _) = q.shape, k.shape
     group = Hq // Hkv
@@ -558,28 +665,29 @@ def _backward_dq(q, k, v, dout, lse, delta, *, causal, sm_scale, blocks, interpr
     col_spec = pl.BlockSpec((1, 1, bq, 128), lambda b, h, i, j: (b, h, i, 0))
     kv_spec = pl.BlockSpec(
         (1, 1, bk, D),
-        _kv_index_map(causal=causal, group=group, block_q=bq, block_k=bk, nk=nk),
+        _kv_index_map(causal=causal, group=group, block_q=bq, block_k=bk, nk=nk, window=window),
     )
+    kv_steps = nk if window is None else _kv_steps(nq, nk, bq, bk, window)
     dq = pl.pallas_call(
         functools.partial(
             _dq_kernel, causal=causal, sm_scale=sm_scale,
-            block_q=bq, block_k=bk, q_len=Sq, kv_len=Sk,
+            block_q=bq, block_k=bk, q_len=Sq, kv_len=Sk, window=window,
         ),
-        grid=(B, Hq, nq, nk),
+        grid=(B, Hq, nq, kv_steps),
         in_specs=[q_spec, kv_spec, kv_spec, q_spec, col_spec, col_spec],
         out_specs=q_spec,
         out_shape=jax.ShapeDtypeStruct(qt.shape, q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
         compiler_params=_compiler_params(_BWD_VMEM_LIMIT),
         interpret=interpret,
-        name="_flash_backward_dq",
+        name="_flash_backward_dq" if window is None else "_window_flash_backward_dq",
     )(qt, kt, vt, dot, columns(lse), columns(delta))
     return jnp.swapaxes(dq, 1, 2)[:, :Sq]
 
 
 @functools.partial(
     jax.jit,
-    static_argnames=("causal", "sm_scale", "dkv_blocks", "dq_blocks", "interpret"),
+    static_argnames=("causal", "sm_scale", "dkv_blocks", "dq_blocks", "interpret", "window"),
 )
 def _flash_backward(
     q: jax.Array,
@@ -593,10 +701,11 @@ def _flash_backward(
     dkv_blocks: tuple[int, int],  # (q block, kv block) of the dk/dv kernel
     dq_blocks: tuple[int, int],
     interpret: bool,
+    window: int | None = None,
 ):
     # delta_i = sum_d out_i * dout_i, the softmax backward's row term.
     delta = jnp.einsum("bqhd,bqhd->bhq", out, dout, preferred_element_type=jnp.float32)
-    kw = dict(causal=causal, sm_scale=sm_scale, interpret=interpret)
+    kw = dict(causal=causal, sm_scale=sm_scale, interpret=interpret, window=window)
     dk, dv = _backward_dkv(q, k, v, dout, lse, delta, blocks=dkv_blocks, **kw)
     dq = _backward_dq(q, k, v, dout, lse, delta, blocks=dq_blocks, **kw)
     return dq, dk, dv
@@ -605,13 +714,13 @@ def _flash_backward(
 # --- custom-vjp core (arrays only; mesh handled by the public wrapper) ---
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _flash_core(q, k, v, causal, sm_scale, block_q, block_k, interpret):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _flash_core(q, k, v, causal, sm_scale, block_q, block_k, interpret, window):
     # Primal-only path (no grad being taken): skip the LSE output entirely.
     bq = _clamp_block(block_q, q.shape[1])
     bk = _clamp_block(block_k, k.shape[1])
     out, _ = _flash_forward(
-        q, k, v, causal, sm_scale, bq, bk, interpret, need_lse=False
+        q, k, v, causal, sm_scale, bq, bk, interpret, need_lse=False, window=window
     )
     return out
 
@@ -662,25 +771,29 @@ def _clamp_block(block: int, seq: int) -> int:
     return min(best, seq_t)
 
 
-def _core_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret):
+def _core_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret, window):
     bq = _clamp_block(block_q, q.shape[1])
     bk = _clamp_block(block_k, k.shape[1])
-    out, lse = _flash_forward(q, k, v, causal, sm_scale, bq, bk, interpret)
+    out, lse = _flash_forward(q, k, v, causal, sm_scale, bq, bk, interpret, window=window)
     return out, (q, k, v, out, lse)
 
 
-def _core_bwd(causal, sm_scale, block_q, block_k, interpret, res, g):
+def _core_bwd(causal, sm_scale, block_q, block_k, interpret, window, res, g):
     del block_q, block_k  # the forward's tiles; the backward has its own
     q, k, v, out, lse = res
     clamp = lambda blocks: (
         _clamp_block(blocks[0], q.shape[1]), _clamp_block(blocks[1], k.shape[1])
+    )
+    dkv_blocks, dq_blocks = (
+        (BWD_DKV_BLOCKS, BWD_DQ_BLOCKS) if window is None
+        else (WINDOW_BWD_DKV_BLOCKS, WINDOW_BWD_DQ_BLOCKS)
     )
     # The scope is what tells the backward's kernels and the few XLA
     # operations around them from the rest of the step's in a profile.
     with jax.named_scope("attn_bwd"):
         return _flash_backward(
             q, k, v, out, lse, g, causal, sm_scale,
-            clamp(BWD_DKV_BLOCKS), clamp(BWD_DQ_BLOCKS), interpret,
+            clamp(dkv_blocks), clamp(dq_blocks), interpret, window=window,
         )
 
 
@@ -693,12 +806,18 @@ def flash_attention(
     v: jax.Array,
     causal: bool = True,
     sm_scale: float | None = None,
-    block_q: int = DEFAULT_BLOCK_Q,
-    block_k: int = DEFAULT_BLOCK_K,
+    block_q: int | None = None,
+    block_k: int | None = None,
     interpret: bool = False,
     mesh: Mesh | None = None,
+    window: int | None = None,
 ) -> jax.Array:
     """Flash attention, [B, S, H, D] in/out, GQA-aware (Hkv must divide Hq).
+
+    ``window``: a sliding window of that many keys, the query's own among
+    them (``t - window < j <= t``); causal only.  ``block_q`` / ``block_k``
+    left out are the swept forward tiles of the kind of call
+    (``DEFAULT_BLOCK_*``, or ``WINDOW_FWD_BLOCKS`` under a window).
 
     Compiled Mosaic kernel unless ``interpret=True`` (the Pallas
     interpreter: slow, for CPU tests — see module docstring).  Off a TPU
@@ -715,10 +834,19 @@ def flash_attention(
         sm_scale = q.shape[-1] ** -0.5
     sm_scale = float(sm_scale)
     interpret = bool(interpret)
+    if window is not None:
+        window = int(window)
+        if not causal or window < 1:
+            raise ValueError(f"a window ({window}) is a positive number of keys, and causal")
+    default_q, default_k = (
+        (DEFAULT_BLOCK_Q, DEFAULT_BLOCK_K) if window is None else WINDOW_FWD_BLOCKS
+    )
+    block_q = default_q if block_q is None else block_q
+    block_k = default_k if block_k is None else block_k
 
     def core(q, k, v):
         # nondiff argnums must be positional for custom_vjp
-        return _flash_core(q, k, v, causal, sm_scale, block_q, block_k, interpret)
+        return _flash_core(q, k, v, causal, sm_scale, block_q, block_k, interpret, window)
     if mesh is not None and mesh.shape.get("sp", 1) > 1:
         raise ValueError(
             "flash_attention does not shard the sequence axis; use "

@@ -69,12 +69,21 @@ def ring_attention(
     mesh: Mesh,
     axis: str = "sp",
     causal: bool = True,
+    window: int | None = None,
 ) -> jax.Array:
     """Causal ring attention over the ``axis`` mesh dimension.
 
     Batch is assumed sharded over (dp, fsdp) and heads over tp as usual;
-    this function only manages the sequence axis.
+    this function only manages the sequence axis.  A sliding ``window`` is
+    refused by name: the ring passes every block to every device and masks
+    by the causal triangle alone, so a window would be silently ignored.
     """
+    if window is not None:
+        raise NotImplementedError(
+            f"ring_attention has no sliding window (window={window}): its blocks are masked "
+            "by the causal triangle alone; use flash_attention or dot_product_attention "
+            "with the sequence unsharded"
+        )
     num_heads = q.shape[2]
     num_kv_heads = k.shape[2]
     sp = mesh.shape[axis]

@@ -29,7 +29,9 @@ BLOCKS = tuple(itertools.product((256, 512, 1024), repeat=2))  # (q block, kv bl
 CALLS = 5
 
 
-def sweep(shape, blocks) -> list[dict]:
+def sweep(shape, blocks, window: int | None = None) -> list[dict]:
+    """`window`: the windowed kernels (`_window_flash_backward_*`) under that
+    window, their forward at `WINDOW_FWD_BLOCKS`."""
     import jax
     import jax.numpy as jnp
 
@@ -41,17 +43,19 @@ def sweep(shape, blocks) -> list[dict]:
     q, dout = (jax.random.normal(k, (B, S, Hq, D), jnp.bfloat16) for k in keys[:2])
     k, v = (jax.random.normal(k, (B, S, Hkv, D), jnp.bfloat16) for k in keys[2:])
     scale = D**-0.5
+    forward = (pa.DEFAULT_BLOCK_Q, pa.DEFAULT_BLOCK_K) if window is None else pa.WINDOW_FWD_BLOCKS
     out, lse = pa._flash_forward(
-        q, k, v, True, scale, pa._clamp_block(pa.DEFAULT_BLOCK_Q, S),
-        pa._clamp_block(pa.DEFAULT_BLOCK_K, S), False,
+        q, k, v, True, scale, pa._clamp_block(forward[0], S),
+        pa._clamp_block(forward[1], S), False, window=window,
     )
+    prefix = "_flash_backward" if window is None else "_window_flash_backward"
     rows_out = []
     for bq, bk in blocks:
         tiles = (pa._clamp_block(bq, S), pa._clamp_block(bk, S))
         run = lambda: pa._flash_backward(
-            q, k, v, out, lse, dout, True, scale, tiles, tiles, False
+            q, k, v, out, lse, dout, True, scale, tiles, tiles, False, window=window
         )
-        row = {"shape": list(shape), "block_q": tiles[0], "block_k": tiles[1]}
+        row = {"shape": list(shape), "window": window, "block_q": tiles[0], "block_k": tiles[1]}
         try:
             jax.block_until_ready(run())
         except Exception as e:  # a tile Mosaic refuses is a row of the sweep too
@@ -67,9 +71,7 @@ def sweep(shape, blocks) -> list[dict]:
         shutil.rmtree(trace_dir, ignore_errors=True)
         device = trace_reduce.devices(rows)[0]
         for kernel in ("dkv", "dq"):
-            seconds, calls = trace_reduce.kernel_seconds(
-                rows, device, rf"^_flash_backward_{kernel}"
-            )
+            seconds, calls = trace_reduce.kernel_seconds(rows, device, rf"^{prefix}_{kernel}")
             row[f"{kernel}_ms"] = 1e3 * seconds / calls if calls else None
         rows_out.append(row)
         print(json.dumps(row, allow_nan=False), flush=True)

@@ -10,7 +10,11 @@ matmuls with their SwiGLU, and from a `jax.profiler` capture the device time of
 the operations that took most of it.  Then the flash attention's forward
 kernel by tile at both decoder cells' shapes (`DEFAULT_BLOCK_Q` /
 `DEFAULT_BLOCK_K` in `ops/pallas_attention.py` are picked from that table),
-and the backward kernels at the cell's 20 heads of 256.  `routing` (run by
+and the backward kernels at the cell's 20 heads of 256.  `window` (run by name
+only) is the three kernels under a sliding window at the shape of
+`laguna-xs.2.train-s8192`'s window layers (64/8 heads of 128, S 8192, window
+512) by tile, with the full-causal kernels at its full layers' 48 heads beside
+them (`WINDOW_FWD_BLOCKS`, `WINDOW_BWD_DKV_BLOCKS`, `WINDOW_BWD_DQ_BLOCKS`).  `routing` (run by
 name only) is one whole routed layer, `ops/moe.routed_experts`, forward and
 backward at that shape and at both cells' expert widths, by the tile of its
 passes over the sorted buffer (`ROW_TILE` in `ops/moe.py` is picked from it)
@@ -20,7 +24,7 @@ the layer's scopes from a capture.  In a tree whose `ops/moe.py` has no
 the code before the tiles is read beside them.  Through chiprun; one JSON line
 per row.  Name a sweep to run it alone.
 
-    chiprun -- python3 scripts/chip_grouped_matmul_sweep.py [experts] [attention] [routing]
+    chiprun -- python3 scripts/chip_grouped_matmul_sweep.py [experts] [attention] [routing] [window]
 """
 
 from __future__ import annotations
@@ -48,6 +52,10 @@ TILES = (
 # `mistral-7b-v0.3.train-s4096` and of `glm-4.7-flash.train-s8192`.
 ATTENTION_SHAPES = ((2, 4096, 32, 8, 128), (2, 8192, 20, 20, 256))
 FORWARD_BLOCKS = (512, 1024, 2048)  # q block and kv block, every combination
+# The windowed sweep: the window layers of `laguna-xs.2.train-s8192`, and its
+# full layers for the full-causal kernels beside them.
+WINDOW_SHAPE, WINDOW, WINDOW_BLOCKS = (2, 8192, 64, 8, 128), 512, (256, 512, 1024)
+WINDOW_FULL_SHAPE = (2, 8192, 48, 8, 128)
 CALLS = 5
 # The routing sweep: the expert widths of the GLM and the LFM2 cell, the tiles
 # of the passes over the buffer, and the shares of the assignments held here
@@ -202,24 +210,32 @@ def routing_sweep() -> None:
             print(json.dumps(row, allow_nan=False), flush=True)
 
 
-def pair_counts(seq: int, block_q: int, block_k: int) -> dict:
+def pair_counts(seq: int, block_q: int, block_k: int, window: int | None = None) -> dict:
     """How many (q block, kv block) pairs of one causal (batch, head) are
     skipped, run under the mask and run without it: `_run_pair`'s rule at a
-    sequence of whole blocks."""
+    sequence of whole blocks.  Under a window `grid_steps` is what the
+    shortened grid walks (q blocks times the widest band's kv blocks)."""
     counts = {"skipped": 0, "masked": 0, "unmasked": 0}
     for q_start, k_start in itertools.product(
         range(0, seq, block_q), range(0, seq, block_k)
     ):
-        if k_start > q_start + block_q - 1:
+        behind = window is not None and k_start + block_k - 1 <= q_start - window
+        crossed = window is not None and k_start <= q_start + block_q - 1 - window
+        if k_start > q_start + block_q - 1 or behind:
             counts["skipped"] += 1
-        elif k_start + block_k - 1 > q_start:
+        elif k_start + block_k - 1 > q_start or crossed:
             counts["masked"] += 1
         else:
             counts["unmasked"] += 1
+    if window is not None:
+        from deeplearning_cfn_tpu.ops.pallas_attention import _kv_steps
+
+        nq, nk = seq // block_q, seq // block_k
+        counts["grid_steps"] = nq * _kv_steps(nq, nk, block_q, block_k, window)
     return counts
 
 
-def forward_sweep(shape) -> None:
+def forward_sweep(shape, window: int | None = None, blocks=FORWARD_BLOCKS) -> None:
     import jax
     import jax.numpy as jnp
 
@@ -231,10 +247,11 @@ def forward_sweep(shape) -> None:
     q = jax.random.normal(keys[0], (B, S, H, hd), jnp.bfloat16)
     k, v = (jax.random.normal(kk, (B, S, KV, hd), jnp.bfloat16) for kk in keys[1:])
     best = None
-    for bq, bk in itertools.product(FORWARD_BLOCKS, repeat=2):
-        row = {"attention_forward": list(shape), "block_q": bq, "block_k": bk}
-        row.update(pair_counts(S, bq, bk))
-        run = lambda: pa._flash_forward(q, k, v, True, hd**-0.5, bq, bk, False)
+    kernel = r"^_flash_forward" if window is None else r"^_window_flash_forward"
+    for bq, bk in itertools.product(blocks, repeat=2):
+        row = {"attention_forward": list(shape), "window": window, "block_q": bq, "block_k": bk}
+        row.update(pair_counts(S, bq, bk, window))
+        run = lambda: pa._flash_forward(q, k, v, True, hd**-0.5, bq, bk, False, window=window)
         try:
             jax.block_until_ready(run())
         except Exception as e:
@@ -245,9 +262,7 @@ def forward_sweep(shape) -> None:
             jax.block_until_ready([run() for _ in range(CALLS)])
         rows = trace_reduce.load_events(trace_dir)
         shutil.rmtree(trace_dir, ignore_errors=True)
-        seconds, calls = trace_reduce.kernel_seconds(
-            rows, trace_reduce.devices(rows)[0], r"^_flash_forward"
-        )
+        seconds, calls = trace_reduce.kernel_seconds(rows, trace_reduce.devices(rows)[0], kernel)
         row["forward_ms"] = 1e3 * seconds / calls if calls else None
         print(json.dumps(row, allow_nan=False), flush=True)
         if calls and (best is None or row["forward_ms"] < best["forward_ms"]):
@@ -264,10 +279,21 @@ def attention_sweep() -> None:
     backward.sweep(ATTENTION_SHAPES[1], blocks)
 
 
+def window_sweep() -> None:
+    import chip_attention_backward_sweep as backward
+
+    forward_sweep(WINDOW_SHAPE, WINDOW, WINDOW_BLOCKS)
+    backward.sweep(WINDOW_SHAPE, tuple(itertools.product(WINDOW_BLOCKS, repeat=2)), WINDOW)
+    # the full layers' kernels at their own tiles, for the time beside them
+    forward_sweep(WINDOW_FULL_SHAPE, None, (1024,))
+    backward.sweep(WINDOW_FULL_SHAPE, ((1024, 1024),))
+
+
 def main(argv: list[str]) -> int:
     import jax
 
-    sweeps = {"experts": experts_sweep, "attention": attention_sweep, "routing": routing_sweep}
+    sweeps = {"experts": experts_sweep, "attention": attention_sweep, "routing": routing_sweep,
+              "window": window_sweep}
     if jax.devices()[0].platform != "tpu":
         print("chip_grouped_matmul_sweep: needs a TPU", file=sys.stderr)
         return 1

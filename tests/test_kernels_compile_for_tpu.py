@@ -59,6 +59,38 @@ def test_forward_and_backward_compile_for_v5e(case, one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes < B * Hq * S * 512 * 4
 
 
+# The attention of `laguna-xs.2.train-s8192` (PR 33): the full layers' 48
+# query heads over 8 key/value heads (groups of 6) through the full-causal
+# kernels, the window layers' 64 (groups of 8) under a window of 512 at the
+# tiles the sweep chose (the module's constants), and a ragged length with a
+# window.  (batch, seq, q heads, kv heads, head dim, window)
+WINDOW_CASES = {
+    "laguna-full-48-heads": (2, 8192, 48, 8, 128, None),
+    "laguna-window-512-64-heads": (2, 8192, 64, 8, 128, 512),
+    "ragged-s2100-window-512": (1, 2100, 64, 8, 128, 512),
+    "window-300-not-a-multiple": (1, 4096, 12, 2, 128, 300),
+}
+
+
+@pytest.mark.parametrize("case", WINDOW_CASES)
+def test_windowed_kernels_compile_for_v5e(case, one_chip):
+    B, S, Hq, Hkv, D, window = WINDOW_CASES[case]
+    q = jax.ShapeDtypeStruct((B, S, Hq, D), jnp.bfloat16, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((B, S, Hkv, D), jnp.bfloat16, sharding=one_chip)
+
+    def grads(q, k, v):
+        loss = lambda q, k, v: pa.flash_attention(q, k, v, window=window).astype(jnp.float32).sum()
+        return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+    compiled = jax.jit(grads).lower(q, kv, kv).compile()
+    text = compiled.as_text()
+    prefix = "_flash" if window is None else "_window_flash"
+    for kernel in ("forward", "backward_dkv", "backward_dq"):
+        assert f"{prefix}_{kernel}" in text, kernel
+    assert ("_window_flash" in text) == (window is not None)
+    assert compiled.memory_analysis().temp_size_in_bytes < B * Hq * S * 512 * 4
+
+
 def test_routed_experts_layer_compiles_for_v5e_without_a_scatter(one_chip):
     """One expert layer of `glm-4.7-flash.train-s8192`, forward and backward:
     16,384 tokens, top 4 of 64, 16 experts of 2048 x 1536 held and a shared

@@ -430,3 +430,203 @@ def test_jit_and_value_and_grad():
     val, grad = step(q, k, v)
     assert np.isfinite(float(val))
     assert np.isfinite(np.asarray(grad)).all()
+
+
+# --- the sliding window (PR 33) -------------------------------------------------
+#
+# Query t sees the keys t - W < j <= t.  The three kernels share one rule for
+# which pairs run and which build a mask, and a windowed call walks a shorter
+# grid, counted from its band's first block; each case below is checked against
+# XLA attention with the same window, output and all three gradients.
+
+WINDOW_CASES = {
+    "a-multiple-of-the-tile": dict(s=96, window=32),
+    "not-a-multiple": dict(s=96, window=20),
+    "wider-than-two-tiles": dict(s=96, window=41),
+    "one-key": dict(s=48, window=1),
+    "ragged-50": dict(s=50, window=20),
+    "ragged-50-window-of-a-tile": dict(s=50, window=16),
+    "longer-window-than-sequence": dict(s=40, window=64),
+    "group-of-6": dict(s=64, hq=6, hkv=1, window=24),
+    "group-of-8": dict(s=64, hq=8, hkv=1, window=24),
+    "group-of-6-two-kv-heads": dict(s=48, hq=12, hkv=2, window=17),
+    "wide-q-block": dict(s=96, window=20, block_q=32, block_k=16),
+    "wide-kv-block": dict(s=96, window=20, block_q=16, block_k=32),
+    "tiles-of-128": dict(b=1, s=400, hq=2, hkv=1, window=150, block_q=128, block_k=128),
+    "tiles-of-128-and-64": dict(b=1, s=300, hq=2, hkv=1, window=128, block_q=128, block_k=64),
+    "jit": dict(s=96, window=20, jit=True),
+}
+
+
+@pytest.fixture
+def window_blocks(monkeypatch):
+    """The windowed backward kernels' tiles, module constants like the
+    full-causal ones'."""
+
+    def set_blocks(block_q: int, block_k: int):
+        for name in ("WINDOW_BWD_DKV_BLOCKS", "WINDOW_BWD_DQ_BLOCKS"):
+            monkeypatch.setattr(pallas_attention, name, (block_q, block_k))
+
+    return set_blocks
+
+
+@pytest.mark.parametrize("case", WINDOW_CASES)
+def test_windowed_kernels_match_xla_attention_with_the_same_window(case, window_blocks):
+    kw = dict(b=2, s=48, hq=4, hkv=2, block_q=16, block_k=16, jit=False)
+    kw.update(WINDOW_CASES[case])
+    window_blocks(kw["block_q"], kw["block_k"])
+    q, k, v = _qkv(b=kw["b"], s=kw["s"], hq=kw["hq"], hkv=kw["hkv"])
+    flash = lambda q, k, v: flash_attention(
+        q, k, v, True, None, kw["block_q"], kw["block_k"], window=kw["window"]
+    )
+    ref = lambda q, k, v: dot_product_attention(q, k, v, causal=True, window=kw["window"])
+    np.testing.assert_allclose(
+        np.asarray(flash(q, k, v)), np.asarray(ref(q, k, v)), atol=2e-5, rtol=2e-5
+    )
+    for a, b in zip(_grads(flash, q, k, v, jit=kw["jit"]), _grads(ref, q, k, v)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-4, rtol=1e-4)
+
+
+def test_xla_attention_window_is_the_band_by_hand():
+    """`t - W < j <= t`: with W = 3, query 5 sees keys 3, 4, 5 and no other."""
+    q, k, v = _qkv(b=1, s=8, hq=1, hkv=1)
+    out = dot_product_attention(q, k, v, causal=True, window=3)
+    scores = np.einsum("d,jd->j", np.asarray(q[0, 5, 0]), np.asarray(k[0, 3:6, 0])) * 16**-0.5
+    p = np.exp(scores - scores.max())
+    want = (p / p.sum()) @ np.asarray(v[0, 3:6, 0])
+    np.testing.assert_allclose(np.asarray(out[0, 5, 0]), want, rtol=1e-5, atol=1e-6)
+    # query 1 has only keys 0 and 1 to see; a window is causal
+    np.testing.assert_allclose(
+        np.asarray(out[0, :2]), np.asarray(dot_product_attention(q, k, v)[0, :2]), rtol=1e-6
+    )
+    with pytest.raises(ValueError, match="causal"):
+        dot_product_attention(q, k, v, causal=False, window=3)
+    with pytest.raises(ValueError, match="causal"):
+        flash_attention(q, k, v, causal=False, window=3)
+
+
+def test_a_window_that_holds_every_key_is_bit_equal_to_no_window(backward_blocks, window_blocks):
+    """Another grid and another mask, the same numbers: with W = S no key is
+    hidden, and the output and the three gradients equal the full-causal
+    kernels' bit for bit."""
+    window_blocks(16, 16)
+    q, k, v = _qkv(s=50, hq=6, hkv=1)
+    full = lambda q, k, v: flash_attention(q, k, v, True, None, 16, 16)
+    windowed = lambda q, k, v: flash_attention(q, k, v, True, None, 16, 16, window=50)
+    np.testing.assert_array_equal(np.asarray(full(q, k, v)), np.asarray(windowed(q, k, v)))
+    for a, b in zip(_grads(full, q, k, v), _grads(windowed, q, k, v)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def _kernels_without_locations(window):
+    """name -> sha256 of the Mosaic kernel's MLIR printed without source
+    locations, lowered for the TPU at a small shape (no chip needed)."""
+    import base64
+    import hashlib
+    import re
+
+    from jax._src import tpu_custom_call  # noqa: F401  (registers the TPU dialect)
+    from jax._src.lib.mlir import ir
+
+    def loss(q, k, v):
+        return pallas_attention.flash_attention(q, k, v, window=window).astype(jnp.float32).sum()
+
+    q = jax.ShapeDtypeStruct((1, 256, 4, 128), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((1, 256, 2, 128), jnp.bfloat16)
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).trace(q, kv, kv).lower(
+        lowering_platforms=("tpu",)
+    ).as_text()
+    out = {}
+    for body, name in re.findall(
+        r'\\22body\\22: \\22([A-Za-z0-9+/=]+)\\22.*?kernel_name = "([^"]+)"', text
+    ):
+        context = ir.Context()
+        context.allow_unregistered_dialects = True
+        with context:
+            asm = ir.Module.parse(base64.b64decode(body)).operation.get_asm(enable_debug_info=False)
+        out[name] = hashlib.sha256(asm.encode()).hexdigest()
+    return out
+
+
+# The three full-causal kernels as the parent of PR 33 lowered them (commit
+# da80100), by `_kernels_without_locations(None)` in that tree.  A PR that
+# changes those kernels on purpose reads the new hashes the same way.
+KERNELS_BEFORE_THE_WINDOW = {
+    "_flash_forward": "4950a89ec7c3568dc58abfabea58218a2c1a39e6d62751dccdb82ae82b03e938",
+    "_flash_backward_dkv": "a507b27a6719320d968be0f4436ac66e7ba2d728b5f430256fdabac029b5b7c2",
+    "_flash_backward_dq": "ba1a0df13c479cedb5fd007e166001900b16d8586f25a08c6d4059ec37ce5478",
+}
+
+
+def test_without_a_window_the_kernels_are_the_ones_before_it():
+    """`window=None` traces no operation more or fewer: the Mosaic modules are
+    the parent's, operation for operation (their serialized form also carries
+    source lines, which moved, so the cells' lowered text differs there and
+    nowhere else: PERF.md, PR 33)."""
+    assert _kernels_without_locations(None) == KERNELS_BEFORE_THE_WINDOW
+
+
+def test_windowed_kernels_carry_names_the_full_causal_readers_do_not_match():
+    """`attention_roofline_share` and `attention_backward_roofline_share` find
+    their kernels by `^_flash_forward` / `^_flash_backward` and divide by a
+    full-causal cost; a windowed call is another program under another name."""
+    windowed = _kernels_without_locations(64)
+    assert set(windowed) == {
+        "_window_flash_forward", "_window_flash_backward_dkv", "_window_flash_backward_dq"
+    }
+    assert not any(n.startswith(("_flash_forward", "_flash_backward")) for n in windowed)
+    assert not set(windowed.values()) & set(KERNELS_BEFORE_THE_WINDOW.values())
+
+
+@pytest.mark.parametrize("seq,block_q,block_k,window,steps", [
+    (8192, 512, 512, 512, 2),    # the band of an aligned q block: its own kv block and the one before
+    (8192, 1024, 1024, 512, 2),
+    (8192, 1024, 512, 512, 3),   # 1,535 keys from an aligned start: three blocks of 512
+    (8192, 512, 1024, 512, 2),
+    (8192, 256, 256, 512, 3),
+    (96, 16, 16, 20, 3),         # 35 keys from position 16 i - 19: three blocks of 16
+    (48, 16, 16, 1, 1),
+    (40, 16, 16, 64, 3),         # a window longer than the sequence: the causal triangle
+])
+def test_a_windowed_grid_has_as_many_kv_steps_as_the_widest_band(seq, block_q, block_k, window, steps):
+    nq, nk = -(-seq // block_q), -(-seq // block_k)
+    assert pallas_attention._kv_steps(nq, nk, block_q, block_k, window) == steps
+    if seq > 128:
+        return
+    # and by the rule itself: the kv blocks that hold a key some query of the block sees
+    most = max(
+        len({key // block_k for t in range(i * block_q, min((i + 1) * block_q, seq))
+             for key in range(max(0, t - window + 1), t + 1)})
+        for i in range(nq)
+    )
+    assert most == steps
+
+
+def test_ring_attention_refuses_a_window_by_name():
+    from deeplearning_cfn_tpu.models.llama import attend
+    from deeplearning_cfn_tpu.parallel.ring_attention import ring_attention
+
+    q, k, v = _qkv(s=16)
+    with pytest.raises(NotImplementedError, match="sliding window"):
+        ring_attention(q, k, v, mesh=None, window=8)
+    with pytest.raises(NotImplementedError, match="sliding window"):
+        attend("ring", q, k, v, None, window=8)
+    np.testing.assert_array_equal(
+        np.asarray(attend("xla", q, k, v, None, window=8)),
+        np.asarray(dot_product_attention(q, k, v, causal=True, window=8)),
+    )
+
+
+def test_windowed_tiles_at_the_cells_shape_are_the_sweeps():
+    """S 8192 under a window of 512 (PERF.md, PR 33): the forward kernel at
+    512 x 1024, both backward kernels at 512 x 512, none of them clamped; and a
+    call that names no tiles takes the kind's own."""
+    from deeplearning_cfn_tpu.ops.pallas_attention import _clamp_block
+
+    assert pallas_attention.WINDOW_FWD_BLOCKS == (512, 1024)
+    assert pallas_attention.WINDOW_BWD_DKV_BLOCKS == pallas_attention.WINDOW_BWD_DQ_BLOCKS == (512, 512)
+    for blocks in (pallas_attention.WINDOW_FWD_BLOCKS, pallas_attention.WINDOW_BWD_DKV_BLOCKS):
+        assert [_clamp_block(b, 8192) for b in blocks] == list(blocks)
+    # at most two kv blocks a q block, and two q blocks a kv block, at those tiles
+    assert pallas_attention._kv_steps(16, 8, 512, 1024, 512) == 2
+    assert pallas_attention._q_steps(16, 16, 512, 512, 512) == 2

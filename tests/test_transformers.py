@@ -18,6 +18,14 @@ RING_VS_DENSE_SCRIPT = """
 import os
 os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["JAX_ENABLE_COMPILATION_CACHE"] = "false"
+# The CPU client's thread pools have one thread a core unless PJRT_NPROC
+# says otherwise (xla/pjrt/utils.cc DefaultThreadPoolSize).  The thunk
+# executor runs collectives on those threads and a collective blocks its
+# thread until every participant has arrived: with 8 devices, several
+# cross-module collectives in flight and 8 threads, the last participant's
+# thunk can be queued behind blocked ones and never runs (user time 12 s of
+# a 200 s deadline, PR 33: not starvation).  More threads than participants.
+os.environ.setdefault("PJRT_NPROC", "64")
 if "xla_force_host_platform_device_count" not in os.environ.get("XLA_FLAGS", ""):
     os.environ["XLA_FLAGS"] = (
         os.environ.get("XLA_FLAGS", "") + " --xla_force_host_platform_device_count=8"
