@@ -30,6 +30,15 @@ transformer flagships (BERT, Llama-3).  Design is TPU-first:
   ``_window_flash_backward_dq``): the benchmark's readers find the
   full-causal kernels by the prefixes ``_flash_forward`` / ``_flash_backward``
   and divide by a full-causal cost.
+- The band step (``_window_flash_forward_band``): a windowed forward call
+  whose window is whole lane tiles (128..1024) and shorter than the sequence,
+  from a caller that names no tiles, takes a q block of ``window`` rows with
+  its whole band, the two aligned kv blocks, in one grid step.  The kv axis
+  and the online softmax go: one maximum, one exponential and one sum over
+  scores that are all inside the band, rows in chunks that skip the band's
+  empty sub-tiles, the log-sum-exp written as a compact row.  The shapes
+  choose (`_takes_band_step`), no argument does; every other windowed call
+  keeps the grid above, and the backward kernels are the same for both.
 - Grouped-query attention is handled in the BlockSpec index maps (a kv head
   is fetched for ``group = Hq // Hkv`` query heads) — no materialized
   ``repeat`` anywhere, forward or backward.
@@ -102,20 +111,28 @@ BWD_DQ_BLOCKS = (1024, 1024)
 _BWD_VMEM_LIMIT = 64 * 1024 * 1024
 # The tiles of calls with a window, (q block, kv block) of the forward, the
 # dk/dv and the dq kernel, from a sweep on v5e at the window layers' shape of
-# `laguna-xs.2.train-s8192` (scripts/chip_grouped_matmul_sweep.py window, PR 33;
+# `laguna-xs.2.train-s8192` (scripts/chip_grouped_matmul_sweep.py window, PR 36;
 # B 2, S 8192, 64/8 heads of 128, window 512, bf16; ms a call, kv block 256 /
 # 512 / 1024):
 #             forward               dk/dv                 dq
 #   q  256: 16.23  11.67  11.65    10.34   9.59  11.18    9.45   7.99  11.97
 #   q  512: 18.64  10.27   9.82     9.24   8.02  10.79    9.16   6.49  10.80
 #   q 1024: 24.22  11.51  10.84    14.31  11.94  13.48   10.32   8.82  11.28
-# A call's band holds 0.27 TFLOP forward (1.35 ms at the chip's peak): the
-# forward reaches 14% of it and the backward pair 23%.  Every pair a windowed
-# call runs at these tiles is a masked one (the diagonal, or the window's
-# edge); 1024 x 1024 runs its tiles at the full-causal kernel's rate with a
-# quarter of their scores inside the band, 512 x 512 at half that rate with
-# half inside, which comes to the same.  Beside them the full-causal kernels
-# at the cell's 48/8 heads: 15.13 ms forward, 20.93 + 17.48 backward.
+#   band:    2.91 (rows in chunks of 256; 3.21 the whole block, 3.44 chunks of 128)
+# A call's band holds 0.27 TFLOP forward (1.35 ms at the chip's peak).  The
+# tiled forward is not bound by its matmuls: the grid steps that run a pair
+# take 1.36 / 1.47 / 1.98 us at a q block of 256, 2.35 / 2.59 / 3.34 at 512 and
+# 4.11 / 3.91 / 5.65 at 1024, of which both tile matmuls at the peak are 12-48%
+# (41% at 512 x 1024); the rest, the softmax's passes over the tile, the running
+# statistics and the accumulator's round trip, follows the q block's rows and
+# is paid on every kv step.  The band step (`_band_forward`: what a call runs
+# that `_takes_band_step`, this shape among them) pays it once a q block: 2,048
+# steps of 1.42 us, 70% of them its matmuls at the peak, 46% of the band's
+# roofline where 512 x 1024 reaches 14%.  `WINDOW_FWD_BLOCKS` is what the other
+# windowed calls run.  The backward pair reaches 23%: every pair it runs at
+# 512 x 512 is a masked one with half of its scores inside the band.  Beside
+# them the full-causal kernels at the cell's 48/8 heads: 15.13 ms forward,
+# 20.93 + 17.48 backward.
 WINDOW_FWD_BLOCKS = (512, 1024)
 WINDOW_BWD_DKV_BLOCKS = (512, 512)
 WINDOW_BWD_DQ_BLOCKS = (512, 512)
@@ -434,6 +451,158 @@ def _flash_forward(
     return out, lse[:, :, :Sq, 0]  # [B, Hq, Sq]
 
 
+# --- the band step: a windowed forward call whose q block is the window -----
+#
+# With ``block_q == window == W`` (S padded to whole blocks) q block i sees keys
+# of two aligned kv blocks only, i - 1 and i, in complementary triangles: at row
+# r and column c of the block, key c of block i - 1 where ``c > r`` and key c of
+# block i where ``c <= r``.  Both blocks are resident, so one grid step holds the
+# q block's whole band: one maximum, one exponential and one sum over scores that
+# are all real ones, no running statistics, no accumulator, no second visit.
+# Padding needs no mask: only padded rows see padded keys, and they are cut off.
+
+
+def _takes_band_step(window: int | None, block_q, block_k, seq_q: int, seq_k: int) -> bool:
+    """Whether a forward call runs the band step, from what the call can see:
+    a window that is whole lane tiles and whose float32 [W, W] score tile fits
+    `_FWD_VMEM_LIMIT`, shorter than the sequence it attends within, and a caller
+    that named no tiles.  Every other windowed call walks `_attn_kernel`'s grid."""
+    return (
+        window is not None and block_q is None and block_k is None
+        and window % 128 == 0 and 128 <= window <= 1024
+        and seq_q == seq_k and seq_q > window
+    )
+
+
+# Rows of a q block the band step takes at a time, where that divides the
+# window (else the whole block): sub-tiles of the resident band.  A chunk of
+# rows [a, a + C) sees the own block's columns before a whole, the block
+# before's columns from a + C on whole, and the two triangles in the C columns
+# between, so it runs (W + C) / 2W of the matmuls of a whole-block step and
+# builds the triangle's select on [C, C] alone.  On v5e at the Laguna cell's
+# shape (W 512; scripts/chip_grouped_matmul_sweep.py window, PR 36) 256 runs a
+# call in 2.91 ms (1.42 us a step, 70% of it the matmuls at the peak), the
+# whole block in 3.21 (84%: the MXU binds) and 128 in 3.44 (49%: matmuls of
+# 128 rows run far from the peak).  Other windows were compiled, not timed.
+_BAND_ROW_CHUNK = 256
+
+
+def _band_kernel(
+    q_ref,  # [1, 1, W, D]
+    k_prev_ref,  # [1, 1, W, D]: kv block i - 1 (block 0 again where i is 0)
+    k_own_ref,  # [1, 1, W, D]: kv block i
+    v_prev_ref,
+    v_own_ref,
+    out_ref,  # [1, 1, W, D]
+    lse_ref,  # [1, 1, 1, W]: a row along the lanes, as the dk/dv kernel reads it
+    *,
+    sm_scale: float,
+    window: int,
+    need_lse: bool,
+):
+    chunk = _BAND_ROW_CHUNK if window % _BAND_ROW_CHUNK == 0 else window
+    row = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    before = col > row  # where the block before is seen; elsewhere the q block's own
+
+    def scores(q, k_rows):  # [chunk, n] float32, scaled
+        nt = (((1,), (1,)), ((), ()))
+        return jax.lax.dot_general(q, k_rows, nt, preferred_element_type=jnp.float32) * sm_scale
+
+    def weighted(p, v_rows):  # [chunk, D] float32
+        nn = (((1,), (0,)), ((), ()))
+        return jax.lax.dot_general(
+            p.astype(v_rows.dtype), v_rows, nn, preferred_element_type=jnp.float32
+        )
+
+    def step(first: bool):
+        """``first``: the q block with no block before it, whose band is the
+        causal triangle of its own block, op for op what `_attn_kernel`
+        computes for a diagonal pair from fresh statistics."""
+        for a in range(0, window, chunk):
+            rows = slice(a, a + chunk)
+            # the columns the chunk sees whole: the own block's before its rows,
+            # the block before's past them
+            whole = [(k_own_ref, v_own_ref, slice(0, a))] if a > 0 else []
+            if not first and a + chunk < window:
+                whole.append((k_prev_ref, v_prev_ref, slice(a + chunk, window)))
+            q = q_ref[0, 0, rows, :]
+            own = scores(q, k_own_ref[0, 0, rows, :])
+            if first:
+                tiles = [jnp.where(before, NEG_INF, own)]
+            else:
+                tiles = [jnp.where(before, scores(q, k_prev_ref[0, 0, rows, :]), own)]
+            tiles += [scores(q, k_ref[0, 0, cols, :]) for k_ref, _, cols in whole]
+            m = functools.reduce(jnp.maximum, [jnp.max(s, axis=1, keepdims=True) for s in tiles])
+            # exp(NEG_INF - m) is 0: every row holds its own key
+            tiles = [jnp.exp(s - m) for s in tiles]
+            l = functools.reduce(jnp.add, [jnp.sum(p, axis=1, keepdims=True) for p in tiles])
+            if first:
+                acc = weighted(tiles[0], v_own_ref[0, 0, rows, :])
+            else:
+                acc = weighted(jnp.where(before, 0.0, tiles[0]), v_own_ref[0, 0, rows, :])
+                acc += weighted(jnp.where(before, tiles[0], 0.0), v_prev_ref[0, 0, rows, :])
+            for p, (_, v_ref, cols) in zip(tiles[1:], whole):
+                acc += weighted(p, v_ref[0, 0, cols, :])
+            out_ref[0, 0, rows, :] = (acc / l).astype(out_ref.dtype)
+            if need_lse:
+                lse = jnp.broadcast_to(m + jnp.log(l), (chunk, 128))
+                lse_ref[0, 0, :, rows] = jnp.transpose(lse)[:1]  # [chunk, 1] -> [1, chunk]
+
+    block = pl.program_id(2)
+    pl.when(block == 0)(lambda: step(True))
+    pl.when(block > 0)(lambda: step(False))
+
+
+@functools.partial(jax.jit, static_argnames=("sm_scale", "window", "interpret", "need_lse"))
+def _band_forward(
+    q: jax.Array,
+    k: jax.Array,
+    v: jax.Array,
+    sm_scale: float,
+    window: int,
+    interpret: bool,
+    need_lse: bool = True,
+):
+    """`_flash_forward`'s results (out [B, S, Hq, D] and lse [B, Hq, S]) for a
+    causal call under ``window``, by the band step: grid (batch, kv head, q
+    block, query head of the group), the group innermost so that its heads
+    find the kv head's two blocks where the head before them left them."""
+    B, S, Hq, D = q.shape
+    Hkv = k.shape[2]
+    group = Hq // Hkv
+    qt, kt, vt = (jnp.swapaxes(_pad_seq(x, window), 1, 2) for x in (q, k, v))
+    s_p = qt.shape[2]
+
+    q_spec = pl.BlockSpec((1, 1, window, D), lambda b, h, i, g: (b, h * group + g, i, 0))
+    prev_spec = pl.BlockSpec((1, 1, window, D), lambda b, h, i, g: (b, h, jnp.maximum(i - 1, 0), 0))
+    own_spec = pl.BlockSpec((1, 1, window, D), lambda b, h, i, g: (b, h, i, 0))
+    if need_lse:
+        # One float32 a row, 4 MB a call at the Laguna cell's shape where the
+        # lane-replicated form is 537 MB written and sliced again by XLA.
+        lse_spec = pl.BlockSpec((1, 1, 1, window), lambda b, h, i, g: (b, h * group + g, 0, i))
+        lse_shape = jax.ShapeDtypeStruct((B, Hq, 1, s_p), jnp.float32)
+    else:  # as `_flash_forward`: one dummy tile that nothing writes
+        lse_spec = pl.BlockSpec((1, 1, 8, 128), lambda b, h, i, g: (0, 0, 0, 0))
+        lse_shape = jax.ShapeDtypeStruct((1, 1, 8, 128), jnp.float32)
+    out, lse = pl.pallas_call(
+        functools.partial(_band_kernel, sm_scale=sm_scale, window=window, need_lse=need_lse),
+        grid=(B, Hkv, s_p // window, group),
+        in_specs=[q_spec, prev_spec, own_spec, prev_spec, own_spec],
+        out_specs=[q_spec, lse_spec],
+        out_shape=[jax.ShapeDtypeStruct((B, Hq, s_p, D), q.dtype), lse_shape],
+        compiler_params=_compiler_params(_FWD_VMEM_LIMIT),
+        interpret=interpret,
+        # Begins `_window_flash_forward`, so the window's readers find it and
+        # the full-causal ones do not; the suffix says which step shape ran.
+        name="_window_flash_forward_band",
+    )(qt, kt, kt, vt, vt)
+    out = jnp.swapaxes(out, 1, 2)[:, :S]
+    if not need_lse:
+        return out, None
+    return out, lse[:, :, 0, :S]
+
+
 # --- backward: two Pallas kernels (flash-attention-2) ---------------------
 #
 # Both recompute the probabilities of one (q block, kv block) pair from the
@@ -717,11 +886,7 @@ def _flash_backward(
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
 def _flash_core(q, k, v, causal, sm_scale, block_q, block_k, interpret, window):
     # Primal-only path (no grad being taken): skip the LSE output entirely.
-    bq = _clamp_block(block_q, q.shape[1])
-    bk = _clamp_block(block_k, k.shape[1])
-    out, _ = _flash_forward(
-        q, k, v, causal, sm_scale, bq, bk, interpret, need_lse=False, window=window
-    )
+    out, _ = _forward(q, k, v, causal, sm_scale, block_q, block_k, interpret, window, need_lse=False)
     return out
 
 
@@ -771,10 +936,24 @@ def _clamp_block(block: int, seq: int) -> int:
     return min(best, seq_t)
 
 
+def _forward(q, k, v, causal, sm_scale, block_q, block_k, interpret, window, need_lse):
+    """The forward call the shapes choose: the band step where the window and
+    the sequence allow it and the caller named no tiles, else the tiled kernel
+    at the caller's tiles or the swept ones of the kind of call, clamped."""
+    if _takes_band_step(window, block_q, block_k, q.shape[1], k.shape[1]):
+        return _band_forward(q, k, v, sm_scale, window, interpret, need_lse=need_lse)
+    default_q, default_k = (
+        (DEFAULT_BLOCK_Q, DEFAULT_BLOCK_K) if window is None else WINDOW_FWD_BLOCKS
+    )
+    bq = _clamp_block(default_q if block_q is None else block_q, q.shape[1])
+    bk = _clamp_block(default_k if block_k is None else block_k, k.shape[1])
+    return _flash_forward(
+        q, k, v, causal, sm_scale, bq, bk, interpret, need_lse=need_lse, window=window
+    )
+
+
 def _core_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret, window):
-    bq = _clamp_block(block_q, q.shape[1])
-    bk = _clamp_block(block_k, k.shape[1])
-    out, lse = _flash_forward(q, k, v, causal, sm_scale, bq, bk, interpret, window=window)
+    out, lse = _forward(q, k, v, causal, sm_scale, block_q, block_k, interpret, window, need_lse=True)
     return out, (q, k, v, out, lse)
 
 
@@ -817,7 +996,8 @@ def flash_attention(
     ``window``: a sliding window of that many keys, the query's own among
     them (``t - window < j <= t``); causal only.  ``block_q`` / ``block_k``
     left out are the swept forward tiles of the kind of call
-    (``DEFAULT_BLOCK_*``, or ``WINDOW_FWD_BLOCKS`` under a window).
+    (``DEFAULT_BLOCK_*``, or ``WINDOW_FWD_BLOCKS`` under a window); with both
+    left out a window the band step takes (`_takes_band_step`) runs that.
 
     Compiled Mosaic kernel unless ``interpret=True`` (the Pallas
     interpreter: slow, for CPU tests — see module docstring).  Off a TPU
@@ -838,11 +1018,6 @@ def flash_attention(
         window = int(window)
         if not causal or window < 1:
             raise ValueError(f"a window ({window}) is a positive number of keys, and causal")
-    default_q, default_k = (
-        (DEFAULT_BLOCK_Q, DEFAULT_BLOCK_K) if window is None else WINDOW_FWD_BLOCKS
-    )
-    block_q = default_q if block_q is None else block_q
-    block_k = default_k if block_k is None else block_k
 
     def core(q, k, v):
         # nondiff argnums must be positional for custom_vjp

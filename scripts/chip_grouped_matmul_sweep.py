@@ -14,7 +14,8 @@ and the backward kernels at the cell's 20 heads of 256.  `window` (run by name
 only) is the three kernels under a sliding window at the shape of
 `laguna-xs.2.train-s8192`'s window layers (64/8 heads of 128, S 8192, window
 512) by tile, with the full-causal kernels at its full layers' 48 heads beside
-them (`WINDOW_FWD_BLOCKS`, `WINDOW_BWD_DKV_BLOCKS`, `WINDOW_BWD_DQ_BLOCKS`).  `routing` (run by
+them (`WINDOW_FWD_BLOCKS`, `WINDOW_BWD_DKV_BLOCKS`, `WINDOW_BWD_DQ_BLOCKS`), and
+the forward's band step by its rows a chunk (`_BAND_ROW_CHUNK`).  `routing` (run by
 name only) is one whole routed layer, `ops/moe.routed_experts`, forward and
 backward at that shape and at both cells' expert widths, by the tile of its
 passes over the sorted buffer (`ROW_TILE` in `ops/moe.py` is picked from it)
@@ -236,6 +237,11 @@ def pair_counts(seq: int, block_q: int, block_k: int, window: int | None = None)
 
 
 def forward_sweep(shape, window: int | None = None, blocks=FORWARD_BLOCKS) -> None:
+    """The forward kernel by tile; under a window each row also says how many
+    grid steps of a call run a pair, the time a step, and what share of the
+    call's time its tile matmuls (both, every score of the tile) would take at
+    the chip's peak.  Then the band step (`_window_flash_forward_band`, PR 36)
+    by its rows a chunk, the same columns: one step a q block of `window` rows."""
     import jax
     import jax.numpy as jnp
 
@@ -248,15 +254,16 @@ def forward_sweep(shape, window: int | None = None, blocks=FORWARD_BLOCKS) -> No
     k, v = (jax.random.normal(kk, (B, S, KV, hd), jnp.bfloat16) for kk in keys[1:])
     best = None
     kernel = r"^_flash_forward" if window is None else r"^_window_flash_forward"
-    for bq, bk in itertools.product(blocks, repeat=2):
-        row = {"attention_forward": list(shape), "window": window, "block_q": bq, "block_k": bk}
-        row.update(pair_counts(S, bq, bk, window))
-        run = lambda: pa._flash_forward(q, k, v, True, hd**-0.5, bq, bk, False, window=window)
+
+    def timed(row, run, steps=None, tile_scores=None):
+        """Print `row` with the kernel's device time a call; `steps`: the grid
+        steps of a call that run a pair, `tile_scores`: the scores they compute."""
+        nonlocal best
         try:
             jax.block_until_ready(run())
         except Exception as e:
             print(json.dumps({**row, "error": str(e)[:300]}, allow_nan=False), flush=True)
-            continue
+            return
         trace_dir = tempfile.mkdtemp(prefix="fwd_sweep_")
         with jax.profiler.trace(trace_dir):
             jax.block_until_ready([run() for _ in range(CALLS)])
@@ -264,9 +271,34 @@ def forward_sweep(shape, window: int | None = None, blocks=FORWARD_BLOCKS) -> No
         shutil.rmtree(trace_dir, ignore_errors=True)
         seconds, calls = trace_reduce.kernel_seconds(rows, trace_reduce.devices(rows)[0], kernel)
         row["forward_ms"] = 1e3 * seconds / calls if calls else None
+        if calls and steps:
+            row["live_steps"] = steps
+            row["us_a_step"] = 1e6 * seconds / calls / steps
+            row["matmuls_at_peak_share"] = 2 * 2 * hd * tile_scores / 197e12 / (seconds / calls)
         print(json.dumps(row, allow_nan=False), flush=True)
         if calls and (best is None or row["forward_ms"] < best["forward_ms"]):
             best = row
+
+    for bq, bk in itertools.product(blocks, repeat=2):
+        row = {"attention_forward": list(shape), "window": window, "block_q": bq, "block_k": bk}
+        row.update(pair_counts(S, bq, bk, window))
+        run = lambda: pa._flash_forward(q, k, v, True, hd**-0.5, bq, bk, False, window=window)
+        steps = B * H * (row["masked"] + row["unmasked"]) if window else None
+        timed(row, run, steps, steps and steps * bq * bk)
+    if window and hasattr(pa, "_band_forward"):
+        swept = pa._BAND_ROW_CHUNK
+        for chunk in (128, 256, window):
+            # the module's constant, read when the call is traced
+            pa._BAND_ROW_CHUNK = chunk
+            pa._band_forward.clear_cache()
+            row = {"attention_forward": list(shape), "window": window, "band": True,
+                   "block_q": window, "rows_a_chunk": chunk}
+            steps = B * H * (S // window)
+            # a step's chunks of C rows see W + C columns each, the first block's half of that
+            scores = B * H * (S // window - 0.5) * window * (window + chunk)
+            timed(row, lambda: pa._band_forward(q, k, v, hd**-0.5, window, False), steps, scores)
+        pa._BAND_ROW_CHUNK = swept
+        pa._band_forward.clear_cache()
     print(json.dumps({"best_forward": best}, allow_nan=False), flush=True)
 
 

@@ -64,12 +64,15 @@ def test_bf16_io():
     )
 
 
-def _reference_lse(q, k, causal):
+def _reference_lse(q, k, causal, window=None):
     """log-sum-exp of each query's valid scores, [B, Hq, Sq], in plain XLA."""
     k = jnp.repeat(k, q.shape[2] // k.shape[2], axis=2)
     s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * q.shape[-1] ** -0.5
     if causal:
-        s = jnp.where(jnp.tril(jnp.ones(s.shape[-2:], bool)), s, -jnp.inf)
+        seen = jnp.tril(jnp.ones(s.shape[-2:], bool))
+        if window is not None:  # t - window < j <= t
+            seen &= ~jnp.tril(seen, -window)
+        s = jnp.where(seen, s, -jnp.inf)
     return jax.nn.logsumexp(s, axis=-1)
 
 
@@ -574,8 +577,31 @@ def test_windowed_kernels_carry_names_the_full_causal_readers_do_not_match():
     assert set(windowed) == {
         "_window_flash_forward", "_window_flash_backward_dkv", "_window_flash_backward_dq"
     }
-    assert not any(n.startswith(("_flash_forward", "_flash_backward")) for n in windowed)
-    assert not set(windowed.values()) & set(KERNELS_BEFORE_THE_WINDOW.values())
+    # at a window of 128 (S 256) the forward is the band step (PR 36), under a
+    # name the window's readers find by the same prefix; the backward pair stays
+    band = _kernels_without_locations(128)
+    assert set(band) == {
+        "_window_flash_forward_band", "_window_flash_backward_dkv", "_window_flash_backward_dq"
+    }
+    for kernels in (windowed, band):
+        assert not any(n.startswith(("_flash_forward", "_flash_backward")) for n in kernels)
+        assert not set(kernels.values()) & set(KERNELS_BEFORE_THE_WINDOW.values())
+
+
+# The three windowed kernels at a window of 64 as the parent of PR 36 lowered
+# them (commit 78316ca), by `_kernels_without_locations(64)` in that tree.
+WINDOWED_KERNELS_BEFORE_THE_BAND_STEP = {
+    "_window_flash_forward": "890baebf7c6291b91f3a9e00a22a948c7412cb5b704fa87ba4a8c19131ca5946",
+    "_window_flash_backward_dkv": "5dd0575139bb1c57972bddc34693870b3d63df216135e9982a20f250c477e47f",
+    "_window_flash_backward_dq": "6420b71f9905714d7834ea58097debbbd525caba459cfe244ee929408ff1c50c",
+}
+
+
+def test_outside_the_band_steps_shapes_a_windowed_call_is_the_one_before_it():
+    """A window the band step does not take (64: not whole lane tiles) lowers
+    to the parent's three Mosaic modules, operation for operation: the tiled
+    forward's grid and body are unchanged, and the backward pair is untouched."""
+    assert _kernels_without_locations(64) == WINDOWED_KERNELS_BEFORE_THE_BAND_STEP
 
 
 @pytest.mark.parametrize("seq,block_q,block_k,window,steps", [
@@ -618,10 +644,25 @@ def test_ring_attention_refuses_a_window_by_name():
 
 
 def test_windowed_tiles_at_the_cells_shape_are_the_sweeps():
-    """S 8192 under a window of 512 (PERF.md, PR 33): the forward kernel at
-    512 x 1024, both backward kernels at 512 x 512, none of them clamped; and a
-    call that names no tiles takes the kind's own."""
+    """S 8192 under a window of 512 (PERF.md, PR 33 and 36): the forward is the
+    band step, one grid step a q block of 512 rows with its two kv blocks of
+    512; both backward kernels run 512 x 512, not clamped.  A windowed call the
+    band step does not take and that names no tiles runs the forward at
+    `WINDOW_FWD_BLOCKS`, 512 x 1024."""
     from deeplearning_cfn_tpu.ops.pallas_attention import _clamp_block
+
+    assert pallas_attention._takes_band_step(512, None, None, 8192, 8192)
+    q = jax.ShapeDtypeStruct((2, 8192, 64, 128), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((2, 8192, 8, 128), jnp.bfloat16)
+    traced = jax.make_jaxpr(
+        lambda q, k, v: pallas_attention.flash_attention(q, k, v, window=512, interpret=True)
+    )(q, kv, kv)
+    (call,) = _named(traced.jaxpr, "pallas_call")
+    assert call.params["name"] == "_window_flash_forward_band"
+    mapping = call.params["grid_mapping"]
+    assert mapping.grid == (2, 8, 16, 8)  # batch, kv head, q block, query head of the group
+    for operand in mapping.block_mappings[:5]:  # q, and k and v of the block before and the own
+        assert [d.block_size for d in operand.block_shape[2:]] == [512, 128]
 
     assert pallas_attention.WINDOW_FWD_BLOCKS == (512, 1024)
     assert pallas_attention.WINDOW_BWD_DKV_BLOCKS == pallas_attention.WINDOW_BWD_DQ_BLOCKS == (512, 512)
@@ -630,3 +671,125 @@ def test_windowed_tiles_at_the_cells_shape_are_the_sweeps():
     # at most two kv blocks a q block, and two q blocks a kv block, at those tiles
     assert pallas_attention._kv_steps(16, 8, 512, 1024, 512) == 2
     assert pallas_attention._q_steps(16, 16, 512, 512, 512) == 2
+
+
+# --- the band step (PR 36) --------------------------------------------------------
+#
+# A windowed forward call whose window is whole lane tiles and shorter than the
+# sequence, from a caller that names no tiles, takes a q block of `window` rows
+# with its whole band in one grid step.  Each case: output, the three gradients
+# (the unchanged backward kernels consume the band step's lse) and the lse itself
+# against XLA.  W 128: the q blocks are 128 rows, so S 512 is four of them, S 400
+# and 300 pad the last one (rows and keys), S 129 is one row past the window.
+
+BAND_CASES = {
+    "aligned-s512": dict(s=512),
+    "ragged-s400": dict(s=400),
+    "ragged-s300": dict(s=300),
+    "one-row-past-the-window": dict(s=129),
+    "group-of-8": dict(s=256, hq=8, hkv=1),
+    "group-of-6-two-kv-heads": dict(s=256, hq=12, hkv=2),
+    "group-of-1-two-kv-heads": dict(s=256, hq=2, hkv=2),
+    "two-sequences": dict(b=2, s=256),
+    "jit": dict(s=300, jit=True),
+    # rows in chunks of `_BAND_ROW_CHUNK` (256): two a q block of 512, three of 768
+    "window-512": dict(s=1100, window=512),
+    "window-768": dict(s=1536, window=768, hq=1),
+    "window-384-in-one-chunk": dict(s=800, window=384, hq=1),
+}
+
+
+def _band_case(case):
+    kw = dict(b=1, s=256, hq=2, hkv=1, window=128, jit=False)
+    kw.update(BAND_CASES[case])
+    return kw, _qkv(b=kw["b"], s=kw["s"], hq=kw["hq"], hkv=kw["hkv"], seed=7)
+
+
+@pytest.mark.parametrize("case", BAND_CASES)
+def test_band_step_matches_xla_attention_with_the_same_window(case):
+    kw, (q, k, v) = _band_case(case)
+    assert pallas_attention._takes_band_step(kw["window"], None, None, kw["s"], kw["s"])
+    flash = lambda q, k, v: flash_attention(q, k, v, window=kw["window"])
+    ref = lambda q, k, v: dot_product_attention(q, k, v, causal=True, window=kw["window"])
+    run = jax.jit(flash) if kw["jit"] else flash
+    np.testing.assert_allclose(
+        np.asarray(run(q, k, v)), np.asarray(ref(q, k, v)), atol=2e-5, rtol=2e-5
+    )
+    for a, b in zip(_grads(flash, q, k, v, jit=kw["jit"]), _grads(ref, q, k, v)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-4, rtol=1e-4)
+
+
+@pytest.mark.parametrize("case", BAND_CASES)
+def test_band_step_lse_is_the_logsumexp_of_the_banded_scores(case):
+    kw, (q, k, v) = _band_case(case)
+    out, lse = pallas_attention._band_forward(q, k, v, q.shape[-1] ** -0.5, kw["window"], True)
+    assert lse.shape == (q.shape[0], q.shape[2], q.shape[1]) and lse.dtype == jnp.float32
+    want = _reference_lse(q, k, True, window=kw["window"])
+    np.testing.assert_allclose(np.asarray(lse), np.asarray(want), atol=2e-5, rtol=2e-5)
+    # and the primal-only form writes no lse and the same output
+    alone, none = pallas_attention._band_forward(
+        q, k, v, q.shape[-1] ** -0.5, kw["window"], True, need_lse=False
+    )
+    assert none is None
+    np.testing.assert_array_equal(np.asarray(alone), np.asarray(out))
+
+
+def test_band_steps_first_q_block_is_the_full_causal_kernels():
+    """The first q block has no block before it: its step is the causal
+    diagonal block, computed operation for operation as `_attn_kernel` computes
+    a masked pair from fresh statistics (alpha is 0, so the accumulator is the
+    product and the sum the row's), and rows 0..W-1 see the same keys with and
+    without the window.  So output and lse equal the full-causal kernel's at
+    tiles of W bit for bit."""
+    q, k, v = _qkv(b=1, s=300, hq=4, hkv=2, seed=5)
+    scale = q.shape[-1] ** -0.5
+    band_out, band_lse = pallas_attention._band_forward(q, k, v, scale, 128, True)
+    full_out, full_lse = pallas_attention._flash_forward(q, k, v, True, scale, 128, 128, True)
+    np.testing.assert_array_equal(np.asarray(band_out[:, :128]), np.asarray(full_out[:, :128]))
+    np.testing.assert_array_equal(np.asarray(band_lse[..., :128]), np.asarray(full_lse[..., :128]))
+    # from row W on the window hides keys, and the two differ
+    assert np.abs(np.asarray(band_out[:, 128:]) - np.asarray(full_out[:, 128:])).max() > 1e-3
+
+
+# (batch, seq, q heads, kv heads, window, tiles named): which forward kernel a
+# call lowers to.  The band step: the Laguna cell's window layers and
+# tests/test_kernels_compile_for_tpu.py's ragged case.  The tiled kernel: a
+# window that is not whole lane tiles (20, 300), one past 1024, one as long as
+# the sequence or longer, and a caller that names a tile.
+SELECTION_CASES = {
+    "the-cells-shape": ((2, 8192, 64, 8, 512, {}), "_window_flash_forward_band"),
+    "ragged-s2100-window-512": ((1, 2100, 64, 8, 512, {}), "_window_flash_forward_band"),
+    "window-1024": ((1, 4096, 8, 8, 1024, {}), "_window_flash_forward_band"),
+    "window-20": ((1, 2048, 4, 2, 20, {}), "_window_flash_forward"),
+    "window-300-not-a-multiple": ((1, 4096, 12, 2, 300, {}), "_window_flash_forward"),
+    "window-2048": ((1, 8192, 4, 2, 2048, {}), "_window_flash_forward"),
+    "window-as-long-as-the-sequence": ((1, 512, 4, 2, 512, {}), "_window_flash_forward"),
+    "window-longer-than-the-sequence": ((1, 256, 4, 2, 512, {}), "_window_flash_forward"),
+    "names-block-q": ((2, 8192, 64, 8, 512, dict(block_q=512)), "_window_flash_forward"),
+    "names-block-k": ((2, 8192, 64, 8, 512, dict(block_k=1024)), "_window_flash_forward"),
+    "no-window": ((2, 8192, 48, 8, None, {}), "_flash_forward"),
+}
+
+
+@pytest.mark.parametrize("case", SELECTION_CASES)
+def test_the_shapes_choose_the_forward_kernel(case):
+    """Lowered for the TPU, forward and backward (no chip needed to lower): the
+    forward kernel's name in the text, beside the kind's two backward kernels.
+    No argument chooses the band step; the shapes do."""
+    import re
+
+    (B, S, Hq, Hkv, window, tiles), forward = SELECTION_CASES[case]
+
+    def loss(q, k, v):
+        out = pallas_attention.flash_attention(q, k, v, window=window, **tiles)
+        return out.astype(jnp.float32).sum()
+
+    q = jax.ShapeDtypeStruct((B, S, Hq, 128), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((B, S, Hkv, 128), jnp.bfloat16)
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).trace(q, kv, kv).lower(
+        lowering_platforms=("tpu",)
+    ).as_text(debug_info=True)
+    backward = "_flash_backward" if window is None else "_window_flash_backward"
+    assert set(re.findall(r'kernel_name = "([^"]+)"', text)) == {
+        forward, f"{backward}_dkv", f"{backward}_dq"
+    }
