@@ -19,6 +19,7 @@ from deeplearning_cfn_tpu.obs.recorder import (
 from deeplearning_cfn_tpu.obs.tracing import (
     counter,
     counters,
+    recent_drains,
     recent_spans,
     reset_aggregates,
     span,
@@ -69,6 +70,7 @@ __all__ = [
     "span",
     "span_aggregates",
     "recent_spans",
+    "recent_drains",
     "counter",
     "counters",
     "reset_aggregates",

@@ -27,6 +27,7 @@ Python in the hot loop beyond feeding batches.
 
 from __future__ import annotations
 
+import collections
 import contextlib
 import itertools
 import time
@@ -57,7 +58,14 @@ from deeplearning_cfn_tpu.train.metrics import (
     ThroughputLogger,
     peak_flops_per_chip,
 )
-from deeplearning_cfn_tpu.obs.tracing import counter, freeze_counters, span
+from deeplearning_cfn_tpu.obs.recorder import get_recorder
+from deeplearning_cfn_tpu.obs.tracing import (
+    Drains,
+    counter,
+    freeze_counters,
+    span,
+    spans_between,
+)
 from deeplearning_cfn_tpu.utils.logging import get_logger
 
 log = get_logger("dlcfn.trainer")
@@ -324,6 +332,16 @@ class _FitSeams:
       ``should_save``, ``stop_fn``.
     - ``fit.checkpoint``: a save; journalled as ``checkpoint``.
 
+    Where a ``fit.sync`` that drained the pending losses ends, the device's
+    queue is empty: what the thread does from there to the end of the next
+    ``fit.dispatch`` the device waits out one for one.  ``drain`` and
+    ``dispatched`` stamp the two ends into a row of ``obs.tracing``'s
+    ``recent_drains()`` (``Drains``); the first step's wait is no drain.
+    A drain far slower than the loop's last ones is journalled once as a
+    ``stall`` (``_judge``), with the seam that held most of the host's
+    segment inside it and, under ``during``, whatever else the thread did
+    under a name in that time: a ``fit.checkpoint``, a ``reshard``.
+
     A ``StepProfiler``, when one was passed, gets the same seconds folded
     into its phases (``PHASES``), so its API and its readers stay as they
     were; its ``compute`` is the host's wait at a sync point, a lower
@@ -337,6 +355,20 @@ class _FitSeams:
         "fit.sync": "compute",
     }
     _END = object()
+    #: The seams of one iteration: any other span of the thread inside a slow
+    #: drain's interval is a pause with a name of its own.
+    STEP_SEAMS = frozenset(
+        ("fit.step", "fit.data_wait", "fit.h2d", "fit.dispatch", "fit.sync", "fit.log")
+    )
+    #: A drain is a stall when its seconds per step exceed the median of the
+    #: last ``STALL_WINDOW`` drains by both: the factor keeps a slow model's
+    #: jitter out, the floor a fast model's (half again of 5 ms is nothing an
+    #: operator can act on; 50 ms is a tenth of a percent of a minute).
+    STALL_FACTOR = 1.5
+    STALL_FLOOR_S = 0.05
+    STALL_WINDOW = 64
+    #: With fewer drains behind it the median is the loop's first, unsettled steps.
+    STALL_MIN_DRAINS = 8
 
     def __init__(self, trainer: "Trainer", profiler: Any):
         from deeplearning_cfn_tpu.obs.profiler import NULL_PROFILER
@@ -345,6 +377,8 @@ class _FitSeams:
         self.prof = profiler if profiler is not None else NULL_PROFILER
         self.t_fit = time.perf_counter()
         self.first_done = False
+        self.drains = Drains()
+        self._per_step: collections.deque[float] = collections.deque(maxlen=self.STALL_WINDOW)
 
     def __call__(self, name: str, samples: int = 1):
         """The context for one seam; ``samples`` spreads a sync's seconds
@@ -362,6 +396,56 @@ class _FitSeams:
                 yield
         finally:
             self.prof.fold(phase, time.perf_counter() - t0, samples=samples)
+
+    @contextlib.contextmanager
+    def drain(self, gstep: int, steps: int):
+        """``fit.sync`` around the readback of ``steps`` pending steps, the
+        last of them ``gstep``, and the drain's row where it ends."""
+        before = self.drains.last
+        with self("fit.sync", steps):  # the profiler spreads over at least one
+            yield
+        row = self.drains.returned(gstep, steps)
+        if row is not None and before is not None:
+            self._judge(row, before)
+
+    def dispatched(self) -> None:
+        """Where a ``fit.dispatch`` has ended: behind a drain, the end of its
+        exposed segment; on every other step one attribute check."""
+        if self.drains.open is not None:
+            self.drains.dispatched()
+
+    def _judge(self, row: dict[str, Any], before: dict[str, Any]) -> None:
+        """Journal the drain as a ``stall`` if it is one.  The host's segment
+        inside its interval is the one the drain ``before`` opened."""
+        per_step = row["interval_s"] / row["steps"]
+        recent = self._per_step
+        if per_step < self.STALL_FLOOR_S or len(recent) < self.STALL_MIN_DRAINS:
+            recent.append(per_step)  # under the floor it is over no median by it
+            return
+        median = sorted(recent)[len(recent) // 2]
+        recent.append(per_step)
+        if per_step <= self.STALL_FACTOR * median or per_step - median < self.STALL_FLOOR_S:
+            return
+        thread, start_ns = row["thread"], before["sync_end_ns"]
+        seam = None
+        if before["exposed_s"] is not None:
+            held = spans_between(thread, start_ns, start_ns + int(before["exposed_s"] * 1e9))
+            held.pop("host.gc", None)  # inside the seam that ran it, and in ``exposed_gc_s``
+            # the iteration's own span holds the others: what they leave is its alone
+            held["fit.step"] = before["exposed_s"] - sum(
+                v for n, v in held.items() if n != "fit.step"
+            )
+            seam = max(held, key=held.get)
+        during = sorted(
+            set(spans_between(thread, start_ns, row["sync_end_ns"])) - self.STEP_SEAMS - {"host.gc"}
+        )
+        get_recorder().record(
+            "stall",
+            **{k: row[k] for k in ("step", "steps", "sync_end_ns", "interval_s", "gc_s", "nivcsw", "majflt")},
+            median_step_s=median, excess_s=row["interval_s"] - row["steps"] * median,
+            exposed_s=before["exposed_s"], exposed_gc_s=before["exposed_gc_s"],
+            seam=seam, during=during or None,
+        )
 
     def step(self, gstep: int):
         return span("fit.step", journal="train_step", step_num=gstep)
@@ -1169,7 +1253,7 @@ class Trainer:
                         # dispatched against the old mesh, then migrate.  The
                         # batch just pulled is trained on the NEW mesh below —
                         # the data stream continues unbroken.
-                        with seams("fit.sync", len(pending)):
+                        with seams.drain(gstep, len(pending)):
                             losses.extend(float(v) for v in jax.device_get(pending))
                         pending.clear()
                         state, action = reshard.execute(self, state, step=gstep)
@@ -1191,6 +1275,7 @@ class Trainer:
                     with seams("fit.dispatch"):
                         with jax.set_mesh(self.mesh):
                             state, metrics = step_fn(state, x, y)
+                    seams.dispatched()
                     gstep += 1
                     pending.append(metrics["loss"])
                     if "counters" in metrics:
@@ -1214,7 +1299,7 @@ class Trainer:
                         # This is where device time surfaces on the host: the
                         # blocked seconds are a lower bound on compute, which the
                         # profiler spreads over the steps drained.
-                        with seams("fit.sync", len(pending)):
+                        with seams.drain(gstep, len(pending)):
                             losses.extend(float(v) for v in jax.device_get(pending))
                         pending.clear()
                         if pending_counters:
@@ -1311,6 +1396,7 @@ class Trainer:
                         # alias into), hence the explicit delete — see
                         # train/data.donate_buffers.
                         donate_buffers((xs, ys))
+                    seams.dispatched()
                     gstep += k
                     pending.append(kloss)
                     seams.first_step(kloss)
@@ -1322,7 +1408,7 @@ class Trainer:
                         with seams.checkpoint(gstep):
                             self._save_checkpoint(checkpointer, gstep, state, datastream)
                     if (i + 1) % sync_every == 0 or i == calls - 1:
-                        with seams("fit.sync", len(pending) * k):
+                        with seams.drain(gstep, len(pending) * k):
                             for vec in jax.device_get(pending):
                                 losses.extend(float(v) for v in vec)
                         pending.clear()
@@ -1352,13 +1438,14 @@ class Trainer:
                     with seams("fit.dispatch"):
                         with jax.set_mesh(self.mesh):
                             state, metrics = step_fn(state, x, y)
+                    seams.dispatched()
                     gstep += 1
                     scalar_pending.append(metrics["loss"])
                     if logger:
                         with seams("fit.log"):
                             logger.step(gstep, metrics["loss"])
                     prof.step_done(step=gstep)
-            with seams("fit.sync", max(1, len(scalar_pending))):
+            with seams.drain(gstep, len(scalar_pending)):
                 losses.extend(float(v) for v in jax.device_get(scalar_pending))
         return state, losses
 

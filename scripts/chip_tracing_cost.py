@@ -1,7 +1,11 @@
 """What the program's tracing costs: the seams' host time per step with no
-capture running (a loop over a jitted no-op), and ResNet-50's steps per second
-inside a `jax.profiler` capture with and without the spans' annotations, with
-the capture's size.  Through chiprun; the last line is the result."""
+capture running (a loop over a jitted no-op), alone and with a drain's row
+every tenth step (ResNet's `log_every`) and the collector's hook installed;
+a drain's row, its judgement by `_FitSeams` and a collection's two hook
+calls by themselves; and
+ResNet-50's steps per second inside a `jax.profiler` capture with and without
+the spans' annotations, with the capture's size and the collections a step of
+that loop makes.  Through chiprun; the last line is the result."""
 
 from __future__ import annotations
 
@@ -10,6 +14,7 @@ import json
 import shutil
 import sys
 import time
+import types
 from pathlib import Path
 
 ROOT = Path(__file__).resolve().parents[1]
@@ -20,7 +25,9 @@ def seams_per_step(steps: int = 20000) -> dict:
     import jax
     import jax.numpy as jnp
 
+    from deeplearning_cfn_tpu.obs import tracing
     from deeplearning_cfn_tpu.obs.tracing import span
+    from deeplearning_cfn_tpu.train.trainer import _FitSeams
 
     noop = jax.jit(lambda x: x)
     x = jnp.zeros((), jnp.float32)
@@ -46,10 +53,63 @@ def seams_per_step(steps: int = 20000) -> dict:
                     pass
         return (time.perf_counter() - t) / steps
 
-    rounds = [(bare(), seamed()) for _ in range(3)]
-    b, s = min(r[0] for r in rounds), min(r[1] for r in rounds)
+    def through_fit_seams(rows: bool, every: int = 10):
+        """The seamed loop with a `fit.sync` every `every` steps, through
+        `_FitSeams` itself: as the parent ran it, and with `rows` as
+        `Trainer.fit` runs it now, the check behind every dispatch and a
+        row every drain."""
+        seams = _FitSeams(types.SimpleNamespace(), None)
+        t = time.perf_counter()
+        for i in range(steps):
+            with seams("fit.data_wait"):
+                pass
+            with span("fit.step", journal=False, step_num=i):
+                with seams("fit.h2d"):
+                    pass
+                with seams("fit.dispatch"):
+                    noop(x)
+                if rows:
+                    seams.dispatched()
+                with seams("fit.log"):
+                    pass
+                if i % every == every - 1:
+                    with seams.drain(i + 1, every) if rows else seams("fit.sync", every):
+                        pass
+        return (time.perf_counter() - t) / steps
+
+    def one_drain():
+        drains = tracing.Drains()
+        t = time.perf_counter()
+        for i in range(steps):
+            drains.returned(i + 1, 1)
+            drains.dispatched()
+        return (time.perf_counter() - t) / steps
+
+    def one_judgement():
+        """`_FitSeams._judge` on a drain of a second a step: over the floor,
+        so the median of the last 64 is taken; no stall among them."""
+        seams = _FitSeams(types.SimpleNamespace(), None)
+        row = {"interval_s": 2.0, "steps": 2}
+        t = time.perf_counter()
+        for _ in range(steps):
+            seams._judge(row, row)
+        return (time.perf_counter() - t) / steps
+
+    def one_collection():
+        t = time.perf_counter()
+        for _ in range(steps):
+            tracing._on_gc("start", {"generation": 0})
+            tracing._on_gc("stop", {"generation": 0})
+        return (time.perf_counter() - t) / steps
+
+    rounds = [(bare(), seamed(), through_fit_seams(False), through_fit_seams(True), one_drain(), one_collection(),
+               one_judgement()) for _ in range(3)]
+    b, s, y, d, row, hook, judged = (min(r[k] for r in rounds) for k in range(7))
     return {"bare_us_per_step": b * 1e6, "seamed_us_per_step": s * 1e6,
-            "five_seams_us_per_step": (s - b) * 1e6}
+            "five_seams_us_per_step": (s - b) * 1e6,
+            "drain_every_10_us_per_step": (d - y) * 1e6,
+            "us_per_drain": row * 1e6, "us_per_judgement": judged * 1e6,
+            "hook_us_per_collection": hook * 1e6}
 
 
 def capture_cost(steps: int = 60) -> dict:
@@ -94,15 +154,21 @@ def capture_cost(steps: int = 60) -> dict:
             jax.profiler.start_trace(str(trace_dir), profiler_options=options)
         # Ten steps for the capture's start to pass, then the timed ones.
         state, _ = trainer.fit(state, batches, steps=10)
+        collections = tracing.counters().get("gc.pause_s", {"count": 0, "total": 0.0})
         t = time.perf_counter()
         state, losses = trainer.fit(state, batches, steps=steps)
         seconds = time.perf_counter() - t
+        after = tracing.counters().get("gc.pause_s", collections)
         size = None
         if level is not None:
             jax.profiler.stop_trace()
             size = max(p.stat().st_size for p in trace_dir.rglob("*.xplane.pb"))
             shutil.rmtree(trace_dir, ignore_errors=True)
-        out[arm] = {"steps_per_s": len(losses) / seconds, "xplane_bytes": size}
+        out[arm] = {
+            "steps_per_s": len(losses) / seconds, "xplane_bytes": size,
+            "collections_per_step": (after["count"] - collections["count"]) / len(losses),
+            "gc_ms_per_step": 1e3 * (after["total"] - collections["total"]) / len(losses),
+        }
     tracing._annotation = annotate
     return out
 

@@ -1,7 +1,9 @@
 """One timeline: `obs.tracing.span` on the profiler's clock, the seams of
-`Trainer.fit`, the named scopes on the step's operations and the compile
+`Trainer.fit`, a row per drain of it with the collector's pauses and a
+`stall` event, the named scopes on the step's operations and the compile
 counters that `enable_compile_cache` feeds."""
 
+import gc
 import subprocess
 import sys
 import threading
@@ -217,6 +219,183 @@ def test_a_step_profiler_still_receives_its_phases(steps_per_call):
     # compute is amortised over the steps a sync drained
     assert snap["phases"]["compute"]["count"] >= 6
     assert trainer.first_step_seconds > 0 and trainer.first_step_at > 0
+
+
+# --- a row per drain, the collector's pauses, the stall event -----------------
+
+
+@pytest.mark.parametrize("steps_per_call, steps", [(1, 5), (2, 5)])
+def test_every_drain_of_the_loop_leaves_a_row_as_the_clock_dictates(monkeypatch, steps_per_call, steps):
+    trainer, state, batch = _lenet_trainer()
+    clock = {"t": 100.0}
+    hooks = _Hooks(clock, batch, save_at={3})
+    monkeypatch.setattr(tracing, "get_recorder", lambda: FlightRecorder())
+    monkeypatch.setattr(tracing, "_perf_counter", lambda: clock["t"])
+    monkeypatch.setattr(tracing, "_time_ns", lambda: int(round(clock["t"] * 1e9)))
+    trainer.fit(
+        state, hooks.batches(steps), steps=steps, logger=hooks, checkpointer=hooks,
+        stop_fn=hooks.stop, prefetch=0, steps_per_call=steps_per_call,
+    )
+    spans = tracing.recent_spans()
+    syncs = [r[2] + r[3] for r in spans if r[1] == "fit.sync"]
+    dispatches = [r[2] + r[3] for r in spans if r[1] == "fit.dispatch"]
+    rows = tracing.recent_drains()
+    # One row for every wait that drained, none for the first step's.
+    assert [r["sync_end_ns"] for r in rows] == syncs[1:]
+    assert sum(r["steps"] for r in rows) == steps and rows[-1]["step"] == steps
+    assert [r["step"] for r in rows] == list(np.cumsum([r["steps"] for r in rows]))
+    assert {r["thread"] for r in rows} == {threading.get_ident()}
+    for before, row in zip([None] + rows, rows):
+        # the bridge: one return on both clocks
+        assert row["sync_end_ns"] == int(round(row["sync_end_s"] * 1e9))
+        if before is None:
+            assert row["interval_s"] is None and row["gc_s"] is None and row["nivcsw"] is None
+        else:
+            assert row["interval_s"] == pytest.approx(row["sync_end_s"] - before["sync_end_s"], abs=1e-9)
+            assert row["interval_s"] > 0 and row["gc_s"] >= 0
+            assert row["nivcsw"] >= 0 and row["majflt"] >= 0  # Linux keeps them by thread
+        following = [d for d in dispatches if d > row["sync_end_ns"]]
+        if following:
+            # from the return to the end of the next dispatch: stop_fn's 3 ms,
+            # then whatever the source took, and nothing on the device
+            assert row["exposed_s"] == pytest.approx((following[0] - row["sync_end_ns"]) / 1e9, abs=1e-9)
+            assert row["exposed_s"] >= 0.004 - 1e-9
+            assert row["exposed_gc_s"] >= 0
+        else:
+            assert row["exposed_s"] is None and row["exposed_gc_s"] is None
+    assert rows[-1]["exposed_s"] is None and rows[0]["exposed_s"] is not None
+
+
+def test_the_collectors_hook_folds_every_pause_and_keeps_the_long_ones_as_spans(monkeypatch):
+    clock = {"t": 50.0}
+    monkeypatch.setattr(tracing, "_perf_counter", lambda: clock["t"])
+    monkeypatch.setattr(tracing, "_time_ns", lambda: int(round(clock["t"] * 1e9)))
+    gc.disable()  # no collection of the interpreter's own between the calls below
+    try:
+        for generation, seconds in ((0, 0.0004), (2, 0.003), (1, 0.0009), (2, 0.25)):
+            tracing._on_gc("start", {"generation": generation})
+            clock["t"] += seconds
+            tracing._on_gc("stop", {"generation": generation, "collected": 0})
+            clock["t"] += 1.0
+        tracing._on_gc("stop", {"generation": 2})  # a stop with no start: installed mid-collection
+    finally:
+        gc.enable()
+    got = tracing.counters()
+    assert got["gc.pause_s"] == {"count": 4, "total": pytest.approx(0.2543)}
+    me = threading.get_ident()
+    assert tracing.recent_spans() == [
+        [me, "host.gc", 51_000_400_000, 3_000_000],
+        [me, "host.gc", 53_004_300_000, 250_000_000],
+    ]
+    tracing.reset_aggregates()
+    assert "gc.pause_s" not in tracing.counters()
+
+
+def test_the_hook_is_installed_once_however_many_loops_are_built():
+    from deeplearning_cfn_tpu.train.trainer import _FitSeams
+
+    trainer, _, _ = _lenet_trainer()
+    for _ in range(3):
+        _FitSeams(trainer, None)
+        tracing.Drains()
+    assert gc.callbacks.count(tracing._on_gc) == 1
+    before = tracing.counters().get("gc.pause_s", {"count": 0})["count"]
+    gc.collect()
+    after = tracing.counters()["gc.pause_s"]
+    assert after["count"] >= before + 1 and after["total"] > 0
+
+
+def test_obs_keeps_drains_without_jax_and_hooks_the_collector_only_when_a_loop_is_built():
+    code = (
+        "import gc, sys\n"
+        "import deeplearning_cfn_tpu.obs as obs\n"
+        "from deeplearning_cfn_tpu.obs import tracing\n"
+        "assert tracing._on_gc not in gc.callbacks\n"
+        "drains = tracing.Drains()\n"
+        "assert gc.callbacks.count(tracing._on_gc) == 1\n"
+        "drains.returned(2, 2); drains.dispatched(); drains.returned(4, 2)\n"
+        "first, second = obs.recent_drains()\n"
+        "assert first['exposed_s'] >= 0 and second['interval_s'] > 0 and second['exposed_s'] is None\n"
+        "assert 'jax' not in sys.modules\n"
+    )
+    subprocess.run([sys.executable, "-c", code], check=True, timeout=120)
+
+
+@pytest.mark.parametrize(
+    "usual_s, slow_s, saves, fires",
+    [
+        (0.5, 1.3, False, True),  # 2.6 times the median and 0.4 s a step over it
+        (0.5, 1.3, True, True),  # the same with a save in it: a stall, and it says during what
+        (0.01, 0.05, False, False),  # five times the median, 40 ms over it: under the floor
+        (0.5, 0.6, False, False),  # 100 ms over it, a fifth: under the factor
+    ],
+)
+def test_a_stall_is_journalled_once_for_a_drain_over_both_thresholds(monkeypatch, usual_s, slow_s, saves, fires):
+    import types
+
+    from deeplearning_cfn_tpu.train import trainer as trainer_module
+
+    clock = {"t": 10.0}
+    monkeypatch.setattr(tracing, "_perf_counter", lambda: clock["t"])
+    monkeypatch.setattr(tracing, "_time_ns", lambda: int(round(clock["t"] * 1e9)))
+    rec = FlightRecorder()
+    monkeypatch.setattr(tracing, "get_recorder", lambda: rec)
+    monkeypatch.setattr(trainer_module, "get_recorder", lambda: rec)
+    seams = trainer_module._FitSeams(types.SimpleNamespace(), None)
+    step = 0
+    for n in range(24):
+        slow = n == 16
+        # the host's segment: 1 ms of fit.log, or the whole surplus before the slow drain
+        with seams("fit.log"):
+            clock["t"] += 0.001 + (2 * (slow_s - usual_s) if slow else 0.0)
+        with seams("fit.dispatch"):
+            clock["t"] += 0.0005
+        seams.dispatched()
+        if slow and saves:
+            with seams.checkpoint(step):
+                pass
+        clock["t"] += 2 * usual_s - 0.0015
+        step += 2
+        with seams.drain(step, 2):
+            pass
+    events = [e for e in rec.tail() if e["kind"] == "stall"]
+    assert len(events) == (1 if fires else 0)
+    if fires:
+        (event,) = events
+        assert event["step"] == 34 and event["steps"] == 2
+        assert event["interval_s"] == pytest.approx(2 * slow_s)
+        assert event["median_step_s"] == pytest.approx(usual_s)
+        assert event["excess_s"] == pytest.approx(2 * (slow_s - usual_s))
+        # the segment inside the slow interval is the one the drain before opened
+        assert event["exposed_s"] == pytest.approx(0.0015 + 2 * (slow_s - usual_s))
+        assert event["seam"] == "fit.log"
+        assert event["during"] == (["fit.checkpoint"] if saves else None)
+        assert {"gc_s", "nivcsw", "majflt", "exposed_gc_s", "sync_end_ns"} <= set(event)
+
+
+def test_a_row_counts_no_negative_pause_when_the_totals_were_reset_under_a_live_loop():
+    drains = tracing.Drains()
+    tracing._gc_totals[:] = [3, 0.5]
+    drains.returned(1, 1)
+    tracing.reset_aggregates()  # zeroes the collector's totals; the loop keeps its snapshot
+    drains.dispatched()
+    row = drains.returned(2, 1)
+    assert row["gc_s"] == 0.0 and drains.last is row
+    assert tracing.recent_drains()[0]["gc_s"] == 0.0
+
+
+def test_recent_drains_are_bounded_and_cleared_with_the_aggregates():
+    drains = tracing.Drains()
+    for n in range(tracing.RECENT_DRAINS + 10):
+        drains.returned(n + 1, 1)
+    rows = tracing.recent_drains()
+    assert len(rows) == tracing.RECENT_DRAINS and rows[-1]["step"] == tracing.RECENT_DRAINS + 10
+    assert drains.returned(0, 0) is None  # a wait that drained nothing is no drain
+    assert len(tracing.recent_drains()) == tracing.RECENT_DRAINS
+    rows[0]["step"] = -1  # a copy: the program's rows are its own
+    assert tracing.recent_drains()[0]["step"] != -1
+    tracing.reset_aggregates()
+    assert tracing.recent_drains() == []
 
 
 # --- the compile counters ---------------------------------------------------
