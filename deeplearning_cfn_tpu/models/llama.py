@@ -54,7 +54,9 @@ class LlamaConfig:
     norm_eps: float = 1e-5
     dtype: Any = jnp.bfloat16
     remat: bool = True
-    # "full": recompute the whole block in backward (lowest memory).
+    # "full": recompute the whole block in backward but the flash kernel's
+    # `out` and `lse` (remat_keeps): the lowest memory there is, which is
+    # layers x [B, S, H, D] in the compute dtype above the residual stream.
     # "dots": save matmul outputs, recompute only elementwise
     # (jax.checkpoint_policies.dots_with_no_batch_dims_saveable) — the
     # standard transformer policy; measured +3% step throughput on the
@@ -396,6 +398,26 @@ def attend(
     return dot_product_attention(q, k, v, causal=True, window=window)
 
 
+def remat_keeps(also: Callable[..., Any] | None = None) -> Callable[..., Any]:
+    """The `jax.checkpoint` policy of every rematerialised decoder block (this
+    module's, and through `mla_moe._checkpointed` the three expert decoders'):
+    a full-causal flash call's `out` and `lse` are kept, layers x [B, S, H, D]
+    in the compute dtype and a float32 a row, so that the quadratic forward
+    kernel runs once a step and not again for the backward pass; everything
+    else is recomputed, or saved where ``also`` (another policy) says.  The
+    policy finds the pair by its names: a block without such a call (ring or
+    XLA attention, a head and its loss) keeps nothing.  A windowed call's pair
+    is named too and NOT kept: its band step is 2.93 ms a call where the
+    full-causal kernel is 15.36 (the Laguna cell), for more bytes of `out`
+    (64 heads against 48)."""
+    from deeplearning_cfn_tpu.ops.pallas_attention import FLASH_RESIDUALS
+
+    keeps = jax.checkpoint_policies.save_only_these_names(*FLASH_RESIDUALS)
+    if also is None:
+        return keeps
+    return jax.checkpoint_policies.save_from_both_policies(keeps, also)
+
+
 def swiglu(h: jax.Array, w_gate: jax.Array, w_up: jax.Array, w_down: jax.Array) -> jax.Array:
     """The gated feed-forward: silu in float32, the products in h's type."""
     gate = jax.nn.silu((h @ w_gate).astype(jnp.float32)).astype(h.dtype)
@@ -501,11 +523,8 @@ def forward_with_aux(
 
     block = partial(decoder_block, cfg, own_batch)
     if cfg.remat:
-        policy = (
-            jax.checkpoint_policies.dots_with_no_batch_dims_saveable
-            if cfg.remat_policy == "dots"
-            else None
-        )
+        dots = jax.checkpoint_policies.dots_with_no_batch_dims_saveable
+        policy = remat_keeps(dots if cfg.remat_policy == "dots" else None)
         block = jax.checkpoint(block, static_argnums=(), policy=policy)
 
     def scan_body(carry, lp):

@@ -81,6 +81,28 @@ def _adafactor_state_bytes(shapes: Any) -> int:
     return total
 
 
+def kept_pair_bytes(cfg: LlamaConfig, b_local: int, seq_len: int, tp: int = 1, sp: int = 1) -> int:
+    """Per-chip bytes of what `llama.remat_keeps` holds from the forward to the
+    backward pass: every layer's flash `out` [B, S, H, D] in the compute dtype
+    and its `lse` [B, H, S] in float32, heads over tp.  Nothing where the
+    block does not run the flash kernel (no remat, below the crossover, ring
+    attention, a config that does not ask for it).  Priced once, which is
+    what the chip holds: in the benchmark's Mistral cell (5 layers, 2 x 4096
+    tokens, 32 heads of 128) the allocator's `bytes_reserved` rose by these
+    340,787,200 bytes to the byte (PERF.md section 6, PR 39), as the compiled
+    step's `memory_analysis().peak_memory_in_bytes` does
+    (tests/test_kernels_compile_for_tpu.py).  `temp_size_in_bytes` of the same
+    analysis rises by twice that; it is a sum of libtpu's and not what the
+    runtime reserves, though the benchmark's `device.memory_peak_bytes` is
+    built on it."""
+    ring = cfg.use_ring_attention and sp > 1  # the flash kernel shards no sequence
+    if not cfg.remat or ring or llama.attention_kind(cfg, None, seq_len, backend="tpu") != "flash":
+        return 0
+    itemsize = np.dtype(cfg.dtype).itemsize
+    heads = cfg.n_heads // tp
+    return cfg.n_layers * b_local * seq_len * heads * (cfg.head_dim * itemsize + 4)
+
+
 @dataclass
 class MemoryReport:
     cfg_name: str
@@ -120,8 +142,10 @@ def memory_report(
 
     Activation model (remat per layer, the forward_with_aux structure):
     the checkpointed residual stream ([B, S, D] bf16 per layer) persists
-    through the backward, plus one block's live intermediates (q/k/v/attn
-    out + the SwiGLU gate/up pair) and the [B, S, V] f32 logits+grad pair.
+    through the backward, and beside it the flash kernel's `out` and `lse`
+    of every layer (`kept_pair_bytes`), plus one block's live intermediates
+    (q/k/v/attn out + the SwiGLU gate/up pair) and the [B, S, V] f32
+    logits+grad pair.
     Batch shards over dp*fsdp, sequence over sp, heads/mlp/vocab over tp.
 
     ``grad_accum`` models TrainerConfig.grad_accum_steps: activations
@@ -169,6 +193,7 @@ def memory_report(
     act_b += b_local * s_local * (
         4 * cfg.dim + 2 * kv_dim + 2 * (cfg.mlp_dim // tp)
     ) * bf16
+    act_b += kept_pair_bytes(cfg, b_local, seq_len, tp, seq_shards)
     # Logits + their cotangent, COMPUTE dtype (the round-3 change: logits
     # stay bf16 end to end — loss reductions convert internally; the f32
     # [B, S, V] materialization this line used to model is gone), vocab

@@ -45,6 +45,7 @@ from deeplearning_cfn_tpu.models.llama import (
     _FunctionalInit,
     attend,
     attention_kind,
+    remat_keeps,
     swiglu,
 )
 from deeplearning_cfn_tpu.ops.attention import rms_norm, rotary_embedding
@@ -335,7 +336,7 @@ def _block(
 
 
 def _checkpointed(cfg: MlaMoeConfig, fn):
-    return jax.checkpoint(fn) if cfg.remat else fn
+    return jax.checkpoint(fn, policy=remat_keeps()) if cfg.remat else fn
 
 
 def _scan_blocks(cfg, mesh, x, stack, positions):

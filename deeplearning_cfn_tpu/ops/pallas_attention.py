@@ -72,6 +72,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh, PartitionSpec as P
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
@@ -952,8 +953,22 @@ def _forward(q, k, v, causal, sm_scale, block_q, block_k, interpret, window, nee
     )
 
 
+# What `jax.ad_checkpoint.checkpoint_name` calls a differentiated call's `out`
+# and its compact `lse` [B, Hq, Sq]: a full-causal call's pair, and a windowed
+# call's.  Outside a `jax.checkpoint` whose policy saves a name it is the
+# identity and lowers to nothing; inside one that does, the kernel that made
+# the pair is not run again for the backward pass.  Which names are worth
+# their bytes is the caller's judgement (models/llama.remat_keeps).
+FLASH_RESIDUALS = ("flash_out", "flash_lse")
+WINDOW_FLASH_RESIDUALS = ("window_flash_out", "window_flash_lse")
+
+
 def _core_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret, window):
     out, lse = _forward(q, k, v, causal, sm_scale, block_q, block_k, interpret, window, need_lse=True)
+    # Named before they part into the result and the residuals: a name on the
+    # result alone would leave the residual another variable, recomputed.
+    out_name, lse_name = FLASH_RESIDUALS if window is None else WINDOW_FLASH_RESIDUALS
+    out, lse = checkpoint_name(out, out_name), checkpoint_name(lse, lse_name)
     return out, (q, k, v, out, lse)
 
 

@@ -128,3 +128,56 @@ def test_routed_experts_layer_compiles_for_v5e_without_a_scatter(one_chip):
     # where a result written tile by tile into zeros is a buffer more each
     # (1.59 GB with every pass over the whole buffer, before PR 32).
     assert compiled.memory_analysis().temp_size_in_bytes < 1.45e9
+
+
+def _mistral_cell_step(one_chip):
+    """The step of `mistral-7b-v0.3.train-s4096` as the benchmark's builder
+    makes it (5 layers at the published widths, adamw, fsdp over one chip, 2 x
+    4096 tokens), compiled from shapes alone."""
+    from functools import partial
+
+    import numpy as np
+
+    from deeplearning_cfn_tpu.models import llama
+    from deeplearning_cfn_tpu.parallel.mesh import MeshSpec, build_mesh
+    from deeplearning_cfn_tpu.train.trainer import TrainerConfig
+
+    cfg = llama.LlamaConfig(
+        vocab_size=32768, dim=4096, n_layers=5, n_heads=32, n_kv_heads=8, mlp_dim=14336,
+        max_seq_len=32768, rope_theta=1e6, use_flash_attention=True,
+    )
+    mesh = build_mesh(MeshSpec.fsdp_parallel(1), list(one_chip.device_set))
+    trainer = llama.make_trainer(cfg, mesh, TrainerConfig(
+        strategy="fsdp", optimizer="adamw", learning_rate=3e-4, weight_decay=0.1,
+        grad_clip_norm=1.0,
+    ))
+    tokens = jax.ShapeDtypeStruct((2, 4096), np.int32, sharding=trainer.batch_sharding)
+    state = jax.eval_shape(
+        partial(trainer.init, jax.random.key(0)), jax.ShapeDtypeStruct((1, 4096), np.int32)
+    )
+    with jax.set_mesh(mesh):
+        return cfg, trainer.step_fn.lower(state, tokens, tokens).compile()
+
+
+def test_the_kept_pair_costs_the_mistral_cells_step_its_shapes_once(one_chip, monkeypatch):
+    """`llama.remat_keeps` in the whole compiled step, beside the same step
+    under the parent's policy (nothing kept): the forward kernel is in the
+    program once and not twice, XLA's own peak rises by the pair's shapes to
+    the byte (`llama_memory.kept_pair_bytes`), and `temp_size_in_bytes`, which
+    the benchmark's `device.memory_peak_bytes` reads, by twice that."""
+    import re
+
+    from deeplearning_cfn_tpu.models import llama, llama_memory
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg, kept = _mistral_cell_step(one_chip)
+    monkeypatch.setattr(llama, "remat_keeps", lambda also=None: also)
+    _, recomputed = _mistral_cell_step(one_chip)
+
+    calls = lambda compiled: len(set(re.findall(r"%(_flash_forward[.\d]*) = ", compiled.as_text())))
+    assert (calls(kept), calls(recomputed)) == (1, 2)
+    pair = llama_memory.kept_pair_bytes(cfg, 2, 4096)
+    assert pair == 5 * (2 * 4096 * 32 * 128 * 2 + 2 * 32 * 4096 * 4) == 340_787_200
+    after, before = kept.memory_analysis(), recomputed.memory_analysis()
+    assert after.peak_memory_in_bytes - before.peak_memory_in_bytes == pair
+    assert after.temp_size_in_bytes - before.temp_size_in_bytes == 2 * pair

@@ -176,3 +176,32 @@ def test_grad_accum_memory_terms_match_chip_observations():
         memory_report(b1, mesh, 10, grad_accum=3)
     with pytest.raises(ValueError, match="must be >= 1"):
         memory_report(b1, mesh, 10, grad_accum=0)
+
+
+def test_the_kept_pair_is_priced_where_the_block_runs_the_flash_kernel():
+    """`llama.remat_keeps` holds every layer's flash `out` and `lse` from the
+    forward to the backward pass: the report's activations carry that term at
+    and above the crossover, and nothing below it, without remat, under ring
+    attention, or for a config that does not ask for the kernel (the 8B
+    preset: docs/MEMORY_8B.md's table is unchanged)."""
+    import dataclasses
+
+    from deeplearning_cfn_tpu.ops.pallas_attention import FLASH_CROSSOVER_SEQ
+
+    cfg = LlamaConfig.b1(seq_len=4096)
+    pair = llama_memory.kept_pair_bytes(cfg, 4, 2048)
+    # 20 layers x 4 x 2048 x 16 heads x (128 bf16 + one float32)
+    assert pair == 20 * 4 * 2048 * 16 * (128 * 2 + 4) == 681_574_400
+    assert llama_memory.kept_pair_bytes(cfg, 4, 2048, tp=2) == pair // 2
+    assert llama_memory.kept_pair_bytes(cfg, 4, FLASH_CROSSOVER_SEQ - 1) == 0
+    assert llama_memory.kept_pair_bytes(dataclasses.replace(cfg, remat=False), 4, 2048) == 0
+    ring = dataclasses.replace(cfg, use_ring_attention=True)
+    assert llama_memory.kept_pair_bytes(ring, 4, 2048, sp=2) == 0
+    assert llama_memory.kept_pair_bytes(ring, 4, 2048, sp=1) == pair
+    assert llama_memory.kept_pair_bytes(LlamaConfig.llama3_8b(), 1, 8192) == 0
+
+    def activations(c):
+        return llama_memory.memory_report(c, {"fsdp": 1}, batch_global=4, seq_len=2048).activations_gib
+
+    without = activations(dataclasses.replace(cfg, use_flash_attention=False))
+    assert activations(cfg) - without == pytest.approx(pair / 1024**3)

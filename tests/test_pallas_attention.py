@@ -17,6 +17,11 @@ import pytest
 
 from deeplearning_cfn_tpu.ops.attention import dot_product_attention
 from deeplearning_cfn_tpu.ops import pallas_attention
+from tests.kernel_text import (
+    equations as _equations,
+    kernels_without_locations as _kernels_without_locations,
+    named as _named,
+)
 
 # The kernel compiles through Mosaic unless told otherwise; on the CPU mesh
 # every call here asks for the interpreter.
@@ -116,18 +121,6 @@ def test_forward_output_and_lse_match(case):
     np.testing.assert_allclose(
         np.asarray(lse), np.asarray(_reference_lse(q, k, kw["causal"])), atol=2e-5, rtol=2e-5
     )
-
-
-def _equations(jaxpr):
-    """Every equation of a jaxpr and of the jaxprs its equations hold."""
-    for eqn in jaxpr.eqns:
-        yield eqn
-        for inner in jax.core.jaxprs_in_params(eqn.params):
-            yield from _equations(inner)
-
-
-def _named(jaxpr, primitive):
-    return [e for e in _equations(jaxpr) if e.primitive.name == primitive]
 
 
 def _forward_kernel_call(s=64, block_q=16, block_k=16, hq=4, hkv=2):
@@ -521,36 +514,6 @@ def test_a_window_that_holds_every_key_is_bit_equal_to_no_window(backward_blocks
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
-def _kernels_without_locations(window):
-    """name -> sha256 of the Mosaic kernel's MLIR printed without source
-    locations, lowered for the TPU at a small shape (no chip needed)."""
-    import base64
-    import hashlib
-    import re
-
-    from jax._src import tpu_custom_call  # noqa: F401  (registers the TPU dialect)
-    from jax._src.lib.mlir import ir
-
-    def loss(q, k, v):
-        return pallas_attention.flash_attention(q, k, v, window=window).astype(jnp.float32).sum()
-
-    q = jax.ShapeDtypeStruct((1, 256, 4, 128), jnp.bfloat16)
-    kv = jax.ShapeDtypeStruct((1, 256, 2, 128), jnp.bfloat16)
-    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).trace(q, kv, kv).lower(
-        lowering_platforms=("tpu",)
-    ).as_text()
-    out = {}
-    for body, name in re.findall(
-        r'\\22body\\22: \\22([A-Za-z0-9+/=]+)\\22.*?kernel_name = "([^"]+)"', text
-    ):
-        context = ir.Context()
-        context.allow_unregistered_dialects = True
-        with context:
-            asm = ir.Module.parse(base64.b64decode(body)).operation.get_asm(enable_debug_info=False)
-        out[name] = hashlib.sha256(asm.encode()).hexdigest()
-    return out
-
-
 # The three full-causal kernels as the parent of PR 33 lowered them (commit
 # da80100), by `_kernels_without_locations(None)` in that tree.  A PR that
 # changes those kernels on purpose reads the new hashes the same way.
@@ -602,6 +565,25 @@ def test_outside_the_band_steps_shapes_a_windowed_call_is_the_one_before_it():
     to the parent's three Mosaic modules, operation for operation: the tiled
     forward's grid and body are unchanged, and the backward pair is untouched."""
     assert _kernels_without_locations(64) == WINDOWED_KERNELS_BEFORE_THE_BAND_STEP
+
+
+# The band step and the backward pair beside it at a window of 128 as the
+# parent of PR 39 lowered them (commit 6f99621), by
+# `_kernels_without_locations(128)` in that tree: with the two tables above,
+# every kernel of this module.
+KERNELS_AT_THE_BAND_STEP = {
+    "_window_flash_forward_band": "cf04d82adf930ed940531396153661f33b4d602c2d9c3bfd6bd30415fa1acbef",
+    "_window_flash_backward_dkv": "c275b17873893cdd5cb902cd1927a83e38afeab195e3e1019eb1ecd6257490e9",
+    "_window_flash_backward_dq": "b9aa81dd95dfc534a061ac9d459afbbcbd9c7c8f0359cec30cc20b3916c08d9e",
+}
+
+
+def test_naming_out_and_lse_changed_no_kernel():
+    """PR 39 put `checkpoint_name` on the forward's results in `_core_fwd`,
+    outside every kernel: the band step's call lowers to the parent's modules
+    (the full-causal and the tiled windowed calls are held by the two tests
+    above, which PR 39 left as they were)."""
+    assert _kernels_without_locations(128) == KERNELS_AT_THE_BAND_STEP
 
 
 @pytest.mark.parametrize("seq,block_q,block_k,window,steps", [
