@@ -217,6 +217,9 @@ class RoutedConfig:
     scale: float = 1.0
     # A shared expert every token passes through, of this width; 0: none.
     shared_dim: int = 0
+    # An expert's form, as its model publishes it: ``swiglu`` is
+    # ``w_down (silu(w_gate x) * w_up x)``, ``relu2`` is ``w_down relu(w_up x)^2``.
+    expert: str = "swiglu"
 
     def __post_init__(self):
         first, count = self.span
@@ -226,6 +229,8 @@ class RoutedConfig:
             raise ValueError(f"held={self.held} is not a span of {self.n_routed} experts")
         if self.score not in ("sigmoid", "softmax"):
             raise ValueError(f"unknown score function {self.score!r}")
+        if self.expert not in ("swiglu", "relu2"):
+            raise ValueError(f"unknown expert form {self.expert!r}")
 
     @property
     def span(self) -> tuple[int, int]:
@@ -239,23 +244,29 @@ class RoutedConfig:
 
 
 def init_routed_params(
-    cfg: RoutedConfig, rng: jax.Array, dim: int, expert_dim: int, dtype: Any = jnp.bfloat16
+    cfg: RoutedConfig, rng: jax.Array, dim: int, expert_dim: int, dtype: Any = jnp.bfloat16,
+    rows_dim: int | None = None,
 ) -> dict:
-    """The held experts' SwiGLU weights stacked on a leading axis, the
-    router over all ``n_routed`` (float32: selection is precision-sensitive),
-    and the shared expert."""
+    """The held experts' weights stacked on a leading axis (``w_gate`` only
+    where the form has one), the router over all ``n_routed`` (float32:
+    selection is precision-sensitive), and the shared expert.  ``rows_dim`` is
+    the width of the rows the experts read and write where that is not the
+    router's ``dim`` (latent experts)."""
     keys = jax.random.split(rng, 8)
     count = cfg.span[1]
+    rows_dim = rows_dim or dim
 
     def dense(key, shape, fan_in):
         return (jax.random.normal(key, shape, jnp.float32) / math.sqrt(fan_in)).astype(dtype)
 
     params = {
         "router": jax.random.normal(keys[0], (dim, cfg.n_routed), jnp.float32) / math.sqrt(dim),
-        "w_gate": dense(keys[1], (count, dim, expert_dim), dim),
-        "w_up": dense(keys[2], (count, dim, expert_dim), dim),
-        "w_down": dense(keys[3], (count, expert_dim, dim), expert_dim),
+        "w_gate": dense(keys[1], (count, rows_dim, expert_dim), rows_dim),
+        "w_up": dense(keys[2], (count, rows_dim, expert_dim), rows_dim),
+        "w_down": dense(keys[3], (count, expert_dim, rows_dim), expert_dim),
     }
+    if cfg.expert == "relu2":
+        del params["w_gate"]
     if cfg.selection_bias:
         params["router_bias"] = jnp.zeros((cfg.n_routed,), jnp.float32)
     if cfg.shared_dim:
@@ -274,6 +285,8 @@ def routed_param_specs(cfg: RoutedConfig) -> dict:
         "w_up": P(None, "fsdp", "tp"),
         "w_down": P(None, "tp", "fsdp"),
     }
+    if cfg.expert == "relu2":
+        del specs["w_gate"]
     if cfg.selection_bias:
         specs["router_bias"] = P(None)
     if cfg.shared_dim:
@@ -426,6 +439,46 @@ _swiglu_rows.defvjp(
 )
 
 
+@jax.custom_vjp
+def _rows_out_once(x, token, slot, held):
+    """`_rows_out` for an expert with one matmul on its rows: the buffer
+    once, and one cotangent, which `_rows_back` reads before ``held`` only."""
+    return x[token]
+
+
+_rows_out_once.defvjp(
+    lambda x, token, slot, held: (x[token], (slot, held)),
+    lambda res, g: (_rows_back(g, *res), None, None, None),
+)
+
+
+def _relu2_tile(up):
+    return (jnp.square(jnp.maximum(up.astype(jnp.float32), 0)).astype(up.dtype),)
+
+
+@jax.custom_vjp
+def _relu2_rows(up, held):
+    """relu(up)^2, squared in float32, of the rows before ``held`` of one
+    grouped matmul's result; zeros from it on.  What `_swiglu_rows` is to a
+    gated expert: one masked pass forward, the live tiles backward."""
+    valid = (jnp.arange(up.shape[0]) < held)[:, None]
+    return jnp.where(valid, _relu2_tile(up)[0], 0)
+
+
+def _relu2_rows_bwd(res, g):
+    up, held = res
+
+    def tile(g, up):
+        return jax.vjp(_relu2_tile, up)[1]((g,))
+
+    # Over the cotangent, which nothing reads after this.
+    (d_up,) = _over_live_rows(tile, held, (g, up), over=(0,))
+    return d_up, None
+
+
+_relu2_rows.defvjp(lambda up, held: (_relu2_rows(up, held), (up, held)), _relu2_rows_bwd)
+
+
 def _weigh_tile(out, weight):
     return (out * weight[:, None],)
 
@@ -496,13 +549,15 @@ def grouped_matmul(
 
 
 def routed_experts(
-    cfg: RoutedConfig, params: dict, x: jax.Array, *, kind: str | None = None,
-    interpret: bool = False,
+    cfg: RoutedConfig, params: dict, x: jax.Array, *, expert_rows: jax.Array | None = None,
+    kind: str | None = None, interpret: bool = False,
 ) -> tuple[jax.Array, dict]:
     """[B, S, d] -> ([B, S, d], statistics of the routing).
 
     The result is the held experts' part of sum_i w_i E_i(x), plus the shared
-    expert.  The statistics are scalars for the step's counters (assignments
+    expert.  The router reads ``x``; the experts read ``expert_rows``
+    [B, S, l] where a model gives them rows of their own (a latent of the
+    hidden state) and the result is then [B, S, l], else ``x``.  The statistics are scalars for the step's counters (assignments
     in all and to held experts, the largest held expert's load, `dropped`:
     assignments to held experts less rows computed, which is 0, and
     `rows_run`: the buffer's rows that the backward pass's passes beside the
@@ -514,6 +569,9 @@ def routed_experts(
     first, count = cfg.span
     kind = kind or grouped_matmul_kind()
     xt = x.reshape(T, d)
+    rows_in = xt if expert_rows is None else expert_rows.reshape(T, expert_rows.shape[-1])
+    if expert_rows is not None and cfg.shared_dim:
+        raise ValueError("a shared expert beside experts with rows of their own is the model's")
     with jax.named_scope("router"):
         experts, weights = route(cfg, params, xt)
     with jax.named_scope("dispatch"):
@@ -537,11 +595,17 @@ def routed_experts(
             local[:, None] == jnp.arange(count, dtype=local.dtype)[None, :], axis=0, dtype=jnp.int32
         )
         held = jnp.sum(group_sizes)  # the rows that hold an assignment: the front of the buffer
-        rows_gate, rows_up = _rows_out(xt, token, slot, held)
+        if cfg.expert == "swiglu":
+            rows_gate, rows_up = _rows_out(rows_in, token, slot, held)
+        else:
+            rows_up = _rows_out_once(rows_in, token, slot, held)
     with jax.named_scope("experts"):
         mm = partial(grouped_matmul, group_sizes=group_sizes, kind=kind, interpret=interpret)
-        gate_up = _swiglu_rows(mm(rows_gate, params["w_gate"]), mm(rows_up, params["w_up"]), held)
-        out = mm(gate_up, params["w_down"])
+        if cfg.expert == "swiglu":
+            wide = _swiglu_rows(mm(rows_gate, params["w_gate"]), mm(rows_up, params["w_up"]), held)
+        else:
+            wide = _relu2_rows(mm(rows_up, params["w_up"]), held)
+        out = mm(wide, params["w_down"])
     with jax.named_scope("combine"):
         y = _weighted_rows_in(out, weights, row_weight, token, slot, held)
     if cfg.shared_dim:
@@ -557,4 +621,4 @@ def routed_experts(
         "rows_run": live_tiles * tile,
         "selected": experts,
     }
-    return y.reshape(B, S, d), stats
+    return y.reshape(B, S, rows_in.shape[-1]), stats

@@ -33,9 +33,6 @@ def kernel_calls(jaxpr) -> collections.Counter:
 def kernels_without_locations(window):
     """name -> sha256 of the Mosaic kernel's MLIR printed without source
     locations, lowered for the TPU at a small shape (no chip needed)."""
-    from jax._src import tpu_custom_call  # noqa: F401  (registers the TPU dialect)
-    from jax._src.lib.mlir import ir
-
     def loss(q, k, v):
         return pallas_attention.flash_attention(q, k, v, window=window).astype(jnp.float32).sum()
 
@@ -44,13 +41,32 @@ def kernels_without_locations(window):
     text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).trace(q, kv, kv).lower(
         lowering_platforms=("tpu",)
     ).as_text()
-    out = {}
-    for body, name in re.findall(
-        r'\\22body\\22: \\22([A-Za-z0-9+/=]+)\\22.*?kernel_name = "([^"]+)"', text
-    ):
-        context = ir.Context()
-        context.allow_unregistered_dialects = True
-        with context:
-            asm = ir.Module.parse(base64.b64decode(body)).operation.get_asm(enable_debug_info=False)
-        out[name] = hashlib.sha256(asm.encode()).hexdigest()
-    return out
+    return {
+        name: hashlib.sha256(_kernel_asm(body).encode()).hexdigest()
+        for body, name in re.findall(_KERNEL_BODY + r'.*?kernel_name = "([^"]+)"', text)
+    }
+
+
+_KERNEL_BODY = r'\\22body\\22: \\22([A-Za-z0-9+/=]+)\\22'
+
+
+def _kernel_asm(body: str) -> str:
+    """A serialized Mosaic kernel's MLIR, printed without source locations."""
+    from jax._src import tpu_custom_call  # noqa: F401  (registers the TPU dialect)
+    from jax._src.lib.mlir import ir
+
+    context = ir.Context()
+    context.allow_unregistered_dialects = True
+    with context:
+        return ir.Module.parse(base64.b64decode(body)).operation.get_asm(enable_debug_info=False)
+
+
+def text_without_kernel_locations(text: str) -> str:
+    """A program lowered for the TPU with each Mosaic kernel's body replaced by
+    the sha256 of its MLIR without source locations: what two checkouts of the
+    same program agree on, whatever their paths and line numbers."""
+    return re.sub(
+        _KERNEL_BODY,
+        lambda m: '\\22body\\22: \\22' + hashlib.sha256(_kernel_asm(m.group(1)).encode()).hexdigest() + '\\22',
+        text,
+    )
