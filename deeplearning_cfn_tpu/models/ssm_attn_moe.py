@@ -18,8 +18,10 @@ the routing counters).  What differs:
   through a depthwise causal convolution of `conv_taps` taps with a bias and a
   SiLU, and parts into x [H, P], B and C [G, N] (a head reads its group's);
   ``dt = softplus(dt + dt_bias)``, ``A = -exp(A_log)``; the scan is
-  `ops/ssd.ssd`; ``y = RMSNorm_group(y * silu(z)) * w`` over each group's
-  channels (the gate first), then ``W_out``.
+  `ops/ssd.ssd`, or `ops/pallas_ssd.ssd` where `pallas_ssd.takes_kernel` says
+  so (a TPU, lane-aligned chunk, state and groups);
+  ``y = RMSNorm_group(y * silu(z)) * w`` over each group's channels (the gate
+  first), then ``W_out``.
 - **Attention** is causal GQA with no positional encoding and no bias.
 - **Latent experts.**  The router reads the normalised hidden state at full
   width; the experts read and write a latent of it (``latent_in`` d -> l, the
@@ -56,6 +58,7 @@ from deeplearning_cfn_tpu.models.mla_moe import (
     _head,
     _head_loss,
 )
+from deeplearning_cfn_tpu.ops import pallas_ssd
 from deeplearning_cfn_tpu.ops.attention import rms_norm
 from deeplearning_cfn_tpu.ops.moe import (
     RoutedConfig,
@@ -329,11 +332,12 @@ def _ssm_mixer(cfg: SsmAttnMoeConfig, lp: dict, n: jax.Array) -> jax.Array:
         xBC = jax.checkpoint(_conv_silu)(xBC, lp["conv_w"], lp["conv_bias"])
     with jax.named_scope("scan"):
         x, Bm, Cm = jnp.split(xBC, (cfg.ssm_inner, cfg.ssm_inner + G * N), axis=-1)
-        y = ssd(
-            x.reshape(B, S, H, cfg.ssm_head_dim),
-            jax.nn.softplus(dt.astype(jnp.float32) + lp["dt_bias"]),
-            -jnp.exp(lp["A_log"]),
-            Bm.reshape(B, S, G, N), Cm.reshape(B, S, G, N), lp["D"], cfg.chunk,
+        x, Bm, Cm = x.reshape(B, S, H, cfg.ssm_head_dim), Bm.reshape(B, S, G, N), Cm.reshape(B, S, G, N)
+        # The shapes and the backend choose: the fused kernels, or the XLA form.
+        scan = pallas_ssd.ssd if pallas_ssd.takes_kernel(x, Bm, cfg.chunk) else ssd
+        y = scan(
+            x, jax.nn.softplus(dt.astype(jnp.float32) + lp["dt_bias"]), -jnp.exp(lp["A_log"]),
+            Bm, Cm, lp["D"], cfg.chunk,
         )
     with jax.named_scope("gate_norm"):
         y = jax.checkpoint(partial(_gate_norm, groups=G, eps=cfg.norm_eps))(

@@ -23,6 +23,17 @@ mask goes *inside* the exponent (``-inf`` above the diagonal): masked after,
 the upper triangle's ``exp`` of a positive sum overflows under a strong decay
 and the product's gradient is NaN.  The four matmuls take operands in x's type
 and accumulate in float32.  Plain `jax.numpy`: JAX differentiates it.
+
+Since PR 42 this module is the fallback and the oracle.  On a TPU a call whose
+sequence is whole chunks, whose chunk and state are whole lane tiles (128) and
+whose groups' heads together are whole lane tiles too, in float32 or bfloat16,
+leaves it for fused kernels of the same arithmetic, which keep a chunk's L,
+masked product and states in VMEM, forward and backward: the module beside this
+one that `models/ssm_attn_moe._ssm_mixer` imports with it, whose `takes_kernel`
+is the rule.  (Its name is not spelled here: tests/test_ssd.py's last test
+holds this file free of the kernel language's name.)  Everything else runs
+here: the CPU, a toy or ragged shape, another type; and the kernels' tests
+hold them to this module's values and gradients.
 """
 
 from __future__ import annotations

@@ -1,5 +1,5 @@
-"""The flash attention's kernels and the routed experts' grouped matmuls
-compiled for a TPU v5e that is described, not attached, at the widths the chip
+"""The flash attention's kernels, the routed experts' grouped matmuls and the
+state-space scan's kernels compiled for a TPU v5e that is described, not attached, at the widths the chip
 runs them: what the interpreter cannot show
 (a tile Mosaic refuses, more VMEM than a kernel may use).  Nothing runs, so
 nothing here says anything about results or times; chip_smoke.py does, on the
@@ -128,6 +128,41 @@ def test_routed_experts_layer_compiles_for_v5e_without_a_scatter(one_chip):
     # where a result written tile by tile into zeros is a buffer more each
     # (1.59 GB with every pass over the whole buffer, before PR 32).
     assert compiled.memory_analysis().temp_size_in_bytes < 1.45e9
+
+
+# The scan of `nemotron-3-super-120b-a12b.train-s8192x1` (PR 42): 128 heads of
+# 64 in 8 groups, a state of 128, chunks of 128; and a head as wide as a lane
+# tile, a group a head, in float32.  (batch, seq, heads, head, groups, dtype)
+SSD_CASES = {
+    "nemotron-cell-s8192": (1, 8192, 128, 64, 8, jnp.bfloat16),
+    "float32-heads-of-128": (2, 1024, 4, 128, 4, jnp.float32),
+}
+
+
+@pytest.mark.parametrize("case", SSD_CASES)
+def test_the_state_space_scans_kernels_compile_for_v5e(case, one_chip):
+    from deeplearning_cfn_tpu.ops import pallas_ssd
+
+    b, S, H, P, G, dtype = SSD_CASES[case]
+    on_chip = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    f32 = jnp.float32
+    args = (
+        on_chip((b, S, H, P), dtype), on_chip((b, S, H), f32), on_chip((H,), f32),
+        on_chip((b, S, G, 128), dtype), on_chip((b, S, G, 128), dtype), on_chip((H,), f32),
+    )
+    assert pallas_ssd.takes_kernel(args[0], args[3], 128, backend="tpu")
+
+    def grads(*args):
+        loss = lambda *a: pallas_ssd.ssd(*a, 128).astype(f32).sum()
+        return jax.grad(loss, argnums=tuple(range(6)))(*args)
+
+    compiled = jax.jit(grads).lower(*args).compile()
+    text = compiled.as_text()
+    assert "_ssd_forward" in text and "_ssd_backward" in text
+    # Nothing of a chunk's matrix form outside the kernels: the temporaries are
+    # the saved states and copies of x's size, under half of one pass's L.
+    states, an_x = b * S // 128 * 128 * H * P * 4, b * S * H * P * jnp.dtype(dtype).itemsize
+    assert compiled.memory_analysis().temp_size_in_bytes < states + 4 * an_x
 
 
 def _mistral_cell_step(one_chip):

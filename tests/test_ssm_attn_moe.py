@@ -262,6 +262,40 @@ def test_three_steps_of_the_trainer_lower_the_loss_and_count_two_routed_blocks()
         assert f"/{scope}/" in text, scope
 
 
+# --- the scan's two forms through the model ---------------------------------------------
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["float32", "bfloat16"])
+def test_the_loss_and_gradients_with_the_scans_kernels_are_those_with_the_xla_form(dtype, monkeypatch):
+    """A lane-aligned model (chunks and a state of 128, groups of two heads of
+    64) through `lm_loss`, rematerialised as the cell's: the mixer takes
+    `ops/pallas_ssd.ssd` where the rule says so (here the test says so, and the
+    Pallas interpreter runs the kernels) and `ops/ssd.ssd` elsewhere."""
+    from deeplearning_cfn_tpu.ops import pallas_ssd
+
+    cfg = model.SsmAttnMoeConfig.tiny(
+        ssm_heads=4, ssm_head_dim=64, ssm_groups=2, ssm_state=128, chunk=128, remat=True, dtype=dtype
+    )
+    params = model.init_params(cfg, jax.random.key(0))
+    tokens = jax.random.randint(jax.random.key(1), (1, 256), 0, cfg.vocab_size)
+    loss = jax.jit(jax.value_and_grad(lambda p: model.lm_loss(cfg, p, tokens, jnp.roll(tokens, -1, 1))[0]))
+    with HIGHEST():
+        want, want_grads = loss(params)
+        entered = []
+        kernels = pallas_ssd.ssd
+        monkeypatch.setattr(pallas_ssd, "takes_kernel", lambda *a, **k: entered.append(a) or True)
+        monkeypatch.setattr(pallas_ssd, "ssd", partial(kernels, interpret=True))
+        got, got_grads = jax.jit(jax.value_and_grad(
+            lambda p: model.lm_loss(cfg, p, tokens, jnp.roll(tokens, -1, 1))[0]))(params)
+    assert entered
+    tolerance = 1e-4 if dtype == jnp.float32 else 2e-2
+    np.testing.assert_allclose(got, want, rtol=tolerance)
+    flat = lambda tree: jax.tree_util.tree_leaves_with_path(tree)
+    for (path, g), (_, w) in zip(flat(got_grads), flat(want_grads), strict=True):
+        g, w = np.asarray(g, np.float32), np.asarray(w, np.float32)
+        assert np.linalg.norm(g - w) <= tolerance * max(np.linalg.norm(w), 1e-3), jax.tree_util.keystr(path)
+
+
 # --- the other cells' steps are the parent's ----------------------------------------------
 
 # sha256 of each cell's train step as its builder makes it, lowered for the TPU
@@ -312,9 +346,10 @@ def lowered_step_without_locations(name: str, batch: int, seq_len: int) -> str:
 @pytest.mark.parametrize("name", PARENT_STEPS)
 def test_the_other_decoder_cells_steps_lower_to_the_parents_text(name, monkeypatch):
     """`RoutedConfig.expert` and `routed_experts(expert_rows=)` are data the
-    three SwiGLU cells do not set: their whole steps (and the Mistral cell's,
+    three SwiGLU cells do not set, and the scan's kernels (PR 42) have one
+    caller, this file's model: their whole steps (and the Mistral cell's,
     which has no experts) lower, for the TPU and at the cells' own sizes, to
-    what the parent's lower to, kernel source locations apart."""
+    what PR 41's parent's lowered to, kernel source locations apart."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     want, batch, seq_len = PARENT_STEPS[name]
     text = lowered_step_without_locations(name, batch, seq_len)
