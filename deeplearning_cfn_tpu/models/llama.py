@@ -441,14 +441,21 @@ def decoder_block(
     [B, S, H, D], what its owner carries on to the next call: nothing, the
     written cache buffers, the fresh k and v).
 
+    A sandwich-normed block is the same block with more leaves: where the
+    layer holds ``attn_post_norm`` the mixer's output goes through an RMSNorm
+    of that weight before it joins the residual stream, and where it holds
+    ``mlp_post_norm`` the dense feed-forward's does (models/looped_decoder.py
+    holds both).  The leaves are the switch: a tree without them traces as
+    it did before they existed.
+
     Returns (x, aux, carried): aux is the MoE load-balancing loss, 0 for
     dense models; carried is the context's second result, untouched.
     """
     B, S, d = x.shape
     hd = cfg.head_dim
     # The named scopes are metadata for a profile's op names
-    # (attn_norm / attn{qkv,rope,core,out} / mlp_norm / mlp): the
-    # computation is the same with and without them.
+    # (attn_norm / attn{qkv,rope,core,out} / attn_post_norm / mlp_norm /
+    # mlp / mlp_post_norm): the computation is the same with and without them.
     with jax.named_scope("attn_norm"):
         h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
     with jax.named_scope("attn"):
@@ -462,7 +469,12 @@ def decoder_block(
         with jax.named_scope("core"):
             attn, carried = kv_context(q, k, v)
         with jax.named_scope("out"):
-            x = x + attn.reshape(B, S, cfg.n_heads * hd) @ lp["wo"]
+            y = attn.reshape(B, S, cfg.n_heads * hd) @ lp["wo"]
+            if "attn_post_norm" not in lp:
+                x = x + y
+    if "attn_post_norm" in lp:
+        with jax.named_scope("attn_post_norm"):
+            x = x + rms_norm(y, lp["attn_post_norm"], cfg.norm_eps)
     with jax.named_scope("mlp_norm"):
         h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
     with jax.named_scope("mlp"):
@@ -471,7 +483,12 @@ def decoder_block(
 
             y, aux = moe_mlp(cfg.moe, lp["moe"], h)
             return x + y, aux, carried
-        x = x + swiglu(h, lp["w_gate"], lp["w_up"], lp["w_down"])
+        y = swiglu(h, lp["w_gate"], lp["w_up"], lp["w_down"])
+        if "mlp_post_norm" not in lp:
+            x = x + y
+    if "mlp_post_norm" in lp:
+        with jax.named_scope("mlp_post_norm"):
+            x = x + rms_norm(y, lp["mlp_post_norm"], cfg.norm_eps)
     return x, jnp.zeros((), jnp.float32), carried
 
 
