@@ -10,7 +10,7 @@ transformer flagships (BERT, Llama-3).  Design is TPU-first:
   live in VMEM scratch across kv steps and the [S, S] score matrix is never
   materialized in HBM.  Scores/softmax in f32 on the MXU via
   ``preferred_element_type``; inputs stay bf16.
-- The causal triangle, the same in all three kernels (forward, dk/dv, dq):
+- The causal triangle, the same in every kernel (forward, dk/dv or fused, dq):
   a (q block, kv block) pair wholly above the diagonal is neither computed
   (``_run_pair``) nor fetched (its BlockSpec index names the block of the
   nearest step that runs, so the pipeline has nothing to copy); a pair
@@ -43,14 +43,20 @@ transformer flagships (BERT, Llama-3).  Design is TPU-first:
   is fetched for ``group = Hq // Hkv`` query heads) — no materialized
   ``repeat`` anywhere, forward or backward.
 - Backward: ``custom_vjp`` with the flash-attention-2 residuals (q, k, v,
-  out and the log-sum-exp: O(S) activation memory) and two Pallas kernels
-  that recompute the probabilities blockwise from the log-sum-exp, so the
-  score-sized tensors s, p, dp, ds live in VMEM only: a dk/dv kernel, grid
-  ``(batch, kv_heads, kv_blocks, group * q_blocks)``, that folds the
-  ``group`` query heads of a kv head into its sequential axis and writes dk
-  and dv once per kv head, and a dq kernel, grid ``(batch, heads, q_blocks,
-  kv_blocks)``.  Only ``delta = rowsum(out * dout)`` and the layout changes
-  around the kernels are XLA.
+  out and the log-sum-exp: O(S) activation memory) and Pallas kernels that
+  recompute the probabilities blockwise from the log-sum-exp, so the
+  score-sized tensors s, p, dp, ds live in VMEM only.  Without a window, one
+  fused kernel (``_flash_backward_fused``), grid ``(batch, kv_heads,
+  kv_blocks, group * q_blocks)``: it folds the ``group`` query heads of a kv
+  head into its sequential axis, writes dk and dv once per kv head, and adds
+  every pair's dq into a float32 block of the whole group that stays in VMEM
+  while the grid walks the kv head: five block matmuls a pair.  Under a
+  window, or where that block does not fit (`_takes_fused_backward`: the
+  shapes choose, no argument does), the pair it grew from: the same dk/dv
+  kernel without the third result, and a dq kernel, grid ``(batch, heads,
+  q_blocks, kv_blocks)``, seven matmuls a pair between them.  Only ``delta =
+  rowsum(out * dout)``, dq's scale and cast and the layout changes around the
+  kernels are XLA.
 - Mesh-aware: pass ``mesh=`` and the kernel runs under ``shard_map`` with
   batch sharded over (dp, fsdp) and heads over tp — attention is
   independent per (batch, head), so each shard computes locally with no
@@ -110,6 +116,33 @@ BWD_DQ_BLOCKS = (1024, 1024)
 # Four float32 [1024, 1024] tiles (s, p, dp, ds) are 16 MB, the default
 # scoped VMEM limit by themselves; a v5e core has 128 MiB.
 _BWD_VMEM_LIMIT = 64 * 1024 * 1024
+# The fused backward kernel's (q block, kv block), from a sweep on v5e at the
+# decoder cells' full-causal shapes (scripts/chip_attention_backward_sweep.py,
+# PR 44; bf16, causal; ms a call by the device trace; the pair is the dk/dv and
+# the dq kernel at their own 1024 x 1024, the fused kernel at q block x kv
+# block 512x512 / 512x1024 / 1024x512 / 1024x1024):
+#   B, S, heads q/kv of D          pair                 fused
+#   2, 4096, 32/8 of 128   (Mistral)   3.98 + 3.47    5.33   5.27   5.30   4.96
+#   1, 8192, 16/16 of 128  (Ouro)      3.54 + 2.93    4.98   4.67   4.68   4.35
+#   2, 8192, 20/20 of 256  (GLM)      16.87 + 13.87  22.25  21.90  21.92  20.98
+#   2, 8192, 32/8 of 64    (LFM2)     13.96 + 11.73  20.37  18.94  18.97  17.68
+#   2, 8192, 48/8 of 128   (Laguna)   20.93 + 17.48  30.30  28.31  28.37  26.54
+#   1, 8192, 32/2 of 128   (Nemotron)  7.00 + 5.96   132-140 MB of VMEM's 128
+#   1, 2048, 16/4 of 64                0.30 + 0.23    0.33   0.37   0.38   0.36
+# 0.67-0.69 of the pair at every shape that fits, under the 5/7 of the matmul
+# count: the exponential, the mask and ds are made once a pair too.  At Ouro's
+# shape the five matmuls of its 576 pairs run at 178 TFLOP/s, 90% of the MXU's
+# peak; the transposed-left matmul's pass over ds^T hides under them.  The
+# whole backward on the host's clock at 1024 x 1024, pair -> fused: 9.20 ->
+# 6.41, 7.81 -> 5.50, 31.92 -> 22.41, 29.06 -> 20.74, 42.73 -> 30.52.  On the
+# chip the three gradients equal the pair's bit for bit at every row.
+BWD_FUSED_BLOCKS = (1024, 1024)
+# What the fused kernel's resident dq (`_fused_dq_bytes`: a kv head's group,
+# float32, both of Pallas's buffers) may take of VMEM beside `_BWD_VMEM_LIMIT`;
+# the call's limit is the sum, 120 MiB of a v5e core's 128 at most.  Laguna's
+# groups of 6 take 48 MiB and fit; Nemotron's groups of 16 would take 128 and
+# run the pair, as does any call past S 57,344 at groups of 1 and heads of 128.
+_BWD_FUSED_DQ_BUDGET = 56 * 1024 * 1024
 # The tiles of calls with a window, (q block, kv block) of the forward, the
 # dk/dv and the dq kernel, from a sweep on v5e at the window layers' shape of
 # `laguna-xs.2.train-s8192` (scripts/chip_grouped_matmul_sweep.py window, PR 36;
@@ -256,12 +289,13 @@ def _kv_index_map(
     return kv_index
 
 
-def _compiler_params(vmem_limit: int) -> pltpu.CompilerParams:
+def _compiler_params(vmem_limit: int, carried_axes: int = 1) -> pltpu.CompilerParams:
     # batch, head and the held block are independent (megacore-splittable);
     # the last grid axis walks the other operand's blocks and carries the
-    # VMEM accumulators.
+    # VMEM accumulators (the last two where the fused backward's dq is carried
+    # over the held blocks too).
     return pltpu.CompilerParams(
-        dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
+        dimension_semantics=("parallel",) * (4 - carried_axes) + ("arbitrary",) * carried_axes,
         vmem_limit_bytes=vmem_limit,
     )
 
@@ -604,16 +638,18 @@ def _band_forward(
     return out, lse[:, :, 0, :S]
 
 
-# --- backward: two Pallas kernels (flash-attention-2) ---------------------
+# --- backward: Pallas kernels (flash-attention-2) --------------------------
 #
-# Both recompute the probabilities of one (q block, kv block) pair from the
+# They recompute the probabilities of one (q block, kv block) pair from the
 # saved log-sum-exp, in VMEM: ``p = exp(q k^T * scale - lse)``,
 # ``ds = p * (dout v^T - delta)`` with ``delta = rowsum(out * dout)``.  The
 # dk/dv kernel holds a kv block and walks the q blocks of every query head
 # of its group (dk, dv are written once per kv head); the dq kernel holds a
-# q block and walks the kv blocks.  7 block matmuls where a fused kernel
-# needs 5, in exchange for no accumulation through HBM.  ``sm_scale`` on ds
-# is applied once, to the float32 accumulators, as they are written.
+# q block and walks the kv blocks: 7 block matmuls a pair between the two.
+# The fused form is the dk/dv kernel with dq as a third result that does not
+# leave VMEM until the kv head is done: 5 matmuls, no accumulation through
+# HBM, and no dq kernel.  ``sm_scale`` on ds is applied once, to the float32
+# sums: by the kernels as they write, by XLA on the fused form's dq.
 
 
 def _dkv_kernel(
@@ -625,9 +661,8 @@ def _dkv_kernel(
     delta_ref,  # [1, 1, 1, Bq] f32
     dk_ref,  # [1, 1, Bk, D]
     dv_ref,  # [1, 1, Bk, D]
-    dk_acc,  # VMEM [Bk, D] f32
-    dv_acc,  # VMEM [Bk, D] f32
-    *,
+    *rest,  # the fused form's dq_ref [1, group, S', D] f32, then the scratch:
+    # dk_acc, dv_acc: VMEM [Bk, D] f32
     causal: bool,
     sm_scale: float,
     block_q: int,
@@ -640,7 +675,16 @@ def _dkv_kernel(
     """Works on the transposed tile s^T = k q^T [Bk, Bq]: the per-query
     statistics then broadcast along sublanes from a compact [1, Bq] row,
     and both accumulations (p^T dout, ds^T q) are plain [Bk, Bq] x [Bq, D]
-    matmuls with nothing to transpose."""
+    matmuls with nothing to transpose.
+
+    The fused form (`_flash_backward_fused`) hands it a third result, the
+    float32 dq of the kv head's whole group, resident in VMEM while the grid
+    walks the head's kv blocks: the pair's ds^T feeds it too, by the one
+    matmul that contracts the first axis of both operands (ds^T)^T k, so a
+    pair is five block matmuls and its scores, exponential, mask, dp and ds
+    are made once.  A q block's rows are summed over the kv blocks in the dq
+    kernel's order, from zeros, and leave unscaled."""
+    *dq_ref, dk_acc, dv_acc = rest
     ki = pl.program_id(2)
     t = pl.program_id(3)  # (query head of the group, q block), q block fastest
     qi = t % nq
@@ -656,10 +700,20 @@ def _dkv_kernel(
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
+    dq_ref = dq_ref[0] if dq_ref else None
+    if dq_ref is not None:
+        # the q block's rows of its query head
+        dq_rows = (0, t // nq, pl.ds(pl.multiple_of(q_start, block_q), block_q), slice(None))
+
+        @pl.when(ki == 0)  # every (query head, q block) passes the first kv block
+        def _init_dq():
+            dq_ref[dq_rows] = jnp.zeros((block_q, dq_ref.shape[-1]), dq_ref.dtype)
+
     def pair(masked: bool):
         q, k, v, do = q_ref[0, 0], k_ref[0, 0], v_ref[0, 0], do_ref[0, 0]
         nt = (((1,), (1,)), ((), ()))  # contract the head dim of both
         nn = (((1,), (0,)), ((), ()))
+        tn = (((0,), (0,)), ((), ()))  # contract the key axis of both
         st = jax.lax.dot_general(k, q, nt, preferred_element_type=jnp.float32)
         pt = jnp.exp(st * sm_scale - lse_ref[0, 0])  # [Bk, Bq]
         if masked:
@@ -678,6 +732,10 @@ def _dkv_kernel(
         dk_acc[:] += jax.lax.dot_general(
             dst.astype(q.dtype), q, nn, preferred_element_type=jnp.float32
         )
+        if dq_ref is not None:
+            dq_ref[dq_rows] += jax.lax.dot_general(
+                dst.astype(k.dtype), k, tn, preferred_element_type=jnp.float32
+            )
 
     _run_pair(
         pair, q_start, k_start, causal=causal, block_q=block_q, block_k=block_k,
@@ -760,9 +818,15 @@ def _pad_rows(x: jax.Array, block: int) -> jax.Array:
     return jnp.pad(x, ((0, 0), (0, 0), (0, (-x.shape[2]) % block)))
 
 
-def _backward_dkv(q, k, v, dout, lse, delta, *, causal, sm_scale, blocks, interpret, window=None):
+def _backward_dkv(
+    q, k, v, dout, lse, delta, *, causal, sm_scale, blocks, interpret, window=None, fused=False
+):
     """dk, dv [B, Sk, Hkv, D]: grid (B, Hkv, kv blocks, group * q blocks);
-    under a window, group * the q blocks the widest kv block's band holds."""
+    under a window, group * the q blocks the widest kv block's band holds.
+    ``fused`` (no window): dq [B, Sq, Hq, D] too, from the same pairs: a third
+    result [B, Hq, S', D] float32 in blocks of a kv head's group, whose index
+    does not move while the grid walks that head's kv and q blocks, so that it
+    stays in VMEM and is written once."""
     bq, bk = blocks
     (B, Sq, Hq, D), (_, Sk, Hkv, _) = q.shape, k.shape
     group = Hq // Hkv
@@ -791,30 +855,44 @@ def _backward_dkv(q, k, v, dout, lse, delta, *, causal, sm_scale, blocks, interp
     q_spec = pl.BlockSpec((1, 1, bq, D), lambda *ids: (*q_index(*ids), 0))
     row_spec = pl.BlockSpec((1, 1, 1, bq), row_index)
     kv_spec = pl.BlockSpec((1, 1, bk, D), lambda b, h, j, t: (b, h, j, 0))
-    dk, dv = pl.pallas_call(
+    out_specs = [kv_spec, kv_spec]
+    out_shape = [jax.ShapeDtypeStruct(kt.shape, k.dtype), jax.ShapeDtypeStruct(vt.shape, v.dtype)]
+    if fused:
+        out_specs.append(pl.BlockSpec((1, group, qt.shape[2], D), lambda b, h, j, t: (b, h, 0, 0)))
+        out_shape.append(jax.ShapeDtypeStruct(qt.shape, jnp.float32))
+        name = "_flash_backward_fused"
+        params = _compiler_params(
+            _BWD_VMEM_LIMIT + _fused_dq_bytes(group, qt.shape[2], D), carried_axes=2
+        )
+    else:
+        name = "_flash_backward_dkv" if window is None else "_window_flash_backward_dkv"
+        params = _compiler_params(_BWD_VMEM_LIMIT)
+    dk, dv, *dq = pl.pallas_call(
         functools.partial(
             _dkv_kernel, causal=causal, sm_scale=sm_scale,
             block_q=bq, block_k=bk, q_len=Sq, kv_len=Sk, nq=q_steps, window=window,
         ),
         grid=(B, Hkv, nk, group * q_steps),
         in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
-        out_specs=[kv_spec, kv_spec],
-        out_shape=[
-            jax.ShapeDtypeStruct(kt.shape, k.dtype),
-            jax.ShapeDtypeStruct(vt.shape, v.dtype),
-        ],
+        out_specs=out_specs,
+        out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM((bk, D), jnp.float32)] * 2,
-        compiler_params=_compiler_params(_BWD_VMEM_LIMIT),
+        compiler_params=params,
         interpret=interpret,
         # Not `_flash_forward...`: the benchmark's attention_roofline_share
         # finds the forward kernel by that prefix, these by `_flash_backward`.
-        name="_flash_backward_dkv" if window is None else "_window_flash_backward_dkv",
+        name=name,
     )(
         qt, kt, vt, dot,
         # the statistics as one row along the lanes: [B, Hq, 1, S']
         _pad_rows(lse, bq)[:, :, None, :], _pad_rows(delta, bq)[:, :, None, :],
     )
-    return jnp.swapaxes(dk, 1, 2)[:, :Sk], jnp.swapaxes(dv, 1, 2)[:, :Sk]
+    dk, dv = jnp.swapaxes(dk, 1, 2)[:, :Sk], jnp.swapaxes(dv, 1, 2)[:, :Sk]
+    if not fused:
+        return dk, dv
+    # The scale, the cast and the way back to [B, Sq, Hq, D] are one XLA pass
+    # over the float32 sums: what `_dq_kernel` does as it writes.
+    return jnp.swapaxes(dq[0] * sm_scale, 1, 2)[:, :Sq].astype(q.dtype), dk, dv
 
 
 def _backward_dq(q, k, v, dout, lse, delta, *, causal, sm_scale, blocks, interpret, window=None):
@@ -879,6 +957,43 @@ def _flash_backward(
     dk, dv = _backward_dkv(q, k, v, dout, lse, delta, blocks=dkv_blocks, **kw)
     dq = _backward_dq(q, k, v, dout, lse, delta, blocks=dq_blocks, **kw)
     return dq, dk, dv
+
+
+def _fused_dq_bytes(group: int, rows: int, head_dim: int) -> int:
+    """VMEM the fused backward's resident dq takes: float32 [group, rows, D] in
+    lane tiles, twice (Pallas keeps two buffers of an output block)."""
+    return 2 * group * rows * _round_up(head_dim, 128) * 4
+
+
+def _takes_fused_backward(window: int | None, group: int, seq_q: int, head_dim: int) -> bool:
+    """Whether a backward pass runs the fused kernel, from what the call can
+    see: no window, and the dq of a kv head's group, resident at the fused
+    tiles, within `_BWD_FUSED_DQ_BUDGET`.  Every other call runs the pair."""
+    rows = _round_up(seq_q, _clamp_block(BWD_FUSED_BLOCKS[0], seq_q))
+    return window is None and _fused_dq_bytes(group, rows, head_dim) <= _BWD_FUSED_DQ_BUDGET
+
+
+@functools.partial(
+    jax.jit, static_argnames=("causal", "sm_scale", "blocks", "interpret")
+)
+def _flash_backward_fused(
+    q: jax.Array,
+    k: jax.Array,
+    v: jax.Array,
+    out: jax.Array,
+    lse: jax.Array,  # [B, Hq, Sq] f32
+    dout: jax.Array,
+    causal: bool,
+    sm_scale: float,
+    blocks: tuple[int, int],  # (q block, kv block)
+    interpret: bool,
+):
+    """`_flash_backward`'s three gradients from one kernel (no window)."""
+    delta = jnp.einsum("bqhd,bqhd->bhq", out, dout, preferred_element_type=jnp.float32)
+    return _backward_dkv(
+        q, k, v, dout, lse, delta, causal=causal, sm_scale=sm_scale,
+        blocks=blocks, interpret=interpret, fused=True,
+    )
 
 
 # --- custom-vjp core (arrays only; mesh handled by the public wrapper) ---
@@ -985,6 +1100,10 @@ def _core_bwd(causal, sm_scale, block_q, block_k, interpret, window, res, g):
     # The scope is what tells the backward's kernels and the few XLA
     # operations around them from the rest of the step's in a profile.
     with jax.named_scope("attn_bwd"):
+        if _takes_fused_backward(window, q.shape[2] // k.shape[2], q.shape[1], q.shape[3]):
+            return _flash_backward_fused(
+                q, k, v, out, lse, g, causal, sm_scale, clamp(BWD_FUSED_BLOCKS), interpret
+            )
         return _flash_backward(
             q, k, v, out, lse, g, causal, sm_scale,
             clamp(dkv_blocks), clamp(dq_blocks), interpret, window=window,

@@ -6,6 +6,8 @@ nothing here says anything about results or times; chip_smoke.py does, on the
 chip.  All of these in this one file: the worker that gets it loads the TPU's
 compiler, and only that one may."""
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -28,12 +30,16 @@ def one_chip():
 # (batch, seq, q heads, kv heads, head dim, dtype): the decoder cell's
 # attention, the latent-attention cell's (20 heads of 192 + 64 = 256, the value
 # head 256 too), the convolution-attention cell's (32/8 heads of 64 at S 8192),
+# the looped decoder's (groups of 1), the state-space hybrid's one attention
+# block (groups of 16: its dq does not fit the fused backward's budget),
 # the ragged case of chip_smoke.py (2100 pads to 2176 in blocks of 128), GQA
 # 16/4 at d64, and float32.
 CASES = {
     "cell-s4096": (2, 4096, 32, 8, 128, jnp.bfloat16),
     "mla-cell-s8192": (2, 8192, 20, 20, 256, jnp.bfloat16),
     "conv-attn-cell-s8192": (2, 8192, 32, 8, 64, jnp.bfloat16),
+    "looped-cell-s8192": (1, 8192, 16, 16, 128, jnp.bfloat16),
+    "ssm-hybrid-cell-s8192-groups-of-16": (1, 8192, 32, 2, 128, jnp.bfloat16),
     "ragged-s2100": (1, 2100, 32, 8, 128, jnp.bfloat16),
     "d64-s2048": (1, 2048, 16, 4, 64, jnp.bfloat16),
     "float32-s2048": (1, 2048, 8, 8, 128, jnp.float32),
@@ -41,22 +47,36 @@ CASES = {
 
 
 @pytest.mark.parametrize("case", CASES)
-def test_forward_and_backward_compile_for_v5e(case, one_chip):
+def test_forward_and_backward_compile_for_v5e(case, one_chip, monkeypatch):
     B, S, Hq, Hkv, D, dtype = CASES[case]
     q = jax.ShapeDtypeStruct((B, S, Hq, D), dtype, sharding=one_chip)
     kv = jax.ShapeDtypeStruct((B, S, Hkv, D), dtype, sharding=one_chip)
 
-    def grads(q, k, v):
-        loss = lambda q, k, v: pa.flash_attention(q, k, v).astype(jnp.float32).sum()
-        return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+    def compile_grads():
+        def grads(q, k, v):  # a function of its own each time: nothing traced is reused
+            loss = lambda q, k, v: pa.flash_attention(q, k, v).astype(jnp.float32).sum()
+            return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
 
-    compiled = jax.jit(grads).lower(q, kv, kv).compile()
+        return jax.jit(grads).lower(q, kv, kv).compile()
+
+    compiled = compile_grads()
     text = compiled.as_text()
-    for kernel in ("_flash_forward", "_flash_backward_dkv", "_flash_backward_dq"):
-        assert kernel in text, kernel
+    fused = pa._takes_fused_backward(None, Hq // Hkv, S, D)
+    assert fused == (case != "ssm-hybrid-cell-s8192-groups-of-16")
+    backward = {"_flash_backward_fused"} if fused else {"_flash_backward_dkv", "_flash_backward_dq"}
+    assert set(re.findall(r"_flash_(?:forward|backward_\w+)", text)) == {"_flash_forward"} | backward
     # No score-sized tensor outside the kernels: all temporaries together
     # stay under one float32 [B, Hq, S, 512] slab of the old XLA backward.
-    assert compiled.memory_analysis().temp_size_in_bytes < B * Hq * S * 512 * 4
+    temporaries = compiled.memory_analysis().temp_size_in_bytes
+    assert temporaries < B * Hq * S * 512 * 4
+    if fused:
+        # Beside the pair at the same shape: the fused call's temporaries are
+        # no larger except by the float32 dq (the pair's two lane-replicated
+        # [B, Hq, S, 128] float32 arrays go, and its bfloat16 dq).
+        monkeypatch.setattr(pa, "_BWD_FUSED_DQ_BUDGET", 0)
+        pair = compile_grads()
+        assert "_flash_backward_fused" not in pair.as_text()
+        assert temporaries <= pair.memory_analysis().temp_size_in_bytes + B * Hq * S * D * 4
 
 
 # The attention of `laguna-xs.2.train-s8192` (PR 33): the full layers' 48
@@ -84,9 +104,12 @@ def test_windowed_kernels_compile_for_v5e(case, one_chip):
 
     compiled = jax.jit(grads).lower(q, kv, kv).compile()
     text = compiled.as_text()
-    prefix = "_flash" if window is None else "_window_flash"
-    for kernel in ("forward", "backward_dkv", "backward_dq"):
-        assert f"{prefix}_{kernel}" in text, kernel
+    kernels = (
+        ("_flash_forward", "_flash_backward_fused") if window is None
+        else ("_window_flash_forward", "_window_flash_backward_dkv", "_window_flash_backward_dq")
+    )
+    for kernel in kernels:
+        assert kernel in text, kernel
     assert ("_window_flash" in text) == (window is not None)
     assert compiled.memory_analysis().temp_size_in_bytes < B * Hq * S * 512 * 4
 
@@ -215,4 +238,6 @@ def test_the_kept_pair_costs_the_mistral_cells_step_its_shapes_once(one_chip, mo
     assert pair == 5 * (2 * 4096 * 32 * 128 * 2 + 2 * 32 * 4096 * 4) == 340_787_200
     after, before = kept.memory_analysis(), recomputed.memory_analysis()
     assert after.peak_memory_in_bytes - before.peak_memory_in_bytes == pair
-    assert after.temp_size_in_bytes - before.temp_size_in_bytes == 2 * pair
+    # to the byte under the pair of backward kernels; with the fused one (PR 44)
+    # the two programs' small buffers differ by 32,256 bytes beside it
+    assert after.temp_size_in_bytes - before.temp_size_in_bytes == pytest.approx(2 * pair, rel=1e-4)

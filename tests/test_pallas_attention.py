@@ -2,9 +2,10 @@
 attention.
 
 Covers: causal/non-causal, GQA, non-divisible sequence lengths (padding +
-masking), and gradients through the custom VJP, whose backward pass is two
-Pallas kernels of its own.  The compiled kernels' numerics are checked on
-the chip by chip_smoke.py.
+masking), and gradients through the custom VJP, whose backward pass is one
+fused Pallas kernel where the shapes allow it and a pair of kernels
+elsewhere.  The compiled kernels' numerics are checked on the chip by
+chip_smoke.py.
 """
 
 import dataclasses
@@ -192,7 +193,7 @@ def backward_blocks(monkeypatch):
     tiles) do not reach."""
 
     def set_blocks(block_q: int, block_k: int):
-        for name in ("BWD_DKV_BLOCKS", "BWD_DQ_BLOCKS"):
+        for name in ("BWD_DKV_BLOCKS", "BWD_DQ_BLOCKS", "BWD_FUSED_BLOCKS"):
             monkeypatch.setattr(pallas_attention, name, (block_q, block_k))
 
     set_blocks(16, 16)
@@ -267,12 +268,24 @@ def test_keys_after_every_query_get_exact_zeros(backward_blocks):
     assert not np.asarray(gf[1][:, 30:]).any() and not np.asarray(gf[2][:, 30:]).any()
 
 
-def test_backward_kernels_are_named_apart_from_the_forward():
+@pytest.fixture
+def the_pair_everywhere(monkeypatch):
+    """No dq fits: every backward pass is the pair of kernels, as before PR 44
+    and as a call runs today whose resident dq passes the budget."""
+    monkeypatch.setattr(pallas_attention, "_BWD_FUSED_DQ_BUDGET", 0)
+
+
+@pytest.mark.parametrize("form", ["fused", "pair"])
+def test_backward_kernels_are_named_apart_from_the_forward(form, request):
     """Lowered for the TPU (no chip needed to lower): the step's text carries
     the scope `attn_bwd`, which attention_backward_ms_per_step reads, and
     only the forward kernel's name begins `_flash_forward`, which
-    attention_roofline_share matches and divides by the forward's FLOPs."""
+    attention_roofline_share matches and divides by the forward's FLOPs; the
+    backward's names begin `_flash_backward`, one kernel or two."""
     import re
+
+    if form == "pair":
+        request.getfixturevalue("the_pair_everywhere")
 
     def loss(q, k, v):
         return pallas_attention.flash_attention(q, k, v).astype(jnp.float32).sum()
@@ -284,10 +297,122 @@ def test_backward_kernels_are_named_apart_from_the_forward():
     )
     text = lowered.as_text(debug_info=True)
     kernels = set(re.findall(r'kernel_name = "([^"]+)"', text))
-    assert kernels == {"_flash_forward", "_flash_backward_dkv", "_flash_backward_dq"}
+    backward = {
+        "fused": {"_flash_backward_fused"}, "pair": {"_flash_backward_dkv", "_flash_backward_dq"}
+    }[form]
+    assert kernels == {"_flash_forward"} | backward
     assert [n for n in kernels if n.startswith("_flash_forward")] == ["_flash_forward"]
+    assert all(re.search(r"^_flash_backward", n) for n in backward)
     assert "attn_bwd" in text
     assert not hasattr(pallas_attention, "_blockwise_backward")
+
+
+# The fused kernel against the pair on the same inputs, both in the interpreter
+# at tiles of 16: the file's cases (groups of 1, 4 and 6, heads of 64, 128 and
+# 256, a ragged length, non-causal, more keys than queries).
+FUSED_CASES = {
+    "groups-of-1": dict(hq=4, hkv=4),
+    "groups-of-4": dict(hq=8, hkv=2),
+    "groups-of-6": dict(hq=6, hkv=1),
+    "heads-of-64": dict(d=64),
+    "heads-of-128": dict(d=128),
+    "heads-of-256": dict(hq=2, hkv=2, d=256),
+    "ragged-50": dict(s=50),
+    "ragged-2100-tiles-of-256": dict(b=1, s=2100, hq=2, hkv=1, blocks=(256, 256)),
+    "non-causal": dict(causal=False),
+    "non-causal-ragged-50-groups-of-4": dict(s=50, hq=8, hkv=2, causal=False),
+    "uneven-blocks": dict(s=64, blocks=(32, 16)),
+    "uneven-blocks-wide-k": dict(s=64, blocks=(16, 32)),
+    "one-block": dict(blocks=(48, 48)),
+    "keys-after-every-query": dict(s=30, keys=50),
+}
+
+
+def _both_backward_forms(case, dtype):
+    kw = dict(b=2, s=48, hq=4, hkv=2, d=16, causal=True, blocks=(16, 16), keys=None)
+    kw.update(FUSED_CASES[case])
+    q, _, _ = _qkv(b=kw["b"], s=kw["s"], hq=kw["hq"], hkv=kw["hkv"], d=kw["d"], dtype=dtype)
+    dout, k, v = _qkv(
+        b=kw["b"], s=kw["keys"] or kw["s"], hq=kw["hq"], hkv=kw["hkv"], d=kw["d"], seed=1, dtype=dtype
+    )
+    dout = dout[:, : kw["s"]]
+    scale, blocks = kw["d"] ** -0.5, kw["blocks"]
+    out, lse = pallas_attention._flash_forward(q, k, v, kw["causal"], scale, *blocks, True)
+    pair = pallas_attention._flash_backward(
+        q, k, v, out, lse, dout, kw["causal"], scale, blocks, blocks, True
+    )
+    fused = pallas_attention._flash_backward_fused(
+        q, k, v, out, lse, dout, kw["causal"], scale, blocks, True
+    )
+    return kw, pair, fused
+
+
+@pytest.mark.parametrize("case", FUSED_CASES)
+def test_fused_backward_is_the_pairs_in_float32(case):
+    """dk and dv are the dk/dv kernel's own operations on the same tiles in the
+    same order: bit for bit.  dq is the dq kernel's sums in its order, a q
+    block's rows over the kv blocks from zeros, by a matmul that contracts the
+    first axis of both operands where the dq kernel's contracts the second of
+    its left one: the interpreter's CPU routine for it may round a sum
+    otherwise (it does at some tiles and not at others), so float32 rounding
+    is the bound there."""
+    kw, (dq, dk, dv), (fdq, fdk, fdv) = _both_backward_forms(case, jnp.float32)
+    assert fdq.dtype == fdk.dtype == fdv.dtype == jnp.float32
+    np.testing.assert_array_equal(np.asarray(fdk), np.asarray(dk))
+    np.testing.assert_array_equal(np.asarray(fdv), np.asarray(dv))
+    np.testing.assert_allclose(np.asarray(fdq), np.asarray(dq), rtol=1e-5, atol=1e-5)
+    if kw["keys"]:  # the keys past the last query: the accumulators' zeros
+        assert not np.asarray(fdk[:, kw["s"]:]).any() and not np.asarray(fdv[:, kw["s"]:]).any()
+        assert np.isfinite(np.asarray(fdq)).all()
+
+
+@pytest.mark.parametrize("case", ["groups-of-1", "groups-of-4", "heads-of-128", "ragged-50"])
+def test_fused_backward_is_the_pairs_in_bfloat16(case):
+    """bfloat16 into the MXU, float32 sums, `ds` cast once: dk and dv bit for
+    bit, dq (which leaves in float32 and is scaled and cast by XLA, as the dq
+    kernel does as it writes) within the bfloat16 tests' tolerance."""
+    _, (dq, dk, dv), (fdq, fdk, fdv) = _both_backward_forms(case, jnp.bfloat16)
+    assert fdq.dtype == fdk.dtype == fdv.dtype == jnp.bfloat16
+    np.testing.assert_array_equal(np.asarray(fdk, np.float32), np.asarray(dk, np.float32))
+    np.testing.assert_array_equal(np.asarray(fdv, np.float32), np.asarray(dv, np.float32))
+    scale = float(jnp.max(jnp.abs(dq.astype(jnp.float32))))
+    np.testing.assert_allclose(
+        np.asarray(fdq, np.float32) / scale, np.asarray(dq, np.float32) / scale, atol=3e-2
+    )
+
+
+# (window, group, S, head dim) -> whether the backward pass is the fused
+# kernel: the six decoder cells' full-causal calls, the Laguna cell's window
+# layers, and a sequence whose dq passes the budget.
+FUSED_RULE = {
+    "ouro-16-16-heads-of-128": ((None, 1, 8192, 128), True),
+    "glm-20-20-heads-of-256": ((None, 1, 8192, 256), True),
+    "mistral-32-8-heads-of-128-s4096": ((None, 4, 4096, 128), True),
+    "lfm2-32-8-heads-of-64": ((None, 4, 8192, 64), True),
+    "laguna-full-48-8-heads-of-128": ((None, 6, 8192, 128), True),
+    "nemotron-32-2-groups-of-16": ((None, 16, 8192, 128), False),
+    "laguna-window-512": ((512, 8, 8192, 128), False),
+    "a-window-as-long-as-the-sequence": ((8192, 1, 8192, 128), False),
+    "s-65536-groups-of-1": ((None, 1, 65536, 128), False),
+    "s-32768-groups-of-1": ((None, 1, 32768, 128), True),
+    "s-32768-groups-of-4": ((None, 4, 32768, 128), False),
+    "ragged-s2100": ((None, 4, 2100, 128), True),
+}
+
+
+@pytest.mark.parametrize("case", FUSED_RULE)
+def test_the_shapes_choose_the_backward_form(case):
+    """What the call can see decides: no window, and the float32 dq of a kv
+    head's group, both of Pallas's buffers, within `_BWD_FUSED_DQ_BUDGET`;
+    the call's VMEM limit is `_BWD_VMEM_LIMIT` and that, under a v5e core's
+    128 MiB."""
+    args, takes = FUSED_RULE[case]
+    assert pallas_attention._takes_fused_backward(*args) is takes
+    window, group, seq, head_dim = args
+    held = pallas_attention._fused_dq_bytes(group, seq, head_dim)
+    assert held == 2 * group * seq * max(head_dim, 128) * 4
+    if takes:
+        assert pallas_attention._BWD_VMEM_LIMIT + held <= 120 * 1024 * 1024
 
 
 def test_twenty_heads_of_256_forward_and_gradients(backward_blocks):
@@ -501,10 +626,13 @@ def test_xla_attention_window_is_the_band_by_hand():
         flash_attention(q, k, v, causal=False, window=3)
 
 
-def test_a_window_that_holds_every_key_is_bit_equal_to_no_window(backward_blocks, window_blocks):
+def test_a_window_that_holds_every_key_is_bit_equal_to_no_window(
+    backward_blocks, window_blocks, the_pair_everywhere
+):
     """Another grid and another mask, the same numbers: with W = S no key is
     hidden, and the output and the three gradients equal the full-causal
-    kernels' bit for bit."""
+    kernels' bit for bit (the pair's: a windowed call runs no other, and the
+    fused form's dq is held to the pair's above)."""
     window_blocks(16, 16)
     q, k, v = _qkv(s=50, hq=6, hkv=1)
     full = lambda q, k, v: flash_attention(q, k, v, True, None, 16, 16)
@@ -524,12 +652,26 @@ KERNELS_BEFORE_THE_WINDOW = {
 }
 
 
-def test_without_a_window_the_kernels_are_the_ones_before_it():
+def test_without_a_window_the_kernels_are_the_ones_before_it(the_pair_everywhere):
     """`window=None` traces no operation more or fewer: the Mosaic modules are
     the parent's, operation for operation (their serialized form also carries
     source lines, which moved, so the cells' lowered text differs there and
-    nowhere else: PERF.md, PR 33)."""
+    nowhere else: PERF.md, PR 33).  Since PR 44 the pair is what a call runs
+    whose dq does not fit; the dk/dv kernel's body, which the fused form
+    shares, traces to the same module without a dq."""
     assert _kernels_without_locations(None) == KERNELS_BEFORE_THE_WINDOW
+
+
+# The fused backward kernel as PR 44 lowered it, beside the forward kernel it
+# left alone, by `_kernels_without_locations(None)` in that tree.
+KERNELS_WITH_THE_FUSED_BACKWARD = {
+    "_flash_forward": KERNELS_BEFORE_THE_WINDOW["_flash_forward"],
+    "_flash_backward_fused": "3b9d5c15de344466a08a816635758b3356e9650ccca8dfe97e05ff79037a2a85",
+}
+
+
+def test_a_full_causal_call_is_the_forward_kernel_and_the_fused_backward():
+    assert _kernels_without_locations(None) == KERNELS_WITH_THE_FUSED_BACKWARD
 
 
 def test_windowed_kernels_carry_names_the_full_causal_readers_do_not_match():
@@ -756,7 +898,8 @@ SELECTION_CASES = {
 @pytest.mark.parametrize("case", SELECTION_CASES)
 def test_the_shapes_choose_the_forward_kernel(case):
     """Lowered for the TPU, forward and backward (no chip needed to lower): the
-    forward kernel's name in the text, beside the kind's two backward kernels.
+    forward kernel's name in the text, beside the kind's backward kernels (the
+    fused one without a window, the pair under one).
     No argument chooses the band step; the shapes do."""
     import re
 
@@ -771,7 +914,8 @@ def test_the_shapes_choose_the_forward_kernel(case):
     text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).trace(q, kv, kv).lower(
         lowering_platforms=("tpu",)
     ).as_text(debug_info=True)
-    backward = "_flash_backward" if window is None else "_window_flash_backward"
-    assert set(re.findall(r'kernel_name = "([^"]+)"', text)) == {
-        forward, f"{backward}_dkv", f"{backward}_dq"
-    }
+    backward = (
+        {"_flash_backward_fused"} if window is None
+        else {"_window_flash_backward_dkv", "_window_flash_backward_dq"}
+    )
+    assert set(re.findall(r'kernel_name = "([^"]+)"', text)) == {forward} | backward

@@ -52,14 +52,16 @@ def test_a_block_runs_the_full_causal_forward_kernel_once_and_the_windowed_one_t
     calls = kernel_calls(jax.make_jaxpr(jax.grad(loss))(params, TOKENS).jaxpr)
     assert calls["_flash_forward"] == 2  # once a run: the parent traced it twice a run
     assert calls["_window_flash_forward_band"] == 2  # forward, and again for the backward
-    assert calls["_flash_backward_dkv"] == calls["_flash_backward_dq"] == 2
+    # a full-causal backward pass is the fused kernel, a windowed one the pair
+    assert calls["_flash_backward_fused"] == 2
+    assert calls["_flash_backward_dkv"] == calls["_flash_backward_dq"] == 0
     assert calls["_window_flash_backward_dkv"] == calls["_window_flash_backward_dq"] == 1
 
 
 @pytest.mark.parametrize("policy", ["full", "dots"])
 def test_llamas_block_keeps_the_pair_under_both_policies(policy, on_a_tpu):
     calls = kernel_calls(_llama_gradient(policy))
-    assert calls == {"_flash_forward": 1, "_flash_backward_dkv": 1, "_flash_backward_dq": 1}
+    assert calls == {"_flash_forward": 1, "_flash_backward_fused": 1}
 
 
 def test_the_pair_survives_shard_map_on_a_dp2_mesh(on_a_tpu):
@@ -119,7 +121,7 @@ def test_remat_keeps_saves_the_full_causal_pair_and_what_the_other_policy_saves(
 
 @pytest.mark.parametrize("window", [None, 16])
 def test_gradients_with_and_without_the_policy_are_bit_equal(window, monkeypatch):
-    for name in ("BWD_DKV_BLOCKS", "BWD_DQ_BLOCKS", "WINDOW_BWD_DKV_BLOCKS", "WINDOW_BWD_DQ_BLOCKS"):
+    for name in ("BWD_FUSED_BLOCKS", "WINDOW_BWD_DKV_BLOCKS", "WINDOW_BWD_DQ_BLOCKS"):
         monkeypatch.setattr(pallas_attention, name, (16, 16))
     block, x, w = _block(window)
 

@@ -349,7 +349,13 @@ def test_the_other_decoder_cells_steps_lower_to_the_parents_text(name, monkeypat
     three SwiGLU cells do not set, and the scan's kernels (PR 42) have one
     caller, this file's model: their whole steps (and the Mistral cell's,
     which has no experts) lower, for the TPU and at the cells' own sizes, to
-    what PR 41's parent's lowered to, kernel source locations apart."""
+    what PR 41's parent's lowered to, kernel source locations apart.  Since
+    PR 44 a full-causal backward pass at these cells' shapes is the fused
+    kernel; with no dq fitting its budget the calls run the pair again, and
+    the steps are still the parent's: nothing else of them moved."""
+    from deeplearning_cfn_tpu.ops import pallas_attention
+
+    monkeypatch.setattr(pallas_attention, "_BWD_FUSED_DQ_BUDGET", 0)
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     want, batch, seq_len = PARENT_STEPS[name]
     text = lowered_step_without_locations(name, batch, seq_len)
