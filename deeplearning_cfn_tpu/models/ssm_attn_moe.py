@@ -21,7 +21,10 @@ the routing counters).  What differs:
   `ops/ssd.ssd`, or `ops/pallas_ssd.ssd` where `pallas_ssd.takes_kernel` says
   so (a TPU, lane-aligned chunk, state and groups);
   ``y = RMSNorm_group(y * silu(z)) * w`` over each group's channels (the gate
-  first), then ``W_out``.
+  first), then ``W_out``.  The convolution with its SiLU and the gated norm
+  are `_conv_silu` and `_gate_norm`, or the fused kernels of
+  `ops/pallas_ssm_stages.py` where its rules say so (a TPU, channels and a
+  group whole lane tiles, whole row tiles).
 - **Attention** is causal GQA with no positional encoding and no bias.
 - **Latent experts.**  The router reads the normalised hidden state at full
   width; the experts read and write a latent of it (``latent_in`` d -> l, the
@@ -58,7 +61,7 @@ from deeplearning_cfn_tpu.models.mla_moe import (
     _head,
     _head_loss,
 )
-from deeplearning_cfn_tpu.ops import pallas_ssd
+from deeplearning_cfn_tpu.ops import pallas_ssd, pallas_ssm_stages
 from deeplearning_cfn_tpu.ops.attention import rms_norm
 from deeplearning_cfn_tpu.ops.moe import (
     RoutedConfig,
@@ -318,10 +321,12 @@ def _gate_norm(y: jax.Array, z: jax.Array, w: jax.Array, groups: int, eps: float
 
 
 def _ssm_mixer(cfg: SsmAttnMoeConfig, lp: dict, n: jax.Array) -> jax.Array:
-    """Mamba-2 on the normalised input n [B, S, d].  The two elementwise
-    stages are rematerialised by themselves: between the passes each holds its
-    inputs in the activations' type and not its float32 intermediates
-    (0.3 GB each a sequence of 8192 at these widths, several of each)."""
+    """Mamba-2 on the normalised input n [B, S, d].  Between the passes each of
+    the two elementwise stages holds its inputs in the activations' type and
+    not its float32 intermediates (0.3 GB each a sequence of 8192 at these
+    widths, several of each): as a fused kernel of `ops/pallas_ssm_stages.py`
+    where its rule takes the shapes and the backend, else as the jnp stage
+    rematerialised by itself."""
     B, S, _ = n.shape
     H, G, N = cfg.ssm_heads, cfg.ssm_groups, cfg.ssm_state
     with jax.named_scope("in_proj"):
@@ -329,7 +334,9 @@ def _ssm_mixer(cfg: SsmAttnMoeConfig, lp: dict, n: jax.Array) -> jax.Array:
             n @ lp["in_proj"], (cfg.ssm_inner, cfg.ssm_inner + cfg.ssm_conv_dim), axis=-1
         )
     with jax.named_scope("conv"):
-        xBC = jax.checkpoint(_conv_silu)(xBC, lp["conv_w"], lp["conv_bias"])
+        kernel = pallas_ssm_stages.takes_conv_kernel(xBC, lp["conv_w"])
+        conv = pallas_ssm_stages.conv_silu if kernel else jax.checkpoint(_conv_silu)
+        xBC = conv(xBC, lp["conv_w"], lp["conv_bias"])
     with jax.named_scope("scan"):
         x, Bm, Cm = jnp.split(xBC, (cfg.ssm_inner, cfg.ssm_inner + G * N), axis=-1)
         x, Bm, Cm = x.reshape(B, S, H, cfg.ssm_head_dim), Bm.reshape(B, S, G, N), Cm.reshape(B, S, G, N)
@@ -340,9 +347,11 @@ def _ssm_mixer(cfg: SsmAttnMoeConfig, lp: dict, n: jax.Array) -> jax.Array:
             Bm, Cm, lp["D"], cfg.chunk,
         )
     with jax.named_scope("gate_norm"):
-        y = jax.checkpoint(partial(_gate_norm, groups=G, eps=cfg.norm_eps))(
-            y.reshape(B, S, cfg.ssm_inner), z, lp["gate_norm"]
-        )
+        y = y.reshape(B, S, cfg.ssm_inner)
+        if pallas_ssm_stages.takes_gate_norm_kernel(y, z, G):
+            y = pallas_ssm_stages.gate_norm(y, z, lp["gate_norm"], G, cfg.norm_eps)
+        else:
+            y = jax.checkpoint(partial(_gate_norm, groups=G, eps=cfg.norm_eps))(y, z, lp["gate_norm"])
     with jax.named_scope("out_proj"):
         return y @ lp["out_proj"]
 

@@ -1,5 +1,6 @@
-"""The flash attention's kernels, the routed experts' grouped matmuls and the
-state-space scan's kernels compiled for a TPU v5e that is described, not attached, at the widths the chip
+"""The flash attention's kernels, the routed experts' grouped matmuls, the
+state-space scan's kernels and the Mamba-2 block's two elementwise stages'
+compiled for a TPU v5e that is described, not attached, at the widths the chip
 runs them: what the interpreter cannot show
 (a tile Mosaic refuses, more VMEM than a kernel may use).  Nothing runs, so
 nothing here says anything about results or times; chip_smoke.py does, on the
@@ -186,6 +187,52 @@ def test_the_state_space_scans_kernels_compile_for_v5e(case, one_chip):
     # the saved states and copies of x's size, under half of one pass's L.
     states, an_x = b * S // 128 * 128 * H * P * 4, b * S * H * P * jnp.dtype(dtype).itemsize
     assert compiled.memory_analysis().temp_size_in_bytes < states + 4 * an_x
+
+
+# The two elementwise stages of that cell's `M` block (PR 45): the convolution
+# over x, B and C side by side (8192 + 2 x 8 x 128 channels, 4 taps) and the
+# gated norm over 8 groups of 1024; and float32 at a small size, three taps, one
+# group.  (batch, seq, inner, groups, state, taps, dtype)
+SSM_STAGE_CASES = {
+    "nemotron-cell-s8192": (1, 8192, 8192, 8, 128, 4, jnp.bfloat16),
+    "float32-one-group-three-taps": (2, 1024, 512, 1, 128, 3, jnp.float32),
+}
+
+
+@pytest.mark.parametrize("stage", ["conv_silu", "gate_norm"])
+@pytest.mark.parametrize("case", SSM_STAGE_CASES)
+def test_the_ssm_blocks_elementwise_stages_kernels_compile_for_v5e(case, stage, one_chip):
+    from deeplearning_cfn_tpu.ops import pallas_ssm_stages as stages
+
+    b, S, inner, groups, state, taps, dtype = SSM_STAGE_CASES[case]
+    on_chip = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    f32 = jnp.float32
+    if stage == "conv_silu":
+        C = inner + 2 * groups * state
+        args = (on_chip((b, S, C), dtype), on_chip((taps, C), dtype), on_chip((C,), f32))
+        assert stages.takes_conv_kernel(*args[:2], backend="tpu")
+        call = stages.conv_silu
+    else:
+        args = (on_chip((b, S, inner), dtype), on_chip((b, S, inner), dtype), on_chip((inner,), f32))
+        assert stages.takes_gate_norm_kernel(*args[:2], groups, backend="tpu")
+        call = lambda y, z, w: stages.gate_norm(y, z, w, groups, 1e-5)
+
+    def grads(*args):
+        return jax.grad(lambda *a: call(*a).astype(f32).sum(), argnums=(0, 1, 2))(*args)
+
+    compiled = jax.jit(grads).lower(*args).compile()
+    text = compiled.as_text()
+    kernels = set(re.findall(r"_(?:conv_silu|gate_norm)_(?:forward|backward)", text))
+    # The gradient alone needs no forward call: the residuals are the inputs.
+    assert kernels == {f"_{stage}_backward"}
+    # Nothing float32 of the array's size outside the kernel: the temporaries
+    # are the cotangent and the partial sums, under one float32 copy of the array.
+    assert compiled.memory_analysis().temp_size_in_bytes < args[0].size * 4
+    both = jax.jit(lambda *a: (call(*a), grads(*a))).lower(*args).compile()
+    assert set(re.findall(r"_(?:conv_silu|gate_norm)_(?:forward|backward)", both.as_text())) == {
+        f"_{stage}_forward", f"_{stage}_backward"
+    }
+    assert both.memory_analysis().temp_size_in_bytes < args[0].size * 4
 
 
 def _mistral_cell_step(one_chip):

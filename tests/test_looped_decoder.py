@@ -335,8 +335,12 @@ def test_the_nemotron_cells_step_lowers_to_the_parents_text(monkeypatch):
     cell's and the three SwiGLU expert cells' whole steps to the text PR 41's
     parent lowered to, and this is the fifth decoder cell's, whose blocks are
     `models/ssm_attn_moe.py`'s own: sha256 of the step lowered for the TPU
-    from shapes alone at PR 43's parent (564913a), kernel source locations
-    apart."""
+    from shapes alone, kernel source locations apart.  Re-pinned by PR 45,
+    which changed this step on purpose (its `M` blocks' two elementwise stages
+    became the kernels of `ops/pallas_ssm_stages.py`): the constant is the step
+    of PR 45's tree (parent e03795d plus that change) and the next PR's guard;
+    through PR 44 it read eabf7f63...79817, the step at PR 43's parent
+    (564913a)."""
     import hashlib
     import json
     from pathlib import Path
@@ -362,4 +366,4 @@ def test_the_nemotron_cells_step_lowers_to_the_parents_text(monkeypatch):
         text = trainer.step_fn.trace(state, tokens, tokens).lower(
             lowering_platforms=("tpu",)).as_text()
     assert hashlib.sha256(text_without_kernel_locations(text).encode()).hexdigest() == (
-        "eabf7f638e624406a460a2f8f90bcdb9c6cdde19ae316e3a02848a1727179817")
+        "1ab8ea1d2df8f537dfc14b8d381cba1e839a3e9dd41719ecf3179dd66a530004")

@@ -296,6 +296,52 @@ def test_the_loss_and_gradients_with_the_scans_kernels_are_those_with_the_xla_fo
         assert np.linalg.norm(g - w) <= tolerance * max(np.linalg.norm(w), 1e-3), jax.tree_util.keystr(path)
 
 
+# --- the two elementwise stages' two forms through the model ----------------------------------
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["float32", "bfloat16"])
+def test_the_loss_and_gradients_with_the_stages_kernels_are_those_with_the_jnp_stages(dtype, monkeypatch):
+    """The same lane-aligned model through `lm_loss`, rematerialised as the
+    cell's: the mixer takes `ops/pallas_ssm_stages.py`'s `conv_silu` and
+    `gate_norm` where the rules say so (here the test says so, the Pallas
+    interpreter runs the kernels, four row tiles a sequence) and
+    `jax.checkpoint` of `_conv_silu` and `_gate_norm` elsewhere."""
+    from deeplearning_cfn_tpu.ops import pallas_ssm_stages as stages
+
+    cfg = model.SsmAttnMoeConfig.tiny(
+        ssm_heads=4, ssm_head_dim=64, ssm_groups=2, ssm_state=128, chunk=128, remat=True, dtype=dtype
+    )
+    params = model.init_params(cfg, jax.random.key(0))
+    tokens = jax.random.randint(jax.random.key(1), (1, 256), 0, cfg.vocab_size)
+    loss = lambda p: model.lm_loss(cfg, p, tokens, jnp.roll(tokens, -1, 1))[0]
+    with HIGHEST():
+        want, want_grads = jax.jit(jax.value_and_grad(loss))(params)
+        entered = []
+        monkeypatch.setattr(stages, "CONV_TILE", (64, 256))
+        monkeypatch.setattr(stages, "GATE_NORM_ROWS", 64)
+        for rule in ("takes_conv_kernel", "takes_gate_norm_kernel"):
+            monkeypatch.setattr(stages, rule, lambda *a, rule=rule, **k: entered.append(rule) or True)
+        for kernel in ("conv_silu", "gate_norm"):
+            monkeypatch.setattr(stages, kernel, partial(getattr(stages, kernel), interpret=True))
+        traced = jax.jit(jax.value_and_grad(loss)).trace(params)
+        got, got_grads = traced.lower().compile()(params)
+    assert set(entered) == {"takes_conv_kernel", "takes_gate_norm_kernel"}
+    # The pair `EM` is one run, its body traced once: each kernel forward in
+    # the first pass and in the block's rematerialised one, and once backward.
+    from tests.kernel_text import kernel_calls
+
+    calls = kernel_calls(traced.jaxpr)
+    assert {n: c for n, c in calls.items() if n.startswith(("_conv", "_gate"))} == {
+        "_conv_silu_forward": 2, "_conv_silu_backward": 1, "_gate_norm_forward": 2, "_gate_norm_backward": 1,
+    }
+    tolerance = 1e-4 if dtype == jnp.float32 else 2e-2
+    np.testing.assert_allclose(got, want, rtol=tolerance)
+    flat = lambda tree: jax.tree_util.tree_leaves_with_path(tree)
+    for (path, g), (_, w) in zip(flat(got_grads), flat(want_grads), strict=True):
+        g, w = np.asarray(g, np.float32), np.asarray(w, np.float32)
+        assert np.linalg.norm(g - w) <= tolerance * max(np.linalg.norm(w), 1e-3), jax.tree_util.keystr(path)
+
+
 # --- the other cells' steps are the parent's ----------------------------------------------
 
 # sha256 of each cell's train step as its builder makes it, lowered for the TPU
