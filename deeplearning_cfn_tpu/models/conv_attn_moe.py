@@ -2,10 +2,10 @@
 convolution or grouped-query attention with normalised heads, each followed
 by a dense SwiGLU or routed experts.  The block of LFM2 (`lfm2_moe`).
 
-Beside `models/llama.py` and `models/mla_moe.py`, and built from their parts
-(`attention_kind`, `attend`, `swiglu`, `ops/moe.routed_experts`, the embedding,
-the head with its rematerialised loss, the routing counters) where the block
-is the same; what differs is here:
+Built from `models/llama.py`'s parts (`attention_kind`, `attend`, `swiglu`),
+`ops/moe.routed_experts` and `models/decoder_stack.py`'s (runs of stacked
+weights, the embedding, the head with its rematerialised loss, the routing
+counters) where the block is the same; what differs is here:
 
 - **The layer pattern is data.**  `layer_types[i]` is ``conv`` or
   ``full_attention``; the first `n_dense_layers` layers have a dense
@@ -31,30 +31,25 @@ from __future__ import annotations
 
 from dataclasses import dataclass
 from functools import partial
-from itertools import groupby
 from typing import Any
 
 import jax
 import jax.numpy as jnp
-import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import Mesh, PartitionSpec as P
 
-from deeplearning_cfn_tpu.models.llama import (
-    BATCH_SPEC,
-    _FunctionalInit,
-    attend,
-    attention_kind,
-    swiglu,
+from deeplearning_cfn_tpu.models import decoder_stack
+from deeplearning_cfn_tpu.models.decoder_stack import (
+    checkpointed,
+    dense_init,
+    embed,
+    init_runs,
+    run_specs,
+    runs_of,
+    scan_runs,
 )
-from deeplearning_cfn_tpu.models.mla_moe import (
-    _checkpointed,
-    _counters,
-    _dense_init,
-    _embed,
-    _head,
-    _head_loss,
-)
+from deeplearning_cfn_tpu.models.llama import attend, attention_kind, swiglu
 from deeplearning_cfn_tpu.ops.attention import rms_norm, rotary_embedding
+from deeplearning_cfn_tpu.ops.conv import short_conv
 from deeplearning_cfn_tpu.ops.moe import (
     RoutedConfig,
     init_routed_params,
@@ -65,48 +60,6 @@ from deeplearning_cfn_tpu.ops.moe import (
 MIXERS = ("conv", "full_attention")
 # A run's kind: its mixer and whether its feed-forward is routed.
 Kind = tuple[str, bool]
-
-
-# --- the pattern as data: what any decoder whose layers differ in kind needs ---
-#
-# A layer's kind is whatever tuple decides its parameters' shapes and its
-# computation.  These four functions are all that knows about runs; this
-# module and models/window_attn_moe.py call them with their own kinds, block
-# parameters and block.
-
-
-def runs_of(kinds) -> tuple:
-    """Consecutive layers of one kind: ((kind, how many), ...)."""
-    return tuple((kind, len(list(group))) for kind, group in groupby(kinds))
-
-
-def init_runs(block_params, runs, key: jax.Array) -> list[dict]:
-    """One dict of stacked weights a run, in forward order;
-    ``block_params(key, kind)`` makes one block's."""
-    stacks = []
-    for (kind, n), run_key in zip(runs, jax.random.split(key, len(runs))):
-        blocks = [block_params(k, kind) for k in jax.random.split(run_key, n)]
-        stacks.append(jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *blocks))
-    return stacks
-
-
-def run_specs(block_specs, runs) -> list[dict]:
-    """``block_specs(kind)`` with a leading, never sharded, layer axis."""
-    is_spec = lambda x: isinstance(x, P)
-    stack = lambda tree: jax.tree_util.tree_map(lambda s: P(None, *s), tree, is_leaf=is_spec)
-    return [stack(block_specs(kind)) for kind, _ in runs]
-
-
-def scan_runs(block_of, runs, stacks: list[dict], x: jax.Array) -> tuple[jax.Array, list[dict]]:
-    """One `scan` a run, the runs in turn: ``block_of(kind)(x, lp)`` gives
-    (x, the routing's statistics or None).  Returns the last block's output
-    and each routed run's statistics stacked on its layer axis."""
-    stats = []
-    for (kind, _), stack in zip(runs, stacks, strict=True):
-        x, run_stats = jax.lax.scan(block_of(kind), x, stack)
-        if run_stats is not None:
-            stats.append(run_stats)
-    return x, stats
 
 
 @dataclass(frozen=True)
@@ -205,7 +158,7 @@ def _block_params(cfg: ConvAttnMoeConfig, key: jax.Array, kind: Kind) -> dict:
     mixer, routed = kind
     keys = jax.random.split(key, 8)
     d, hd = cfg.dim, cfg.head_dim
-    init = partial(_dense_init, dtype=cfg.dtype)
+    init = partial(dense_init, dtype=cfg.dtype)
     params = {
         "operator_norm": jnp.ones((d,), jnp.float32),
         "ffn_norm": jnp.ones((d,), jnp.float32),
@@ -233,7 +186,7 @@ def _block_params(cfg: ConvAttnMoeConfig, key: jax.Array, kind: Kind) -> dict:
 def init_params(cfg: ConvAttnMoeConfig, rng: jax.Array) -> dict:
     k_embed, k_runs = jax.random.split(rng)
     return {
-        "embed": _dense_init(k_embed, (cfg.vocab_size, cfg.dim), cfg.dim, cfg.dtype),
+        "embed": dense_init(k_embed, (cfg.vocab_size, cfg.dim), cfg.dim, cfg.dtype),
         "final_norm": jnp.ones((cfg.dim,), jnp.float32),
         "runs": init_runs(partial(_block_params, cfg), cfg.runs, k_runs),
     }
@@ -267,15 +220,11 @@ def param_specs(cfg: ConvAttnMoeConfig) -> dict:
 
 
 def param_shardings(cfg: ConvAttnMoeConfig, mesh: Mesh) -> dict:
-    return jax.tree_util.tree_map(
-        lambda spec: NamedSharding(mesh, spec), param_specs(cfg),
-        is_leaf=lambda x: isinstance(x, P),
-    )
+    return decoder_stack.shardings(param_specs(cfg), mesh)
 
 
 def param_count(cfg: ConvAttnMoeConfig) -> int:
-    shapes = jax.eval_shape(partial(init_params, cfg), jax.random.key(0))
-    return sum(int(np.prod(x.shape)) for x in jax.tree_util.tree_leaves(shapes))
+    return decoder_stack.count(cfg, init_params)
 
 
 def train_flops_per_token(cfg: ConvAttnMoeConfig, seq_len: int) -> float:
@@ -299,14 +248,6 @@ def train_flops_per_token(cfg: ConvAttnMoeConfig, seq_len: int) -> float:
 
 
 # --- forward ------------------------------------------------------------
-
-
-def short_conv(z: jax.Array, w: jax.Array) -> jax.Array:
-    """The depthwise causal convolution: z [B, S, d], w [L, d] ->
-    c[:, t] = sum_j w[j] * z[:, t - (L - 1) + j], zeros before the start."""
-    L, S = w.shape[0], z.shape[1]
-    padded = jnp.pad(z, ((0, 0), (L - 1, 0), (0, 0)))
-    return sum(w[j] * padded[:, j : j + S] for j in range(L))
 
 
 def _conv_mixer(lp: dict, h: jax.Array) -> jax.Array:
@@ -372,9 +313,9 @@ def hidden_states(
     """tokens [B, S] -> (the last block's output before the final norm
     [B, S, d], each routed run's statistics stacked on its layer axis)."""
     with jax.named_scope("embed"):
-        x = _embed(cfg, params, tokens)
+        x = embed(cfg, params, tokens)
     positions = jnp.arange(tokens.shape[1], dtype=jnp.int32)
-    block = _checkpointed(cfg, partial(_block, cfg, mesh))
+    block = checkpointed(cfg, partial(_block, cfg, mesh))
     # One block for every kind: it reads a layer's kind off the leaves it is given.
     return scan_runs(lambda kind: lambda x, lp: block(x, lp, positions), cfg.runs, params["runs"], x)
 
@@ -385,14 +326,11 @@ def lm_loss(
 ) -> tuple[jax.Array, dict]:
     """Next-token cross-entropy; `targets[i]` is the token that follows
     `tokens[i]` (the last one wrapped, and masked).  The head with its loss
-    is rematerialised, as `mla_moe.lm_loss`'s."""
+    is rematerialised (`decoder_stack.next_token_loss`)."""
     x, stats = hidden_states(cfg, params, tokens, mesh)
-    head_loss = _checkpointed(cfg, partial(_head_loss, cfg))
-    loss = head_loss(params["final_norm"], params["embed"].T, x, targets, ahead=1)
-    metrics = {"perplexity": jnp.exp(loss)}
-    if stats:
-        metrics["counters"] = _counters(cfg, stats)
-    return loss, metrics
+    return decoder_stack.next_token_loss(
+        cfg, params["final_norm"], params["embed"].T, x, targets, stats
+    )
 
 
 def logits(
@@ -401,26 +339,12 @@ def logits(
     """float32 logits and each routed block's selection [blocks, T, k]: the
     inspection entry point, not the train hot path."""
     x, stats = hidden_states(cfg, params, tokens, mesh)
-    out = {
-        "main": _head(cfg, params["final_norm"], params["embed"].T, x).astype(jnp.float32)
-    }
-    if stats:
-        out["selected"] = jnp.concatenate([s["selected"] for s in stats])
-    return out
+    return decoder_stack.inspect_logits(cfg, params["final_norm"], params["embed"].T, x, stats)
 
 
 def make_trainer(cfg: ConvAttnMoeConfig, mesh: Mesh, trainer_config) -> Any:
     """The generic SPMD Trainer on this model, as `llama.make_trainer`."""
-    from deeplearning_cfn_tpu.train.trainer import Trainer
-
-    return Trainer(
-        _FunctionalInit(cfg, init_params),
-        mesh,
-        trainer_config,
-        loss_fn=lambda p, x, y: lm_loss(cfg, p, x, y, mesh),
-        param_shardings=param_shardings(cfg, mesh),
-        batch_spec=BATCH_SPEC,
-        analytic_flops_fn=lambda x: (
-            train_flops_per_token(cfg, x.shape[1]) * x.shape[0] * x.shape[1]
-        ),
+    return decoder_stack.make_trainer(
+        cfg, mesh, trainer_config, init_params=init_params, lm_loss=lm_loss,
+        param_specs=param_specs, train_flops_per_token=train_flops_per_token,
     )

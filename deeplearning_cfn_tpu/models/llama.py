@@ -29,16 +29,15 @@ from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
-import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import Mesh, PartitionSpec as P
 
+from deeplearning_cfn_tpu.models import decoder_stack
+from deeplearning_cfn_tpu.models.decoder_stack import embed, head, remat_keeps, token_nll
 from deeplearning_cfn_tpu.ops.attention import (
     dot_product_attention,
     rms_norm,
     rotary_embedding,
 )
-
-BATCH_SPEC = P(("dp", "fsdp"), "sp")  # [batch, seq] token arrays
 
 
 @dataclass(frozen=True)
@@ -226,10 +225,7 @@ def init_params(cfg: LlamaConfig, rng: jax.Array) -> dict:
     d, hd = cfg.dim, cfg.head_dim
     L = cfg.n_layers
 
-    def dense_init(key, shape, fan_in):
-        return (jax.random.normal(key, shape, jnp.float32) / np.sqrt(fan_in)).astype(
-            cfg.dtype
-        )
+    dense_init = partial(decoder_stack.dense_init, dtype=cfg.dtype)
 
     layers: dict = {
         "attn_norm": jnp.ones((L, d), jnp.float32),
@@ -308,11 +304,7 @@ def param_specs(cfg: LlamaConfig) -> dict:
 
 
 def param_shardings(cfg: LlamaConfig, mesh: Mesh) -> dict:
-    return jax.tree_util.tree_map(
-        lambda spec: NamedSharding(mesh, spec),
-        param_specs(cfg),
-        is_leaf=lambda x: isinstance(x, P),
-    )
+    return decoder_stack.shardings(param_specs(cfg), mesh)
 
 
 def active_param_count(cfg: LlamaConfig) -> int:
@@ -340,16 +332,10 @@ def train_flops_per_token(cfg: LlamaConfig, seq_len: int) -> float:
 
 
 def param_count(cfg: LlamaConfig) -> int:
-    return sum(
-        int(np.prod(x.shape)) for x in jax.tree_util.tree_leaves(
-            jax.eval_shape(partial(init_params, cfg), jax.random.key(0))
-        )
-    )
+    return decoder_stack.count(cfg, init_params)
 
 
 # --- forward ------------------------------------------------------------
-
-from deeplearning_cfn_tpu.parallel.sharding import maybe_shard as _maybe_shard
 
 
 def attention_kind(
@@ -396,26 +382,6 @@ def attend(
     # (docs/BENCH_NOTES.md): use_flash means "fastest memory-safe
     # attention", not "always Pallas".
     return dot_product_attention(q, k, v, causal=True, window=window)
-
-
-def remat_keeps(also: Callable[..., Any] | None = None) -> Callable[..., Any]:
-    """The `jax.checkpoint` policy of every rematerialised decoder block (this
-    module's, and through `mla_moe._checkpointed` the three expert decoders'):
-    a full-causal flash call's `out` and `lse` are kept, layers x [B, S, H, D]
-    in the compute dtype and a float32 a row, so that the quadratic forward
-    kernel runs once a step and not again for the backward pass; everything
-    else is recomputed, or saved where ``also`` (another policy) says.  The
-    policy finds the pair by its names: a block without such a call (ring or
-    XLA attention, a head and its loss) keeps nothing.  A windowed call's pair
-    is named too and NOT kept: its band step is 2.93 ms a call where the
-    full-causal kernel is 15.36 (the Laguna cell), for more bytes of `out`
-    (64 heads against 48)."""
-    from deeplearning_cfn_tpu.ops.pallas_attention import FLASH_RESIDUALS
-
-    keeps = jax.checkpoint_policies.save_only_these_names(*FLASH_RESIDUALS)
-    if also is None:
-        return keeps
-    return jax.checkpoint_policies.save_from_both_policies(keeps, also)
 
 
 def swiglu(h: jax.Array, w_gate: jax.Array, w_up: jax.Array, w_down: jax.Array) -> jax.Array:
@@ -500,12 +466,8 @@ def head_logits(cfg: LlamaConfig, params: dict, x: jax.Array) -> jax.Array:
     over the vocab convert inside their reductions (exact: bf16 -> f32 is
     lossless), so loss numerics are identical to an f32 materialization;
     the decode and serve programs cast what they sample from."""
-    with jax.named_scope("final_norm"):
-        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    with jax.named_scope("head"):
-        if cfg.tied_embeddings:
-            return x @ params["embed"].astype(cfg.dtype).T
-        return x @ params["output"]
+    output = params["embed"].astype(cfg.dtype).T if cfg.tied_embeddings else params["output"]
+    return head(cfg, params["final_norm"], output, x)
 
 
 def forward_with_aux(
@@ -518,19 +480,8 @@ def forward_with_aux(
     configs) — added to the training objective, excluded from perplexity.
     """
     B, S = tokens.shape
-    # The stored table is P("tp", "fsdp"); gathering from it directly makes
-    # the lookup output emb-sharded over fsdp, and GSPMD cannot reshard
-    # {emb: fsdp} -> {batch: fsdp, seq: sp} without replicating the whole
-    # activation ("involuntary full rematerialization", the round-1 dryrun
-    # warning).  Constraining the bf16 working copy to P("tp", None) keeps
-    # vocab sharded (the large axis) while the gather output inherits the
-    # token sharding (batch over dp/fsdp, seq over sp) plus an unsharded
-    # emb axis — exactly the activation layout, so the constraint below is
-    # a no-op instead of a blocking reshard.
     with jax.named_scope("embed"):
-        table = _maybe_shard(params["embed"].astype(cfg.dtype), P("tp", None))
-        x = table[tokens]
-        x = _maybe_shard(x, P(("dp", "fsdp"), "sp", None))
+        x = embed(cfg, params, tokens)
     positions = jnp.arange(S, dtype=jnp.int32)
 
     def own_batch(q, k, v):
@@ -588,37 +539,12 @@ def forward(
     return forward_with_aux(cfg, params, tokens, mesh)[0].astype(jnp.float32)
 
 
-class _FunctionalInit:
-    """Adapter giving the functional model the tiny surface Trainer.init
-    expects (a flax-style ``init`` returning {"params": ...})."""
-
-    def __init__(self, cfg: Any, init_fn=None):
-        self.cfg = cfg
-        self.init_fn = init_fn or init_params
-
-    def init(self, rng: jax.Array, sample: jax.Array) -> dict:
-        del sample
-        return {"params": self.init_fn(self.cfg, rng)}
-
-
 def make_trainer(cfg: LlamaConfig, mesh: Mesh, trainer_config) -> Any:
     """Wire a Llama config into the generic SPMD Trainer: explicit 2D
     param shardings, token batch sharded over (dp/fsdp, sp), causal-LM loss."""
-    from deeplearning_cfn_tpu.train.trainer import Trainer
-
-    return Trainer(
-        _FunctionalInit(cfg),
-        mesh,
-        trainer_config,
-        loss_fn=lambda p, x, y: causal_lm_loss(cfg, p, x, y, mesh),
-        param_shardings=param_shardings(cfg, mesh),
-        batch_spec=BATCH_SPEC,
-        # Analytic 6N numerator: flash attention runs in a Pallas custom
-        # call whose FLOPs XLA cost analysis cannot see, so every MFU
-        # consumer must use this instead (docs/BENCH_NOTES.md).
-        analytic_flops_fn=lambda x: (
-            train_flops_per_token(cfg, x.shape[1]) * x.shape[0] * x.shape[1]
-        ),
+    return decoder_stack.make_trainer(
+        cfg, mesh, trainer_config, init_params=init_params, lm_loss=causal_lm_loss,
+        param_specs=param_specs, train_flops_per_token=train_flops_per_token,
     )
 
 
@@ -633,16 +559,8 @@ def causal_lm_loss(
     target wraps to the sequence start).  MoE configs add the router
     load-balancing aux loss to the objective (not to perplexity)."""
     logits, aux = forward_with_aux(cfg, params, tokens, mesh)
-    # Logsumexp form of -log_softmax[target]: nll = lse(logits) - gold.
-    # Identical math to log_softmax-then-gather, but the [B, S, V] tensor
-    # is only ever READ by reductions (XLA fuses the bf16->f32 convert
-    # into them) instead of materialized as an f32 copy plus a full-width
-    # f32 log_softmax — at V=32k that materialization was ~28% of the
-    # 435M training step (docs/BENCH_NOTES.md round-3 trace).
     with jax.named_scope("xent"):
-        lse = jax.scipy.special.logsumexp(logits.astype(jnp.float32), axis=-1)
-        gold = jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
-        nll = lse - gold.astype(jnp.float32)
+        nll = token_nll(logits, targets)
         mask = jnp.ones_like(nll).at[:, -1].set(0.0)
         loss = jnp.sum(nll * mask) / jnp.sum(mask)
     metrics = {"perplexity": jnp.exp(loss)}

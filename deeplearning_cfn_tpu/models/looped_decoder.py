@@ -5,9 +5,9 @@ Latent Reasoning via Looped Language Models", arXiv:2510.25741).
 
 Beside `models/llama.py`, and built from its parts: `decoder_block` is the
 block (the two post-norms are leaves it finds in the layer's tree),
-`attend` / `attention_kind` the attention, `remat_keeps` the policy of a
-rematerialised block; the embedding and the checkpoint wrapper are
-`models/mla_moe.py`'s.  What differs is here:
+`attend` / `attention_kind` the attention; the embedding, the checkpoint
+wrapper with its policy and a token's cross-entropy are
+`models/decoder_stack.py`'s.  What differs is here:
 
 - **The loop.**  h = E[tokens]; `passes` times: h through the L blocks in
   turn, n = RMSNorm_f(h), logits = n W_out, and the next pass starts from n.
@@ -41,18 +41,11 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import Mesh, PartitionSpec as P
 
-from deeplearning_cfn_tpu.models import llama
-from deeplearning_cfn_tpu.models.llama import (
-    BATCH_SPEC,
-    LlamaConfig,
-    _FunctionalInit,
-    attend,
-    attention_kind,
-    decoder_block,
-)
-from deeplearning_cfn_tpu.models.mla_moe import _checkpointed, _embed
+from deeplearning_cfn_tpu.models import decoder_stack, llama
+from deeplearning_cfn_tpu.models.decoder_stack import checkpointed, embed, token_nll
+from deeplearning_cfn_tpu.models.llama import LlamaConfig, attend, attention_kind, decoder_block
 from deeplearning_cfn_tpu.ops.attention import rms_norm
 
 POST_NORMS = ("attn_post_norm", "mlp_post_norm")
@@ -109,15 +102,11 @@ def param_specs(cfg: LoopedDecoderConfig) -> dict:
 
 
 def param_shardings(cfg: LoopedDecoderConfig, mesh: Mesh) -> dict:
-    return jax.tree_util.tree_map(
-        lambda spec: NamedSharding(mesh, spec), param_specs(cfg),
-        is_leaf=lambda x: isinstance(x, P),
-    )
+    return decoder_stack.shardings(param_specs(cfg), mesh)
 
 
 def param_count(cfg: LoopedDecoderConfig) -> int:
-    shapes = jax.eval_shape(partial(init_params, cfg), jax.random.key(0))
-    return sum(int(np.prod(x.shape)) for x in jax.tree_util.tree_leaves(shapes))
+    return decoder_stack.count(cfg, init_params)
 
 
 def train_flops_per_token(cfg: LoopedDecoderConfig, seq_len: int) -> float:
@@ -150,10 +139,7 @@ def _pass_head(
     with jax.named_scope("head"):
         logits = n @ top["output"]
     with jax.named_scope("xent"):
-        # llama.causal_lm_loss's form: the logits are read by reductions only.
-        lse = jax.scipy.special.logsumexp(logits.astype(jnp.float32), axis=-1)
-        gold = jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
-        nll = lse - gold.astype(jnp.float32)
+        nll = token_nll(logits, targets)
     with jax.named_scope("exit_gate"):
         # float32 with a full-precision product, as a router's scores: one
         # output a token, and what the passes' losses are weighed by.
@@ -172,20 +158,20 @@ def _passes(
     dec = cfg.decoder
     S = tokens.shape[1]
     with jax.named_scope("embed"):
-        x = _embed(dec, params, tokens)
+        x = embed(dec, params, tokens)
     positions = jnp.arange(S, dtype=jnp.int32)
 
     def own_batch(q, k, v):
         return attend(attention_kind(dec, mesh, S), q, k, v, mesh), None
 
-    block = _checkpointed(dec, partial(decoder_block, dec, own_batch))
+    block = checkpointed(dec, partial(decoder_block, dec, own_batch))
     top = {k: v for k, v in params.items() if k not in ("embed", "layers")}
 
     def unit(top, h):
         n, logits, nll, z = _pass_head(dec, top, h, targets)
         return n, (nll, z, logits) if keep_logits else (nll, z)
 
-    unit = _checkpointed(dec, unit)
+    unit = checkpointed(dec, unit)
 
     def one_pass(h, _):
         with jax.named_scope("loop_pass"):
@@ -248,16 +234,7 @@ def logits(
 
 def make_trainer(cfg: LoopedDecoderConfig, mesh: Mesh, trainer_config) -> Any:
     """The generic SPMD Trainer on this model, as `llama.make_trainer`."""
-    from deeplearning_cfn_tpu.train.trainer import Trainer
-
-    return Trainer(
-        _FunctionalInit(cfg, init_params),
-        mesh,
-        trainer_config,
-        loss_fn=lambda p, x, y: lm_loss(cfg, p, x, y, mesh),
-        param_shardings=param_shardings(cfg, mesh),
-        batch_spec=BATCH_SPEC,
-        analytic_flops_fn=lambda x: (
-            train_flops_per_token(cfg, x.shape[1]) * x.shape[0] * x.shape[1]
-        ),
+    return decoder_stack.make_trainer(
+        cfg, mesh, trainer_config, init_params=init_params, lm_loss=lm_loss,
+        param_specs=param_specs, train_flops_per_token=train_flops_per_token,
     )

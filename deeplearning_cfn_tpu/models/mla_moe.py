@@ -2,8 +2,9 @@
 DeepSeek-V3 (arXiv:2412.19437), which `glm4_moe_lite` follows key for key.
 
 Beside `models/llama.py`, and built from its parts (`attention_kind`,
-`attend`, `swiglu`, the stacked-layer layout, scan + `jax.checkpoint`, the
-logsumexp cross-entropy) where the block is the same; what differs is here:
+`attend`, `swiglu`, the stacked-layer layout) and from
+`models/decoder_stack.py`'s (the embedding, the head with its rematerialised
+loss, the checkpoint wrapper) where the block is the same; what differs is here:
 
 - **Latent attention (MLA).**  Queries go through a low-rank pair
   (`wq_a`, RMSNorm, `wq_b`); keys and values are expanded from one
@@ -37,25 +38,19 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
-import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import Mesh, PartitionSpec as P
 
-from deeplearning_cfn_tpu.models.llama import (
-    BATCH_SPEC,
-    _FunctionalInit,
-    attend,
-    attention_kind,
-    remat_keeps,
-    swiglu,
-)
+from deeplearning_cfn_tpu.models import decoder_stack
+from deeplearning_cfn_tpu.models.decoder_stack import checkpointed, dense_init, embed, head, head_loss
+from deeplearning_cfn_tpu.models.llama import attend, attention_kind, swiglu
 from deeplearning_cfn_tpu.ops.attention import rms_norm, rotary_embedding
 from deeplearning_cfn_tpu.ops.moe import (
     RoutedConfig,
     init_routed_params,
     routed_experts,
     routed_param_specs,
+    routing_counters,
 )
-from deeplearning_cfn_tpu.parallel.sharding import maybe_shard as _maybe_shard
 
 
 @dataclass(frozen=True)
@@ -138,14 +133,10 @@ class MlaMoeConfig:
 # --- parameters ---------------------------------------------------------
 
 
-def _dense_init(key, shape, fan_in, dtype):
-    return (jax.random.normal(key, shape, jnp.float32) / np.sqrt(fan_in)).astype(dtype)
-
-
 def _attention_params(cfg: MlaMoeConfig, key: jax.Array) -> dict:
     keys = jax.random.split(key, 5)
     d, H = cfg.dim, cfg.n_heads
-    init = partial(_dense_init, dtype=cfg.dtype)
+    init = partial(dense_init, dtype=cfg.dtype)
     return {
         "attn_norm": jnp.ones((d,), jnp.float32),
         "wq_a": init(keys[0], (d, cfg.q_lora_rank), d),
@@ -169,7 +160,7 @@ def _block_params(cfg: MlaMoeConfig, key: jax.Array, routed: bool) -> dict:
         params["moe"] = init_routed_params(cfg.routed, k_ff, cfg.dim, cfg.expert_dim, cfg.dtype)
     else:
         keys = jax.random.split(k_ff, 3)
-        init = partial(_dense_init, dtype=cfg.dtype)
+        init = partial(dense_init, dtype=cfg.dtype)
         params["w_gate"] = init(keys[0], (cfg.dim, cfg.mlp_dim), cfg.dim)
         params["w_up"] = init(keys[1], (cfg.dim, cfg.mlp_dim), cfg.dim)
         params["w_down"] = init(keys[2], (cfg.mlp_dim, cfg.dim), cfg.mlp_dim)
@@ -185,8 +176,8 @@ def init_params(cfg: MlaMoeConfig, rng: jax.Array) -> dict:
     keys = jax.random.split(rng, 6)
     d = cfg.dim
     params = {
-        "embed": _dense_init(keys[0], (cfg.vocab_size, d), d, cfg.dtype),
-        "output": _dense_init(keys[1], (d, cfg.vocab_size), d, cfg.dtype),
+        "embed": dense_init(keys[0], (cfg.vocab_size, d), d, cfg.dtype),
+        "output": dense_init(keys[1], (d, cfg.vocab_size), d, cfg.dtype),
         "final_norm": jnp.ones((d,), jnp.float32),
         "layers": _stacked(cfg, keys[3], cfg.n_routed_layers, routed=True),
     }
@@ -196,7 +187,7 @@ def init_params(cfg: MlaMoeConfig, rng: jax.Array) -> dict:
         params["mtp"] = {
             "hidden_norm": jnp.ones((d,), jnp.float32),
             "embed_norm": jnp.ones((d,), jnp.float32),
-            "join": _dense_init(keys[4], (2 * d, d), 2 * d, cfg.dtype),
+            "join": dense_init(keys[4], (2 * d, d), 2 * d, cfg.dtype),
             "block": _block_params(cfg, keys[5], routed=True),
             "final_norm": jnp.ones((d,), jnp.float32),
         }
@@ -239,15 +230,11 @@ def param_specs(cfg: MlaMoeConfig) -> dict:
 
 
 def param_shardings(cfg: MlaMoeConfig, mesh: Mesh) -> dict:
-    return jax.tree_util.tree_map(
-        lambda spec: NamedSharding(mesh, spec), param_specs(cfg),
-        is_leaf=lambda x: isinstance(x, P),
-    )
+    return decoder_stack.shardings(param_specs(cfg), mesh)
 
 
 def param_count(cfg: MlaMoeConfig) -> int:
-    shapes = jax.eval_shape(partial(init_params, cfg), jax.random.key(0))
-    return sum(int(np.prod(x.shape)) for x in jax.tree_util.tree_leaves(shapes))
+    return decoder_stack.count(cfg, init_params)
 
 
 def train_flops_per_token(cfg: MlaMoeConfig, seq_len: int) -> float:
@@ -335,26 +322,15 @@ def _block(
         return x + swiglu(h, lp["w_gate"], lp["w_up"], lp["w_down"]), None
 
 
-def _checkpointed(cfg: MlaMoeConfig, fn):
-    return jax.checkpoint(fn, policy=remat_keeps()) if cfg.remat else fn
-
-
 def _scan_blocks(cfg, mesh, x, stack, positions):
     """The stack's blocks in turn, each rematerialised; the routing's
     statistics stacked on the layer axis (None for dense blocks)."""
-    block = _checkpointed(cfg, partial(_block, cfg, mesh))
+    block = checkpointed(cfg, partial(_block, cfg, mesh))
 
     def body(x, lp):
         return block(x, lp, positions)
 
     return jax.lax.scan(body, x, stack)
-
-
-def _embed(cfg: MlaMoeConfig, params: dict, tokens: jax.Array) -> jax.Array:
-    # The working copy keeps the vocabulary sharded and the gather's output
-    # in the activations' layout (llama.forward_with_aux has the reasoning).
-    table = _maybe_shard(params["embed"].astype(cfg.dtype), P("tp", None))
-    return _maybe_shard(table[tokens], P(("dp", "fsdp"), "sp", None))
 
 
 def hidden_states(
@@ -363,7 +339,7 @@ def hidden_states(
     """tokens [B, S] -> (the last block's output before the final norm
     [B, S, d], the routed blocks' statistics stacked [L, ...])."""
     with jax.named_scope("embed"):
-        x = _embed(cfg, params, tokens)
+        x = embed(cfg, params, tokens)
     positions = jnp.arange(tokens.shape[1], dtype=jnp.int32)
     if cfg.n_dense_layers:
         x, _ = _scan_blocks(cfg, mesh, x, params["dense"], positions)
@@ -381,55 +357,14 @@ def _predicted(
         joined = jnp.concatenate(
             [
                 rms_norm(x, mtp["hidden_norm"], cfg.norm_eps),
-                rms_norm(_embed(cfg, params, next_tokens), mtp["embed_norm"], cfg.norm_eps),
+                rms_norm(embed(cfg, params, next_tokens), mtp["embed_norm"], cfg.norm_eps),
             ],
             axis=-1,
         )
         h = joined @ mtp["join"]
     with jax.named_scope("block"):
-        block = _checkpointed(cfg, partial(_block, cfg, mesh))
+        block = checkpointed(cfg, partial(_block, cfg, mesh))
         return block(h, mtp["block"], jnp.arange(x.shape[1], dtype=jnp.int32))
-
-
-def _head(cfg: MlaMoeConfig, norm: jax.Array, output: jax.Array, x: jax.Array) -> jax.Array:
-    """Logits in the compute type (llama.forward_with_aux says why)."""
-    with jax.named_scope("final_norm"):
-        x = rms_norm(x, norm, cfg.norm_eps)
-    with jax.named_scope("head"):
-        return x @ output
-
-
-def _head_loss(cfg, norm, output, x, targets, ahead: int) -> jax.Array:
-    """Mean cross-entropy of `targets` over the positions that have one:
-    the last `ahead` of a sequence hold a wrapped token and are left out."""
-    logits = _head(cfg, norm, output, x)
-    with jax.named_scope("xent"):
-        lse = jax.scipy.special.logsumexp(logits.astype(jnp.float32), axis=-1)
-        gold = jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
-        nll = lse - gold.astype(jnp.float32)
-        mask = (jnp.arange(targets.shape[1]) < targets.shape[1] - ahead).astype(jnp.float32)
-        return jnp.sum(nll * mask) / (targets.shape[0] * jnp.sum(mask))
-
-
-def _counters(cfg: MlaMoeConfig, stats: list[dict]) -> dict:
-    """The step's routing statistics over every routed block (the prediction
-    module's too) as the scalars the trainer folds into `obs.tracing`
-    counters: sums of assignments and of the buffer's rows the layers' passes
-    ran over, the largest and the mean load of a held expert, and what was
-    dropped."""
-    every = {
-        k: jnp.concatenate([jnp.atleast_1d(s[k]) for s in stats])
-        for k in ("assignments", "assignments_held", "rows_run", "load_max", "dropped")
-    }
-    held = jnp.sum(every["assignments_held"])
-    return {
-        "moe.assignments": jnp.sum(every["assignments"]),
-        "moe.assignments_held": held,
-        "moe.rows_run": jnp.sum(every["rows_run"]),
-        "moe.expert_load_max": jnp.max(every["load_max"]),
-        "moe.expert_load_mean": held / (every["load_max"].shape[0] * cfg.routed.span[1]),
-        "moe.dropped": jnp.sum(every["dropped"]),
-    }
 
 
 def lm_loss(
@@ -442,21 +377,21 @@ def lm_loss(
     is rematerialised: two sets of logits and one gradient of them are
     larger than everything else the backward pass keeps."""
     x, stats = hidden_states(cfg, params, tokens, mesh)
-    head_loss = _checkpointed(cfg, partial(_head_loss, cfg))
-    loss = main = head_loss(params["final_norm"], params["output"], x, targets, ahead=1)
+    one_head = checkpointed(cfg, partial(head_loss, cfg))
+    loss = main = one_head(params["final_norm"], params["output"], x, targets, ahead=1)
     stats = [stats]
     metrics = {"perplexity": jnp.exp(main)}
     if cfg.n_predict:
         with jax.named_scope("mtp"):
             h, mtp_stats = _predicted(cfg, params, x, targets, mesh)
-            mtp = head_loss(
+            mtp = one_head(
                 params["mtp"]["final_norm"], params["output"], h,
                 jnp.roll(targets, -1, axis=1), ahead=2,
             )
         stats.append(mtp_stats)
         loss = main + cfg.mtp_loss_weight * mtp
         metrics["mtp_loss"] = mtp
-    metrics["counters"] = _counters(cfg, stats)
+    metrics["counters"] = routing_counters(cfg.routed, stats)
     return loss, metrics
 
 
@@ -469,12 +404,12 @@ def logits(
     inspection entry point, not the train hot path."""
     x, stats = hidden_states(cfg, params, tokens, mesh)
     out = {
-        "main": _head(cfg, params["final_norm"], params["output"], x).astype(jnp.float32),
+        "main": head(cfg, params["final_norm"], params["output"], x).astype(jnp.float32),
         "selected": stats["selected"],
     }
     if cfg.n_predict and targets is not None:
         h, mtp_stats = _predicted(cfg, params, x, targets, mesh)
-        out["mtp"] = _head(cfg, params["mtp"]["final_norm"], params["output"], h).astype(
+        out["mtp"] = head(cfg, params["mtp"]["final_norm"], params["output"], h).astype(
             jnp.float32
         )
         out["selected"] = jnp.concatenate([out["selected"], mtp_stats["selected"][None]])
@@ -483,16 +418,7 @@ def logits(
 
 def make_trainer(cfg: MlaMoeConfig, mesh: Mesh, trainer_config) -> Any:
     """The generic SPMD Trainer on this model, as `llama.make_trainer`."""
-    from deeplearning_cfn_tpu.train.trainer import Trainer
-
-    return Trainer(
-        _FunctionalInit(cfg, init_params),
-        mesh,
-        trainer_config,
-        loss_fn=lambda p, x, y: lm_loss(cfg, p, x, y, mesh),
-        param_shardings=param_shardings(cfg, mesh),
-        batch_spec=BATCH_SPEC,
-        analytic_flops_fn=lambda x: (
-            train_flops_per_token(cfg, x.shape[1]) * x.shape[0] * x.shape[1]
-        ),
+    return decoder_stack.make_trainer(
+        cfg, mesh, trainer_config, init_params=init_params, lm_loss=lm_loss,
+        param_specs=param_specs, train_flops_per_token=train_flops_per_token,
     )

@@ -2,11 +2,11 @@
 *one* part, a Mamba-2 mixer, grouped-query attention or routed latent experts,
 by a pattern string.  The stack of Nemotron-H (`nemotron_h`).
 
-Beside `models/conv_attn_moe.py`, whose run machinery it calls (`init_runs`,
-`run_specs`, `scan_runs`), and built from the other decoders' parts where the
-part is the same (`attention_kind`, `attend`, `short_conv`,
-`ops/moe.routed_experts`, the embedding, the head with its rematerialised loss,
-the routing counters).  What differs:
+On `models/decoder_stack.py`'s run machinery (`init_runs`, `run_specs`,
+`scan_runs`), and built from the shared parts where the part is the same
+(`llama.attention_kind`, `attend`, `ops/conv.short_conv`,
+`ops/moe.routed_experts`, `decoder_stack`'s embedding, head with its
+rematerialised loss and routing counters).  What differs:
 
 - **The pattern is a string** over ``M`` (Mamba-2), ``*`` (attention) and
   ``E`` (experts), `hybrid_override_pattern`.  A run of single blocks would
@@ -48,21 +48,21 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
-import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import Mesh, PartitionSpec as P
 
-from deeplearning_cfn_tpu.models.conv_attn_moe import init_runs, run_specs, scan_runs, short_conv
-from deeplearning_cfn_tpu.models.llama import BATCH_SPEC, _FunctionalInit, attend, attention_kind
-from deeplearning_cfn_tpu.models.mla_moe import (
-    _checkpointed,
-    _counters,
-    _dense_init,
-    _embed,
-    _head,
-    _head_loss,
+from deeplearning_cfn_tpu.models import decoder_stack
+from deeplearning_cfn_tpu.models.decoder_stack import (
+    checkpointed,
+    dense_init,
+    embed,
+    init_runs,
+    run_specs,
+    scan_runs,
 )
+from deeplearning_cfn_tpu.models.llama import attend, attention_kind
 from deeplearning_cfn_tpu.ops import pallas_ssd, pallas_ssm_stages
 from deeplearning_cfn_tpu.ops.attention import rms_norm
+from deeplearning_cfn_tpu.ops.conv import short_conv
 from deeplearning_cfn_tpu.ops.moe import (
     RoutedConfig,
     init_routed_params,
@@ -187,7 +187,7 @@ class SsmAttnMoeConfig:
 def _block_params(cfg: SsmAttnMoeConfig, key: jax.Array, block: str) -> dict:
     keys = jax.random.split(key, 6)
     d = cfg.dim
-    init = partial(_dense_init, dtype=cfg.dtype)
+    init = partial(dense_init, dtype=cfg.dtype)
     params = {"norm": jnp.ones((d,), jnp.float32)}
     if block == "M":
         H, inner, conv = cfg.ssm_heads, cfg.ssm_inner, cfg.ssm_conv_dim
@@ -230,8 +230,8 @@ def _unit_params(cfg: SsmAttnMoeConfig, key: jax.Array, unit: str) -> list[dict]
 def init_params(cfg: SsmAttnMoeConfig, rng: jax.Array) -> dict:
     k_embed, k_output, k_runs = jax.random.split(rng, 3)
     return {
-        "embed": _dense_init(k_embed, (cfg.vocab_size, cfg.dim), cfg.dim, cfg.dtype),
-        "output": _dense_init(k_output, (cfg.dim, cfg.vocab_size), cfg.dim, cfg.dtype),
+        "embed": dense_init(k_embed, (cfg.vocab_size, cfg.dim), cfg.dim, cfg.dtype),
+        "output": dense_init(k_output, (cfg.dim, cfg.vocab_size), cfg.dim, cfg.dtype),
         "final_norm": jnp.ones((cfg.dim,), jnp.float32),
         "runs": init_runs(partial(_unit_params, cfg), cfg.runs, k_runs),
     }
@@ -266,15 +266,11 @@ def param_specs(cfg: SsmAttnMoeConfig) -> dict:
 
 
 def param_shardings(cfg: SsmAttnMoeConfig, mesh: Mesh) -> dict:
-    return jax.tree_util.tree_map(
-        lambda spec: NamedSharding(mesh, spec), param_specs(cfg),
-        is_leaf=lambda x: isinstance(x, P),
-    )
+    return decoder_stack.shardings(param_specs(cfg), mesh)
 
 
 def param_count(cfg: SsmAttnMoeConfig) -> int:
-    shapes = jax.eval_shape(partial(init_params, cfg), jax.random.key(0))
-    return sum(int(np.prod(x.shape)) for x in jax.tree_util.tree_leaves(shapes))
+    return decoder_stack.count(cfg, init_params)
 
 
 def train_flops_per_token(cfg: SsmAttnMoeConfig, seq_len: int) -> float:
@@ -410,10 +406,10 @@ def hidden_states(
     """tokens [B, S] -> (the last block's output before the final norm
     [B, S, d], each routed run's statistics stacked on its axis)."""
     with jax.named_scope("embed"):
-        x = _embed(cfg, params, tokens)
+        x = embed(cfg, params, tokens)
 
     def unit_of(unit: str):
-        blocks = [_checkpointed(cfg, partial(_block, cfg, mesh, b)) for b in unit]
+        blocks = [checkpointed(cfg, partial(_block, cfg, mesh, b)) for b in unit]
 
         def body(x, lps):
             routed = []
@@ -440,14 +436,11 @@ def lm_loss(
 ) -> tuple[jax.Array, dict]:
     """Next-token cross-entropy; `targets[i]` is the token that follows
     `tokens[i]` (the last one wrapped, and masked).  The head with its loss
-    is rematerialised, as `mla_moe.lm_loss`'s."""
+    is rematerialised (`decoder_stack.next_token_loss`)."""
     x, stats = hidden_states(cfg, params, tokens, mesh)
-    head_loss = _checkpointed(cfg, partial(_head_loss, cfg))
-    loss = head_loss(params["final_norm"], params["output"], x, targets, ahead=1)
-    metrics = {"perplexity": jnp.exp(loss)}
-    if stats:
-        metrics["counters"] = _counters(cfg, stats)
-    return loss, metrics
+    return decoder_stack.next_token_loss(
+        cfg, params["final_norm"], params["output"], x, targets, stats
+    )
 
 
 def logits(
@@ -456,24 +449,12 @@ def logits(
     """float32 logits and each routed block's selection [blocks, T, k]: the
     inspection entry point, not the train hot path."""
     x, stats = hidden_states(cfg, params, tokens, mesh)
-    out = {"main": _head(cfg, params["final_norm"], params["output"], x).astype(jnp.float32)}
-    if stats:
-        out["selected"] = jnp.concatenate([s["selected"] for s in stats])
-    return out
+    return decoder_stack.inspect_logits(cfg, params["final_norm"], params["output"], x, stats)
 
 
 def make_trainer(cfg: SsmAttnMoeConfig, mesh: Mesh, trainer_config) -> Any:
     """The generic SPMD Trainer on this model, as `llama.make_trainer`."""
-    from deeplearning_cfn_tpu.train.trainer import Trainer
-
-    return Trainer(
-        _FunctionalInit(cfg, init_params),
-        mesh,
-        trainer_config,
-        loss_fn=lambda p, x, y: lm_loss(cfg, p, x, y, mesh),
-        param_shardings=param_shardings(cfg, mesh),
-        batch_spec=BATCH_SPEC,
-        analytic_flops_fn=lambda x: (
-            train_flops_per_token(cfg, x.shape[1]) * x.shape[0] * x.shape[1]
-        ),
+    return decoder_stack.make_trainer(
+        cfg, mesh, trainer_config, init_params=init_params, lm_loss=lm_loss,
+        param_specs=param_specs, train_flops_per_token=train_flops_per_token,
     )

@@ -4,13 +4,14 @@ rotary rules, a head-wise sigmoid gate on the attention output, and a dense
 SwiGLU or routed experts with a shared one after it.  The block of Laguna
 (`laguna`).
 
-Beside `models/conv_attn_moe.py`, whose pattern-as-data machinery it calls
-(`runs_of`, `init_runs`, `run_specs`, `scan_runs`: consecutive layers of one
-kind are a run, each run one stack of weights and one `scan`), and built from
-the other decoders' parts where the block is the same (`attention_kind`,
-`attend`, `swiglu`, `ops/moe.routed_experts`, the embedding, the head with its
-rematerialised loss, the routing counters).  A module of its own and not two
-more entries in that module's `MIXERS`: there a layer's kind is read off the
+Beside `models/conv_attn_moe.py`, on `models/decoder_stack.py`'s
+pattern-as-data machinery as it is (`runs_of`, `init_runs`, `run_specs`,
+`scan_runs`: consecutive layers of one kind are a run, each run one stack of
+weights and one `scan`), and built from the shared parts where the block is
+the same (`llama.attention_kind`, `attend`, `swiglu`, `ops/moe.routed_experts`,
+`decoder_stack`'s embedding, head with its rematerialised loss and routing
+counters).  A module of its own and not two more entries in
+`conv_attn_moe.MIXERS`: there a layer's kind is read off the
 leaves it is given and every attention layer has the configuration's one head
 count, rotary rule and mask; here the kind (mixer, heads, routed) decides
 shapes, mask and rotary rule and is closed over by each run's block, the
@@ -44,24 +45,19 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import Mesh, PartitionSpec as P
 
-from deeplearning_cfn_tpu.models.conv_attn_moe import init_runs, run_specs, runs_of, scan_runs
-from deeplearning_cfn_tpu.models.llama import (
-    BATCH_SPEC,
-    _FunctionalInit,
-    attend,
-    attention_kind,
-    swiglu,
+from deeplearning_cfn_tpu.models import decoder_stack
+from deeplearning_cfn_tpu.models.decoder_stack import (
+    checkpointed,
+    dense_init,
+    embed,
+    init_runs,
+    run_specs,
+    runs_of,
+    scan_runs,
 )
-from deeplearning_cfn_tpu.models.mla_moe import (
-    _checkpointed,
-    _counters,
-    _dense_init,
-    _embed,
-    _head,
-    _head_loss,
-)
+from deeplearning_cfn_tpu.models.llama import attend, attention_kind, swiglu
 from deeplearning_cfn_tpu.ops.attention import partial_rotary_embedding, rms_norm, yarn_inv_freq
 from deeplearning_cfn_tpu.ops.moe import (
     RoutedConfig,
@@ -230,7 +226,7 @@ def _block_params(cfg: WindowAttnMoeConfig, key: jax.Array, kind: Kind) -> dict:
     _, heads, routed = kind
     keys = jax.random.split(key, 8)
     d, hd = cfg.dim, cfg.head_dim
-    init = partial(_dense_init, dtype=cfg.dtype)
+    init = partial(dense_init, dtype=cfg.dtype)
     params = {
         "attn_norm": jnp.ones((d,), jnp.float32),
         "mlp_norm": jnp.ones((d,), jnp.float32),
@@ -253,8 +249,8 @@ def _block_params(cfg: WindowAttnMoeConfig, key: jax.Array, kind: Kind) -> dict:
 def init_params(cfg: WindowAttnMoeConfig, rng: jax.Array) -> dict:
     k_embed, k_output, k_runs = jax.random.split(rng, 3)
     return {
-        "embed": _dense_init(k_embed, (cfg.vocab_size, cfg.dim), cfg.dim, cfg.dtype),
-        "output": _dense_init(k_output, (cfg.dim, cfg.vocab_size), cfg.dim, cfg.dtype),
+        "embed": dense_init(k_embed, (cfg.vocab_size, cfg.dim), cfg.dim, cfg.dtype),
+        "output": dense_init(k_output, (cfg.dim, cfg.vocab_size), cfg.dim, cfg.dtype),
         "final_norm": jnp.ones((cfg.dim,), jnp.float32),
         "runs": init_runs(partial(_block_params, cfg), cfg.runs, k_runs),
     }
@@ -285,15 +281,11 @@ def param_specs(cfg: WindowAttnMoeConfig) -> dict:
 
 
 def param_shardings(cfg: WindowAttnMoeConfig, mesh: Mesh) -> dict:
-    return jax.tree_util.tree_map(
-        lambda spec: NamedSharding(mesh, spec), param_specs(cfg),
-        is_leaf=lambda x: isinstance(x, P),
-    )
+    return decoder_stack.shardings(param_specs(cfg), mesh)
 
 
 def param_count(cfg: WindowAttnMoeConfig) -> int:
-    shapes = jax.eval_shape(partial(init_params, cfg), jax.random.key(0))
-    return sum(int(np.prod(x.shape)) for x in jax.tree_util.tree_leaves(shapes))
+    return decoder_stack.count(cfg, init_params)
 
 
 def attended_keys(cfg: WindowAttnMoeConfig, mixer: str, seq_len: int) -> float:
@@ -384,11 +376,11 @@ def hidden_states(
     """tokens [B, S] -> (the last block's output before the final norm
     [B, S, d], each routed run's statistics stacked on its layer axis)."""
     with jax.named_scope("embed"):
-        x = _embed(cfg, params, tokens)
+        x = embed(cfg, params, tokens)
     positions = jnp.arange(tokens.shape[1], dtype=jnp.int32)
 
     def block_of(kind: Kind):
-        block = _checkpointed(cfg, partial(_block, cfg, mesh, kind))
+        block = checkpointed(cfg, partial(_block, cfg, mesh, kind))
         return lambda x, lp: block(x, lp, positions)
 
     return scan_runs(block_of, cfg.runs, params["runs"], x)
@@ -400,14 +392,11 @@ def lm_loss(
 ) -> tuple[jax.Array, dict]:
     """Next-token cross-entropy; `targets[i]` is the token that follows
     `tokens[i]` (the last one wrapped, and masked).  The head with its loss
-    is rematerialised, as `mla_moe.lm_loss`'s."""
+    is rematerialised (`decoder_stack.next_token_loss`)."""
     x, stats = hidden_states(cfg, params, tokens, mesh)
-    head_loss = _checkpointed(cfg, partial(_head_loss, cfg))
-    loss = head_loss(params["final_norm"], params["output"], x, targets, ahead=1)
-    metrics = {"perplexity": jnp.exp(loss)}
-    if stats:
-        metrics["counters"] = _counters(cfg, stats)
-    return loss, metrics
+    return decoder_stack.next_token_loss(
+        cfg, params["final_norm"], params["output"], x, targets, stats
+    )
 
 
 def logits(
@@ -416,24 +405,12 @@ def logits(
     """float32 logits and each routed block's selection [blocks, T, k]: the
     inspection entry point, not the train hot path."""
     x, stats = hidden_states(cfg, params, tokens, mesh)
-    out = {"main": _head(cfg, params["final_norm"], params["output"], x).astype(jnp.float32)}
-    if stats:
-        out["selected"] = jnp.concatenate([s["selected"] for s in stats])
-    return out
+    return decoder_stack.inspect_logits(cfg, params["final_norm"], params["output"], x, stats)
 
 
 def make_trainer(cfg: WindowAttnMoeConfig, mesh: Mesh, trainer_config) -> Any:
     """The generic SPMD Trainer on this model, as `llama.make_trainer`."""
-    from deeplearning_cfn_tpu.train.trainer import Trainer
-
-    return Trainer(
-        _FunctionalInit(cfg, init_params),
-        mesh,
-        trainer_config,
-        loss_fn=lambda p, x, y: lm_loss(cfg, p, x, y, mesh),
-        param_shardings=param_shardings(cfg, mesh),
-        batch_spec=BATCH_SPEC,
-        analytic_flops_fn=lambda x: (
-            train_flops_per_token(cfg, x.shape[1]) * x.shape[0] * x.shape[1]
-        ),
+    return decoder_stack.make_trainer(
+        cfg, mesh, trainer_config, init_params=init_params, lm_loss=lm_loss,
+        param_specs=param_specs, train_flops_per_token=train_flops_per_token,
     )

@@ -622,3 +622,24 @@ def routed_experts(
         "selected": experts,
     }
     return y.reshape(B, S, rows_in.shape[-1]), stats
+
+
+def routing_counters(cfg: RoutedConfig, stats: list[dict]) -> dict:
+    """A step's statistics from `routed_experts` over every routed block (one
+    dict a block, or stacked on a leading axis) as the scalars the trainer
+    folds into `obs.tracing` counters: sums of assignments and of the buffer's
+    rows the layers' passes ran over, the largest and the mean load of a held
+    expert, and what was dropped."""
+    every = {
+        k: jnp.concatenate([jnp.atleast_1d(s[k]) for s in stats])
+        for k in ("assignments", "assignments_held", "rows_run", "load_max", "dropped")
+    }
+    held = jnp.sum(every["assignments_held"])
+    return {
+        "moe.assignments": jnp.sum(every["assignments"]),
+        "moe.assignments_held": held,
+        "moe.rows_run": jnp.sum(every["rows_run"]),
+        "moe.expert_load_max": jnp.max(every["load_max"]),
+        "moe.expert_load_mean": held / (every["load_max"].shape[0] * cfg.span[1]),
+        "moe.dropped": jnp.sum(every["dropped"]),
+    }

@@ -324,46 +324,3 @@ def test_three_steps_of_the_trainer_lower_the_loss_and_fold_the_loops_counters()
     assert has_scope("jit(train_step)/loss/loop_head/head/dot_general", "head")
     assert not has_scope("jit(train_step)/loss/attn_post_norm/mul", "attn_norm")
     assert not has_scope("jit(train_step)/loss/while/body/loop_pass/mul", "loop")
-
-
-# --- the other cells' steps are the parent's --------------------------------------------
-
-
-def test_the_nemotron_cells_step_lowers_to_the_parents_text(monkeypatch):
-    """`llama.decoder_block` finds its post-norms as leaves, and no tree but
-    this module's holds them: tests/test_ssm_attn_moe.py holds the Mistral
-    cell's and the three SwiGLU expert cells' whole steps to the text PR 41's
-    parent lowered to, and this is the fifth decoder cell's, whose blocks are
-    `models/ssm_attn_moe.py`'s own: sha256 of the step lowered for the TPU
-    from shapes alone, kernel source locations apart.  Re-pinned by PR 45,
-    which changed this step on purpose (its `M` blocks' two elementwise stages
-    became the kernels of `ops/pallas_ssm_stages.py`): the constant is the step
-    of PR 45's tree (parent e03795d plus that change) and the next PR's guard;
-    through PR 44 it read eabf7f63...79817, the step at PR 43's parent
-    (564913a)."""
-    import hashlib
-    import json
-    from pathlib import Path
-
-    from benchmarks.manifest import Manifest
-    from deeplearning_cfn_tpu.models import ssm_attn_moe
-    from deeplearning_cfn_tpu.parallel.mesh import MeshSpec, build_mesh
-    from deeplearning_cfn_tpu.train.trainer import TrainerConfig
-    from tests.kernel_text import text_without_kernel_locations
-
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    repo = Path(__file__).resolve().parents[1]
-    config = json.loads(
-        (repo / "benchmarks/configs/nemotron-3-super-120b-a12b.json").read_text())
-    cfg = Manifest().module("builders", "ssm_attn_moe").model_config(config)
-    mesh = build_mesh(MeshSpec.fsdp_parallel(1), jax.devices()[:1])
-    trainer = ssm_attn_moe.make_trainer(cfg, mesh, TrainerConfig(
-        strategy="fsdp", optimizer="adamw", learning_rate=3e-4, weight_decay=0.1,
-        grad_clip_norm=1.0, log_every=2))
-    tokens = jax.ShapeDtypeStruct((1, 8192), np.int32)
-    state = jax.eval_shape(partial(trainer.init, jax.random.key(0)), tokens)
-    with jax.set_mesh(mesh):
-        text = trainer.step_fn.trace(state, tokens, tokens).lower(
-            lowering_platforms=("tpu",)).as_text()
-    assert hashlib.sha256(text_without_kernel_locations(text).encode()).hexdigest() == (
-        "1ab8ea1d2df8f537dfc14b8d381cba1e839a3e9dd41719ecf3179dd66a530004")

@@ -1,14 +1,11 @@
 """`models/ssm_attn_moe.py` and what it asked of `ops/moe.py`: the pattern's
 units, each block kind by hand at a tiny size, both forms of a routed expert
-(SwiGLU, relu^2 with rows of their own) against a dense loop over experts, a
-step of the trainer, and the four decoder cells' steps lowered to the text the
-parent's lower to.  Model against reference is
-tests/benchmark_tests/test_benchmark_ssm_attn_moe.py."""
+(SwiGLU, relu^2 with rows of their own) against a dense loop over experts, and
+a step of the trainer.  Model against reference is
+tests/benchmark_tests/test_benchmark_ssm_attn_moe.py; the cell's lowered step
+is pinned in tests/test_cell_steps.py."""
 
-import hashlib
-import json
 from functools import partial
-from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -18,9 +15,7 @@ import pytest
 from deeplearning_cfn_tpu.models import ssm_attn_moe as model
 from deeplearning_cfn_tpu.ops import moe
 from deeplearning_cfn_tpu.ops.moe import RoutedConfig, init_routed_params, route, routed_experts
-from tests.kernel_text import text_without_kernel_locations
 
-REPO = Path(__file__).resolve().parents[1]
 HIGHEST = partial(jax.default_matmul_precision, "highest")
 
 
@@ -340,69 +335,3 @@ def test_the_loss_and_gradients_with_the_stages_kernels_are_those_with_the_jnp_s
     for (path, g), (_, w) in zip(flat(got_grads), flat(want_grads), strict=True):
         g, w = np.asarray(g, np.float32), np.asarray(w, np.float32)
         assert np.linalg.norm(g - w) <= tolerance * max(np.linalg.norm(w), 1e-3), jax.tree_util.keystr(path)
-
-
-# --- the other cells' steps are the parent's ----------------------------------------------
-
-# sha256 of each cell's train step as its builder makes it, lowered for the TPU
-# from shapes alone at the parent commit (578d359), each Mosaic kernel's body
-# replaced by the sha256 of its MLIR without source locations.
-PARENT_STEPS = {
-    "mistral-7b-v0.3": ("8946d392f01aa378c21651962d281549557c9fdddcdf6e5eba5316069cd3bd77", 2, 4096),
-    "glm-4.7-flash": ("2ac6716748d024bd6f80b82bfbfecc9d89a7b76ec87ea09a6f24a73432ec48f3", 2, 8192),
-    "lfm2-8b-a1b": ("2ca42c8a08a2f44071c0ed91cd42483a4e6056ea3a1f8d29c8301b217927f0b9", 2, 8192),
-    "laguna-xs.2": ("be00b39258be6ca904c6a8b699b9f8f1d983570ea4a5229615ebce49f29547bc", 2, 8192),
-}
-
-
-def lowered_step_without_locations(name: str, batch: int, seq_len: int) -> str:
-    from benchmarks.manifest import Manifest
-    from deeplearning_cfn_tpu.models import conv_attn_moe, llama, mla_moe, window_attn_moe
-    from deeplearning_cfn_tpu.parallel.mesh import MeshSpec, build_mesh
-    from deeplearning_cfn_tpu.train.trainer import TrainerConfig
-
-    config = json.loads((REPO / "benchmarks" / "configs" / f"{name}.json").read_text())
-    if config["kind"] == "decoder":
-        module, cfg = llama, llama.LlamaConfig(
-            vocab_size=config["vocab_size"], dim=config["hidden_size"],
-            n_layers=config["num_hidden_layers"], n_heads=config["num_attention_heads"],
-            n_kv_heads=config["num_key_value_heads"], mlp_dim=config["intermediate_size"],
-            max_seq_len=config["max_position_embeddings"], rope_theta=float(config["rope_theta"]),
-            norm_eps=config["rms_norm_eps"], dtype=jnp.dtype(config["torch_dtype"]), remat=True,
-            remat_policy=config["remat_policy"], tied_embeddings=config["tie_word_embeddings"],
-            use_flash_attention=config["use_flash_attention"],
-        )
-    else:
-        module = {"mla_moe": mla_moe, "conv_attn_moe": conv_attn_moe,
-                  "window_attn_moe": window_attn_moe}[config["kind"]]
-        cfg = Manifest().module("builders", config["kind"]).model_config(config)
-    mesh = build_mesh(MeshSpec.fsdp_parallel(1), jax.devices()[:1])
-    trainer = module.make_trainer(cfg, mesh, TrainerConfig(
-        strategy="fsdp", optimizer="adamw", learning_rate=3e-4, weight_decay=0.1,
-        grad_clip_norm=1.0, log_every=2))
-    tokens = jax.ShapeDtypeStruct((batch, seq_len), np.int32)
-    state = jax.eval_shape(
-        partial(trainer.init, jax.random.key(0)), jax.ShapeDtypeStruct((1, seq_len), np.int32))
-    with jax.set_mesh(mesh):
-        text = trainer.step_fn.trace(state, tokens, tokens).lower(lowering_platforms=("tpu",)).as_text()
-
-    return text_without_kernel_locations(text)
-
-
-@pytest.mark.parametrize("name", PARENT_STEPS)
-def test_the_other_decoder_cells_steps_lower_to_the_parents_text(name, monkeypatch):
-    """`RoutedConfig.expert` and `routed_experts(expert_rows=)` are data the
-    three SwiGLU cells do not set, and the scan's kernels (PR 42) have one
-    caller, this file's model: their whole steps (and the Mistral cell's,
-    which has no experts) lower, for the TPU and at the cells' own sizes, to
-    what PR 41's parent's lowered to, kernel source locations apart.  Since
-    PR 44 a full-causal backward pass at these cells' shapes is the fused
-    kernel; with no dq fitting its budget the calls run the pair again, and
-    the steps are still the parent's: nothing else of them moved."""
-    from deeplearning_cfn_tpu.ops import pallas_attention
-
-    monkeypatch.setattr(pallas_attention, "_BWD_FUSED_DQ_BUDGET", 0)
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    want, batch, seq_len = PARENT_STEPS[name]
-    text = lowered_step_without_locations(name, batch, seq_len)
-    assert hashlib.sha256(text.encode()).hexdigest() == want

@@ -17,7 +17,7 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from deeplearning_cfn_tpu.models import conv_attn_moe, window_attn_moe
+from deeplearning_cfn_tpu.models import window_attn_moe
 from deeplearning_cfn_tpu.models.window_attn_moe import (
     FULL_ROTARY,
     SLIDING_ROTARY,
@@ -275,16 +275,6 @@ def test_config_refuses_what_it_cannot_build():
         WindowAttnMoeConfig.tiny(layer_types=(), mlp_layer_types=(), heads_per_layer=())
     with pytest.raises(ValueError, match="key/value heads"):
         WindowAttnMoeConfig.tiny(heads_per_layer=(6, 8, 8, 7, 6))
-
-
-def test_both_decoders_of_layer_lists_share_the_run_machinery():
-    """One definition of a run, of its stacked weights and of the scan over
-    runs: `models/conv_attn_moe.py`'s functions, called by both modules."""
-    for name in ("runs_of", "init_runs", "run_specs", "scan_runs"):
-        assert getattr(window_attn_moe, name) is getattr(conv_attn_moe, name)
-    kinds = (("a", 1), ("a", 1), ("b", 2), ("a", 1))
-    assert conv_attn_moe.runs_of(kinds) == ((("a", 1), 2), (("b", 2), 1), (("a", 1), 1))
-    assert WindowAttnMoeConfig.tiny().runs == conv_attn_moe.runs_of(WindowAttnMoeConfig.tiny().kinds)
 
 
 def test_fit_trains_and_folds_the_routing_counters_at_the_log_seam():
