@@ -1,7 +1,7 @@
 """What every decoder of this package shares, under all of them: `llama.py`
-and the five kinds built beside it (`mla_moe`, `conv_attn_moe`,
-`window_attn_moe`, `ssm_attn_moe`, `looped_decoder`) import this module, and it
-imports none of `models/`.
+and the six kinds built beside it (`mla_moe`, `conv_attn_moe`,
+`window_attn_moe`, `ssm_attn_moe`, `looped_decoder`, `mamba_attn`) import this
+module, and it imports none of `models/`.
 
 A kind's own module holds what *is* the kind: its configuration, a block's
 parameters and their specs, its mixers and its block, `hidden_states`, its

@@ -28,6 +28,8 @@ STEPS = {
     "laguna-xs.2": "9b677fca70578e550d4f0e7d6ae9031428612872ade943f8aea77d030538d932",
     "nemotron-3-super-120b-a12b": "1ab8ea1d2df8f537dfc14b8d381cba1e839a3e9dd41719ecf3179dd66a530004",
     "ouro-2.6b": "173cda54d3db1119256c9ed06402770e2279f876085e09d45da6f0d86920315b",
+    # PR 47's own cell, taken on PR 47's tree: the six rows above did not move.
+    "jamba2-3b": "9684fd2cf07f76fa6d1a0bb7af4b1e60a6c34e939749723465c9dc16f73b0b30",
 }
 
 
@@ -35,7 +37,7 @@ def cell_trainer(name: str):
     """The trainer of the configuration's cell as its builder makes it, and the
     cell's batch and sequence length."""
     from deeplearning_cfn_tpu.models import (
-        conv_attn_moe, llama, looped_decoder, mla_moe, ssm_attn_moe, window_attn_moe)
+        conv_attn_moe, llama, looped_decoder, mamba_attn, mla_moe, ssm_attn_moe, window_attn_moe)
     from deeplearning_cfn_tpu.parallel.mesh import MeshSpec, build_mesh
     from deeplearning_cfn_tpu.train.trainer import TrainerConfig
 
@@ -56,7 +58,7 @@ def cell_trainer(name: str):
     else:
         module = {"mla_moe": mla_moe, "conv_attn_moe": conv_attn_moe,
                   "window_attn_moe": window_attn_moe, "ssm_attn_moe": ssm_attn_moe,
-                  "looped_decoder": looped_decoder}[config["kind"]]
+                  "looped_decoder": looped_decoder, "mamba_attn": mamba_attn}[config["kind"]]
         cfg = manifest.module("builders", config["kind"]).model_config(config)
     mesh = build_mesh(MeshSpec.fsdp_parallel(1), jax.devices()[:1])
     trainer = module.make_trainer(cfg, mesh, TrainerConfig(

@@ -1,4 +1,4 @@
-"""`models/decoder_stack.py` sits under the six decoder modules: which way the
+"""`models/decoder_stack.py` sits under the seven decoder modules: which way the
 imports in `models/` point, read from the source by `ast`, and the run
 machinery its three users share.  That the move changed no cell's program is
 tests/test_cell_steps.py."""
@@ -11,7 +11,7 @@ from deeplearning_cfn_tpu.models.window_attn_moe import WindowAttnMoeConfig
 
 MODELS = Path(decoder_stack.__file__).parent
 PACKAGE = "deeplearning_cfn_tpu"
-KINDS = ("mla_moe", "conv_attn_moe", "window_attn_moe", "ssm_attn_moe", "looped_decoder")
+KINDS = ("mla_moe", "conv_attn_moe", "window_attn_moe", "ssm_attn_moe", "looped_decoder", "mamba_attn")
 
 
 def _imports(path: Path):

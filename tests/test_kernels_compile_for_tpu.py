@@ -1,5 +1,5 @@
 """The flash attention's kernels, the routed experts' grouped matmuls, the
-state-space scan's kernels and the Mamba-2 block's two elementwise stages'
+state-space scan's kernels, the selective scan's and the Mamba-2 block's two elementwise stages'
 compiled for a TPU v5e that is described, not attached, at the widths the chip
 runs them: what the interpreter cannot show
 (a tile Mosaic refuses, more VMEM than a kernel may use).  Nothing runs, so
@@ -187,6 +187,45 @@ def test_the_state_space_scans_kernels_compile_for_v5e(case, one_chip):
     # the saved states and copies of x's size, under half of one pass's L.
     states, an_x = b * S // 128 * 128 * H * P * 4, b * S * H * P * jnp.dtype(dtype).itemsize
     assert compiled.memory_analysis().temp_size_in_bytes < states + 4 * an_x
+
+
+# The selective scan of the Jamba cell's Mamba-1 layers (PR 47) and float32 at a
+# small size.  (batch, seq, channels, states, dtype)
+SELECTIVE_SCAN_CASES = {
+    "jamba-cell-s8192": (1, 8192, 5120, 16, jnp.bfloat16),
+    "float32-s1024": (2, 1024, 512, 16, jnp.float32),
+}
+
+
+@pytest.mark.parametrize("case", SELECTIVE_SCAN_CASES)
+def test_the_selective_scans_kernels_compile_for_v5e(case, one_chip):
+    from deeplearning_cfn_tpu.ops import pallas_selective_scan as kernels
+
+    b, S, I, N, dtype = SELECTIVE_SCAN_CASES[case]
+    on_chip = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    f32 = jnp.float32
+    args = (
+        on_chip((b, S, I), dtype), on_chip((b, S, I), f32), on_chip((I, N), f32),
+        on_chip((b, S, N), dtype), on_chip((b, S, N), dtype), on_chip((I,), f32),
+    )
+    assert kernels.takes_kernel(args[0], args[2], backend="tpu")
+
+    def grads(*args):
+        loss = lambda *a: kernels.selective_scan(*a).astype(f32).sum()
+        return jax.grad(loss, argnums=tuple(range(6)))(*args)
+
+    compiled = jax.jit(grads).lower(*args).compile()
+    text = compiled.as_text()
+    assert "_selective_scan_forward" in text and "_selective_scan_backward" in text
+    # No [S, I, N] array outside the kernels: the temporaries are the chunks'
+    # boundary states, B and C spread over a lane tile with their gradients'
+    # partial sums, and copies of x's size; a state a token would be 16 of dt's.
+    a_dt = b * S * I * 4
+    states = b * S // kernels.CHUNK * N * I * 4
+    columns = b * S * N * 128 * (2 * jnp.dtype(dtype).itemsize + 2 * 4)
+    temporaries = compiled.memory_analysis().temp_size_in_bytes
+    assert temporaries < states + columns + 1.5 * a_dt
+    assert case != "jamba-cell-s8192" or temporaries < N * a_dt / 4
 
 
 # The two elementwise stages of that cell's `M` block (PR 45): the convolution
