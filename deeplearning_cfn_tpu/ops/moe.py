@@ -187,7 +187,9 @@ def moe_mlp(
 # past them is neither read nor written.  Forward there is one whole pass
 # beside the matmuls, the SwiGLU under a row mask; the gather into the buffer
 # has no select, and the weighting rides the token-major gather back
-# (`_rows_back`), which reads a slot of every token and stays whole.  The
+# (`_rows_back`), which reads a slot of every token and stays whole; where a
+# token has more choices than experts held here, the same gather of the rows'
+# cotangent runs over its live slots alone (`_live_slots_first`).  The
 # one-hot path above stays for ``LlamaConfig.n_experts`` and the serving engine
 # until experts are spread over the ``ep`` axis; a model module calls one or
 # the other.
@@ -382,6 +384,14 @@ def _rows_back(rows, slot, held, weight=None):
     return jnp.sum(picked, axis=0, dtype=jnp.float32).astype(rows.dtype)
 
 
+def _live_slots_first(slot, j):
+    """[T, k] -> [T, j]: each token's ``j`` smallest slots.  The live rows are
+    the buffer's front and at most ``j`` of a token's choices are held here,
+    so every live slot of the token is among them: a token-major pass over
+    them is no wider than the buffer.  One sort along a token's choices."""
+    return jax.lax.sort(slot, dimension=1)[:, :j]
+
+
 @jax.custom_vjp
 def _rows_out(x, token, slot, held):
     """x [T, d] -> the buffer [R, d], once for each of its two grouped
@@ -561,8 +571,9 @@ def routed_experts(
     in all and to held experts, the largest held expert's load, `dropped`:
     assignments to held experts less rows computed, which is 0, and
     `rows_run`: the buffer's rows that the backward pass's passes beside the
-    matmuls run over, the live tiles' rows) and ``selected`` [T, k], the
-    experts each token chose.
+    matmuls run over, the live tiles' rows, `slots_read`: the buffer rows the
+    two token-major gathers fetch, forward and backward) and ``selected``
+    [T, k], the experts each token chose.
     """
     B, S, d = x.shape
     T, k = B * S, cfg.top_k
@@ -584,11 +595,15 @@ def routed_experts(
         weights = weights.astype(x.dtype)
         # The argsort, with the weights riding it into the buffer's order.
         by_row = jax.lax.stop_gradient(weights).reshape(T * k)
-        _, order, row_weight = jax.lax.sort(
+        by_expert, order, row_weight = jax.lax.sort(
             (local, jnp.arange(T * k, dtype=jnp.int32), by_row), num_keys=1, is_stable=True
         )
         R = cfg.buffer_rows(T)
         slot = jnp.argsort(order).astype(jnp.int32).reshape(T, k)  # inverse permutation
+        # The gather back of the rows' cotangent, no wider than the buffer.  The
+        # weighted pass keeps a token's every choice: with it and its cotangent
+        # over the live slots the Nemotron step hangs on the chip (PERF.md, PR 48).
+        slot_out = _live_slots_first(slot, count) if k > count else slot
         order, row_weight = order[:R], row_weight[:R]
         token = (order // k).astype(jnp.int32)
         group_sizes = jnp.sum(
@@ -596,9 +611,9 @@ def routed_experts(
         )
         held = jnp.sum(group_sizes)  # the rows that hold an assignment: the front of the buffer
         if cfg.expert == "swiglu":
-            rows_gate, rows_up = _rows_out(rows_in, token, slot, held)
+            rows_gate, rows_up = _rows_out(rows_in, token, slot_out, held)
         else:
-            rows_up = _rows_out_once(rows_in, token, slot, held)
+            rows_up = _rows_out_once(rows_in, token, slot_out, held)
     with jax.named_scope("experts"):
         mm = partial(grouped_matmul, group_sizes=group_sizes, kind=kind, interpret=interpret)
         if cfg.expert == "swiglu":
@@ -617,8 +632,9 @@ def routed_experts(
         "assignments": jnp.asarray(T * k, jnp.int32),
         "assignments_held": held,  # from the selection
         "load_max": jnp.max(group_sizes),
-        "dropped": held - jnp.sum(local[order] < count, dtype=jnp.int32),  # from the buffer
+        "dropped": held - jnp.sum(by_expert[:R] < count, dtype=jnp.int32),  # from the buffer
         "rows_run": live_tiles * tile,
+        "slots_read": jnp.asarray(slot.size + slot_out.size, jnp.int32),
         "selected": experts,
     }
     return y.reshape(B, S, rows_in.shape[-1]), stats
@@ -628,17 +644,18 @@ def routing_counters(cfg: RoutedConfig, stats: list[dict]) -> dict:
     """A step's statistics from `routed_experts` over every routed block (one
     dict a block, or stacked on a leading axis) as the scalars the trainer
     folds into `obs.tracing` counters: sums of assignments and of the buffer's
-    rows the layers' passes ran over, the largest and the mean load of a held
-    expert, and what was dropped."""
+    rows the layers' passes ran over and the token-major gathers fetch, the
+    largest and the mean load of a held expert, and what was dropped."""
     every = {
         k: jnp.concatenate([jnp.atleast_1d(s[k]) for s in stats])
-        for k in ("assignments", "assignments_held", "rows_run", "load_max", "dropped")
+        for k in ("assignments", "assignments_held", "rows_run", "slots_read", "load_max", "dropped")
     }
     held = jnp.sum(every["assignments_held"])
     return {
         "moe.assignments": jnp.sum(every["assignments"]),
         "moe.assignments_held": held,
         "moe.rows_run": jnp.sum(every["rows_run"]),
+        "moe.slots_read": jnp.sum(every["slots_read"]),
         "moe.expert_load_max": jnp.max(every["load_max"]),
         "moe.expert_load_mean": held / (every["load_max"].shape[0] * cfg.span[1]),
         "moe.dropped": jnp.sum(every["dropped"]),

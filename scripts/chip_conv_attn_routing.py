@@ -84,6 +84,7 @@ def main(argv: list[str]) -> int:
                     "held_share": round(float(c["moe.assignments_held"] / c["moe.assignments"]), 4),
                     "load_max_over_mean": round(float(c["moe.expert_load_max"] / c["moe.expert_load_mean"]), 3),
                     "rows_run_over_held": round(float(c["moe.rows_run"] / c["moe.assignments_held"]), 3),
+                    "slots_read_over_held": round(float(c["moe.slots_read"] / c["moe.assignments_held"]), 3),
                 }, allow_nan=False), flush=True)
         del state, rows
     return 0

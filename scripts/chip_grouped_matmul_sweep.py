@@ -16,16 +16,27 @@ only) is the three kernels under a sliding window at the shape of
 512) by tile, with the full-causal kernels at its full layers' 48 heads beside
 them (`WINDOW_FWD_BLOCKS`, `WINDOW_BWD_DKV_BLOCKS`, `WINDOW_BWD_DQ_BLOCKS`), and
 the forward's band step by its rows a chunk (`_BAND_ROW_CHUNK`).  `routing` (run by
-name only) is one whole routed layer, `ops/moe.routed_experts`, forward and
-backward at that shape and at both cells' expert widths, by the tile of its
-passes over the sorted buffer (`ROW_TILE` in `ops/moe.py` is picked from it)
-and by the share of the assignments that go to held experts: device time by
-the layer's scopes from a capture.  In a tree whose `ops/moe.py` has no
-`ROW_TILE` (copy this file there) it measures that tree's layer, which is how
-the code before the tiles is read beside them.  Through chiprun; one JSON line
+name only) is the routed layer at the four routed cells' shapes, each read
+from its cell's configuration and traffic (tokens x a token's slots x the
+rows' width), at four shares of the buffer that hold an assignment (what the
+cells run, 1.5% to 25%, and a deployment's full buffer).  First the
+token-major pass alone (`ops/moe._rows_back` with the weights, as `combine`
+runs it) by form: the whole gather into a [j, T, d] array, which is what the
+layer runs, at a token's every choice and, where a token has more choices
+than experts held, after the sort that puts its live slots first
+(`_live_slots_first`); then the two forms PR 48 swept and did not take, the
+pass tiled over tokens by the tokens of a tile and the inverse form that adds
+the live row tiles into a float32 [T, d] accumulator.  Then one whole layer,
+`ops/moe.routed_experts`, forward and backward: device time by the layer's
+scopes from a capture.  In a tree whose `ops/moe.py` has no
+`_live_slots_first` (copy this file there) it measures that tree's whole
+gather and layer, which is how the parent is read beside the change.  `row_tile` (run by name only)
+is the layer at the GLM cell's shape and both SwiGLU widths by the tile of its
+passes over the sorted buffer (`ROW_TILE` in `ops/moe.py` is picked from it).
+Through chiprun; one JSON line
 per row.  Name a sweep to run it alone.
 
-    chiprun -- python3 scripts/chip_grouped_matmul_sweep.py [experts] [attention] [routing] [window]
+    chiprun -- python3 scripts/chip_grouped_matmul_sweep.py [experts] [attention] [routing] [row_tile] [window]
 """
 
 from __future__ import annotations
@@ -36,6 +47,7 @@ import shutil
 import sys
 import tempfile
 import time
+from functools import partial
 from pathlib import Path
 
 ROOT = Path(__file__).resolve().parents[1]
@@ -58,13 +70,22 @@ FORWARD_BLOCKS = (512, 1024, 2048)  # q block and kv block, every combination
 WINDOW_SHAPE, WINDOW, WINDOW_BLOCKS = (2, 8192, 64, 8, 128), 512, (256, 512, 1024)
 WINDOW_FULL_SHAPE = (2, 8192, 48, 8, 128)
 CALLS = 5
-# The routing sweep: the expert widths of the GLM and the LFM2 cell, the tiles
+# The row-tile sweep: the expert widths of the GLM and the LFM2 cell, the tiles
 # of the passes over the buffer, and the shares of the assignments held here
 # (an expert-parallel rank of four holds a quarter on average; 1: every expert).
 ROUTING_WIDTHS = (1536, 1792)
 ROUTING_TILES = (2048, 4096, 8192, 16384)
 ROUTING_SHARES = (0.12, 0.25, 0.5, 1.0)
 ROUTING_SCOPES = ("router", "dispatch", "experts", "combine")
+# The routing sweep: the cells whose configurations give the shapes, the
+# shares of the buffer's rows that hold an assignment, and the tokens of a tile
+# of the token-major pass's tiled form.
+ROUTED_CELLS = (
+    "lfm2-8b-a1b.train-s8192", "glm-4.7-flash.train-s8192", "laguna-xs.2.train-s8192",
+    "nemotron-3-super-120b-a12b.train-s8192x1",
+)
+LIVE_SHARES = (0.015, 0.15, 0.25, 1.0)
+TOKEN_TILES = (256, 512, 1024, 2048, 4096)
 
 
 def top_operations(trace_dir: str, n: int = 6) -> list:
@@ -137,22 +158,23 @@ def experts_sweep() -> None:
         print(json.dumps(row, allow_nan=False), flush=True)
 
 
-def routing_input(share: float):
-    """x [1, TOKENS, D] whose first N_ROUTED columns are the router's logits
+def routing_input(share: float, tokens=TOKENS, dim=D, n_routed=N_ROUTED, held=HELD, base=0.25):
+    """x [1, tokens, dim] whose first n_routed columns are the router's logits
     (the router is an identity on them): noise, and per token a push towards
-    the held experts (all four choices held), none (one in four held) or away
-    from them (none held), mixed so that `share` of the assignments are held."""
+    the held experts (as many choices held as can be), none (`base` of the
+    buffer held) or away from them (none held), mixed so that `share` of the
+    buffer's rows hold an assignment."""
     import jax
     import jax.numpy as jnp
 
-    keys = jax.random.split(jax.random.key(int(share * 100)), 3)
-    towards = max(0.0, (share - 0.25) / 0.75)
-    away = max(0.0, 1 - share / 0.25)
-    u = jax.random.uniform(keys[0], (TOKENS, 1))
+    keys = jax.random.split(jax.random.key(int(share * 1000)), 3)
+    towards = max(0.0, (share - base) / (1 - base))
+    away = max(0.0, 1 - share / base)
+    u = jax.random.uniform(keys[0], (tokens, 1))
     push = jnp.where(u < towards, 8.0, jnp.where(u > 1 - away, -8.0, 0.0))
-    logits = jax.random.normal(keys[1], (TOKENS, N_ROUTED)) + push * (jnp.arange(N_ROUTED) < HELD)
-    x = jax.random.normal(keys[2], (TOKENS, D)).at[:, :N_ROUTED].set(logits)
-    return x.astype(jnp.bfloat16).reshape(1, TOKENS, D)
+    logits = jax.random.normal(keys[1], (tokens, n_routed)) + push * (jnp.arange(n_routed) < held)
+    x = jax.random.normal(keys[2], (tokens, dim)).at[:, :n_routed].set(logits)
+    return x.astype(jnp.bfloat16).reshape(1, tokens, dim)
 
 
 def scope_ms(trace_dir: str) -> dict:
@@ -175,7 +197,47 @@ def scope_ms(trace_dir: str) -> dict:
     return {"all": ms(every), **{scope: ms(spans) for scope, spans in by_scope.items()}}
 
 
-def routing_sweep() -> None:
+def device_ms(run, *args) -> dict:
+    """`scope_ms` of CALLS calls of `run`, compiled and run once before."""
+    import jax
+
+    jax.block_until_ready(run(*args))
+    trace_dir = tempfile.mkdtemp(prefix="routing_sweep_")
+    with jax.profiler.trace(trace_dir):
+        jax.block_until_ready([run(*args) for _ in range(CALLS)])
+    ms = scope_ms(trace_dir)
+    shutil.rmtree(trace_dir, ignore_errors=True)
+    return ms
+
+
+def timed_layer(cfg, params, inputs: dict, row: dict, rows_dim: int | None = None) -> None:
+    """One whole routed layer forward and backward on each of `inputs`
+    ({share: x}): the statistics, the host clock's time and the device time by
+    scope, a JSON line each.  `rows_dim`: the experts read and write the
+    input's first columns alone (a latent's width)."""
+    import jax
+    import jax.numpy as jnp
+
+    from deeplearning_cfn_tpu.ops import moe
+
+    def loss(params, x):
+        latent = None if rows_dim is None else x[..., :rows_dim]
+        y, stats = moe.routed_experts(cfg, params, x, expert_rows=latent)
+        stats = {k: v for k, v in stats.items() if k != "selected"}
+        return jnp.sum(y.astype(jnp.float32) ** 2), stats
+
+    run = jax.jit(jax.grad(loss, argnums=(0, 1), has_aux=True))
+    for share, x in inputs.items():
+        _, stats = jax.block_until_ready(run(params, x))
+        out = {**row, "share": share, **{k: int(v) for k, v in stats.items()}}
+        t0 = time.perf_counter()
+        jax.block_until_ready([run(params, x) for _ in range(CALLS)])
+        out["forward_backward_ms"] = round(1e3 * (time.perf_counter() - t0) / CALLS, 3)
+        out["device_ms"] = device_ms(run, params, x)
+        print(json.dumps(out, allow_nan=False), flush=True)
+
+
+def row_tile_sweep() -> None:
     import jax
     import jax.numpy as jnp
 
@@ -189,26 +251,138 @@ def routing_sweep() -> None:
         params["router"] = jnp.eye(D, N_ROUTED, dtype=jnp.float32)
         if tile:
             moe.ROW_TILE = tile
+        timed_layer(cfg, params, inputs, {"routing": width, "row_tile": tile})
 
-        def loss(params, x):
-            y, stats = moe.routed_experts(cfg, params, x)
-            stats = {k: v for k, v in stats.items() if k != "selected"}
-            return jnp.sum(y.astype(jnp.float32) ** 2), stats
 
-        run = jax.jit(jax.grad(loss, argnums=(0, 1), has_aux=True))
-        for share, x in inputs.items():
-            _, stats = jax.block_until_ready(run(params, x))
-            row = {"routing": width, "row_tile": tile, "share": share}
-            row.update({k: int(v) for k, v in stats.items()})
-            t0 = time.perf_counter()
-            jax.block_until_ready([run(params, x) for _ in range(CALLS)])
-            row["forward_backward_ms"] = round(1e3 * (time.perf_counter() - t0) / CALLS, 3)
-            trace_dir = tempfile.mkdtemp(prefix="routing_sweep_")
-            with jax.profiler.trace(trace_dir):
-                jax.block_until_ready([run(params, x) for _ in range(CALLS)])
-            row["device_ms"] = scope_ms(trace_dir)
-            shutil.rmtree(trace_dir, ignore_errors=True)
-            print(json.dumps(row, allow_nan=False), flush=True)
+def rows_back_tiled(rows, slot, held, weight, tile):
+    """ISSUE 48's step 2, swept and not taken (PERF.md section 6, PR 48): the
+    token-major pass in a loop over tiles of `tile` tokens, a tile's gather,
+    product, select and float32 sum written into the result once; where the
+    tile does not divide the tokens the last one overlaps the one before it."""
+    import jax
+    import jax.numpy as jnp
+
+    T = slot.shape[0]
+    slot, weight = slot.T, weight.T
+    bits = jnp.finfo(rows.dtype)
+
+    def body(i, y):
+        start = jnp.minimum(i * tile, T - tile)
+        at = jax.lax.dynamic_slice_in_dim(slot, start, tile, 1)
+        by = jax.lax.dynamic_slice_in_dim(weight, start, tile, 1)
+        picked = rows[jnp.minimum(at, rows.shape[0] - 1)]  # [j, tile, d]
+        picked = jax.lax.reduce_precision(picked * by[..., None], bits.nexp, bits.nmant)
+        picked = jnp.where((at < held)[..., None], picked, 0)
+        summed = jnp.sum(picked, axis=0, dtype=jnp.float32).astype(rows.dtype)
+        return jax.lax.dynamic_update_slice_in_dim(y, summed, start, 0)
+
+    return jax.lax.fori_loop(0, -(-T // tile), body, jnp.zeros((T, rows.shape[1]), rows.dtype))
+
+
+def rows_back_inverse(rows, token, held, row_weight, n_tokens):
+    """ISSUE 48's candidate (a): the live row tiles, each row times its weight
+    rounded to the rows' type, added into a float32 [T, d] accumulator at the
+    row's token and cast once."""
+    import jax
+    import jax.numpy as jnp
+
+    from deeplearning_cfn_tpu.ops import moe
+
+    tile, live_tiles = moe._live_tiles(rows.shape[0], held)
+    bits = jnp.finfo(rows.dtype)
+
+    def body(i, acc):
+        start = i * tile
+        cut = lambda a: jax.lax.dynamic_slice_in_dim(a, start, tile)
+        product = jax.lax.reduce_precision(
+            cut(rows) * cut(row_weight)[:, None], bits.nexp, bits.nmant)
+        live = (start + jnp.arange(tile) < held)[:, None]
+        return acc.at[cut(token)].add(jnp.where(live, product, 0).astype(jnp.float32))
+
+    acc = jnp.zeros((n_tokens, rows.shape[1]), jnp.float32)
+    return jax.lax.fori_loop(0, live_tiles, body, acc).astype(rows.dtype)
+
+
+def token_major_sweep(name: str, tokens: int, k: int, j: int, d: int) -> None:
+    """The token-major pass alone at one cell's shape, by form, tile and live
+    share: a random permutation of the buffer's rows as the tokens' slots (a
+    token's k - j choices that cannot be held here point past the buffer, in
+    columns drawn anew for each token), device time a call."""
+    import jax
+    import jax.numpy as jnp
+
+    from deeplearning_cfn_tpu.ops import moe
+
+    keys = jax.random.split(jax.random.key(tokens + k), 5)
+    n_rows = tokens * j
+    rows = jax.random.normal(keys[0], (n_rows, d), jnp.bfloat16)
+    narrow = jax.random.permutation(keys[1], n_rows).astype(jnp.int32).reshape(tokens, j)
+    beyond = n_rows + jnp.arange(tokens * (k - j), dtype=jnp.int32).reshape(tokens, k - j)
+    columns = jnp.argsort(jax.random.uniform(keys[2], (tokens, k)), axis=1)
+    wide = jnp.take_along_axis(jnp.concatenate([narrow, beyond], axis=1), columns, axis=1)
+    weight = jax.random.uniform(keys[3], (tokens, k), jnp.float32).astype(jnp.bfloat16)
+    # the buffer's order: the row's token, and its weight
+    token = (jnp.argsort(narrow.reshape(-1)) // j).astype(jnp.int32)
+    row_weight = jax.random.uniform(keys[4], (n_rows,), jnp.float32).astype(jnp.bfloat16)
+    row = {"token_major": name, "tokens": tokens, "choices": k, "slots": j, "width": d}
+
+    def emit(form, run, *args, **more):
+        """A row for each live share: `run(*args)` with the count where an
+        argument is None."""
+        for share in LIVE_SHARES:
+            held = jnp.asarray(int(share * n_rows), jnp.int32)
+            out = {**row, "form": form, "share": share, **more}
+            try:
+                out["device_ms"] = device_ms(run, *(held if a is None else a for a in args))["all"]
+            except Exception as e:  # a form the compiler refuses is a row too
+                out["error"] = str(e)[:300]
+            print(json.dumps(out, allow_nan=False), flush=True)
+
+    whole = jax.jit(moe._rows_back)
+    emit("whole", whole, rows, wide, None, weight, slots_read=tokens * k)
+    if not hasattr(moe, "_live_slots_first"):
+        return
+    slot, by = wide, weight
+    if k > j:
+        first = jax.jit(lambda s: moe._live_slots_first(s, j))
+        emit("live_slots_first", first, wide)
+        slot, by = first(wide), weight[:, :j]
+        emit("whole_after_sort", whole, rows, slot, None, by, slots_read=tokens * j)
+    for tile in TOKEN_TILES:
+        emit("tiled", jax.jit(partial(rows_back_tiled, tile=min(tile, tokens))), rows, slot, None, by,
+             token_tile=tile, slots_a_gather=tile * j, slots_read=tokens * j)
+    inverse = jax.jit(rows_back_inverse, static_argnums=4)
+    emit("inverse", inverse, rows, token, None, row_weight, tokens)
+
+
+def routing_sweep() -> None:
+    import jax
+    import jax.numpy as jnp
+
+    from benchmarks.manifest import Manifest
+    from deeplearning_cfn_tpu.ops import moe
+
+    manifest = Manifest()
+    for name in ROUTED_CELLS:
+        (cell,) = [w for w in manifest.data["workloads"] if w["name"] == name]
+        config, traffic = manifest.config(cell["config"]), manifest.json("traffic", cell["traffic"])
+        model = manifest.module("builders", config["kind"]).model_config(config)
+        cfg, tokens = model.routed, traffic["global_batch"] * traffic["seq_len"]
+        count = cfg.span[1]
+        j = min(cfg.top_k, count)
+        d = getattr(model, "latent_dim", model.dim)  # the rows the experts read and write
+        token_major_sweep(name, tokens, cfg.top_k, j, d)
+        # the layer alone: no shared expert, the held span from 0, an identity router
+        cfg = moe.RoutedConfig(n_routed=cfg.n_routed, top_k=cfg.top_k, held=(0, count),
+                               expert=cfg.expert)
+        params = moe.init_routed_params(cfg, jax.random.key(0), model.dim, model.expert_dim,
+                                        rows_dim=d)
+        params["router"] = jnp.eye(model.dim, cfg.n_routed, dtype=jnp.float32)
+        base = cfg.top_k * count / (cfg.n_routed * j)  # of the buffer, at a uniform router
+        inputs = {share: routing_input(share, tokens, model.dim, cfg.n_routed, count, base)
+                  for share in LIVE_SHARES}
+        timed_layer(cfg, params, inputs, {"routing": name},
+                    rows_dim=d if d != model.dim else None)
 
 
 def pair_counts(seq: int, block_q: int, block_k: int, window: int | None = None) -> dict:
@@ -325,7 +499,7 @@ def main(argv: list[str]) -> int:
     import jax
 
     sweeps = {"experts": experts_sweep, "attention": attention_sweep, "routing": routing_sweep,
-              "window": window_sweep}
+              "row_tile": row_tile_sweep, "window": window_sweep}
     if jax.devices()[0].platform != "tpu":
         print("chip_grouped_matmul_sweep: needs a TPU", file=sys.stderr)
         return 1

@@ -21,12 +21,17 @@ from tests.kernel_text import text_without_kernel_locations
 # what the decoders share into `models/decoder_stack.py` and had to leave all six
 # as they were.  A PR that means to change a cell's step replaces that cell's row
 # and says why; a row that moves in a PR that does not mean it is a fault.
+# PR 48 re-took the four routed rows (glm-4.7-flash, lfm2-8b-a1b, laguna-xs.2,
+# nemotron-3-super-120b-a12b) and meant to: `ops/moe.routed_experts` counts
+# `slots_read`, reads `dropped` from the sort's own keys and, in the Nemotron
+# row, gathers the rows' cotangent back over a token's live slots, no wider
+# than the buffer.  The three rows without a routed layer did not move.
 STEPS = {
     "mistral-7b-v0.3": "44e7a8f13d410b187f5495093044228525242162611407fe3fa14d707f08605f",
-    "glm-4.7-flash": "a6519d15d73ef035429a8557061398cce37615e19eeddacd2b9efdfe080c95b9",
-    "lfm2-8b-a1b": "b82e5a3bbf65a31a66a2e97d869a4f1aa6f2f71baa9fd94c5c3ab63ade56fd82",
-    "laguna-xs.2": "9b677fca70578e550d4f0e7d6ae9031428612872ade943f8aea77d030538d932",
-    "nemotron-3-super-120b-a12b": "1ab8ea1d2df8f537dfc14b8d381cba1e839a3e9dd41719ecf3179dd66a530004",
+    "glm-4.7-flash": "d1ce4dcb68465d51c3032071be0376872f75210e434ad6118511a3b3078fbd8b",
+    "lfm2-8b-a1b": "d0da684a89090c5dda85fa7fe7010680c4f31a1b13d5d754cb22a18aaeadae8f",
+    "laguna-xs.2": "6a4822a3815a1b1f47d0a912508765f868fde5a1701f8c71fa890a90fa4a8e3f",
+    "nemotron-3-super-120b-a12b": "979557c6defbfe2f5e1e441c3b539c4d3ff28827450e5d4f01eaccbd8bf764f6",
     "ouro-2.6b": "173cda54d3db1119256c9ed06402770e2279f876085e09d45da6f0d86920315b",
     # PR 47's own cell, taken on PR 47's tree: the six rows above did not move.
     "jamba2-3b": "9684fd2cf07f76fa6d1a0bb7af4b1e60a6c34e939749723465c9dc16f73b0b30",

@@ -177,12 +177,16 @@ def test_fit_trains_and_folds_the_routing_counters_at_the_log_seam():
     assert losses[-1] < losses[0]
     counted = {k: v for k, v in tracing.counters().items() if k.startswith("moe.")}
     assert set(counted) == {
-        "moe.assignments", "moe.assignments_held", "moe.rows_run", "moe.expert_load_max",
-        "moe.expert_load_mean", "moe.dropped",
+        "moe.assignments", "moe.assignments_held", "moe.rows_run", "moe.slots_read",
+        "moe.expert_load_max", "moe.expert_load_mean", "moe.dropped",
     }
     assert all(v["count"] == 7 for v in counted.values())  # the odd last step too
     blocks, tokens = 4, 4 * 32  # the attention layer and three conv layers route
     assert counted["moe.assignments"]["total"] == 7 * blocks * tokens * cfg.top_k
+    # the token-major gathers read a slot for each of a token's choices forward, and backward
+    # for each that can be held here
+    assert counted["moe.slots_read"]["total"] == 7 * blocks * tokens * (
+        cfg.top_k + min(cfg.top_k, cfg.held_experts[1]))
     assert 0 < counted["moe.assignments_held"]["total"] < counted["moe.assignments"]["total"]
     # the passes over the buffer stop at the tile that holds the last held row
     assert (

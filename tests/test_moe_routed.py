@@ -21,6 +21,10 @@ def _swiglu(x, w_gate, w_up, w_down):
     return (jax.nn.silu(x @ w_gate) * (x @ w_up)) @ w_down
 
 
+def _relu2(x, w_up, w_down):
+    return jnp.square(jax.nn.relu(x @ w_up)) @ w_down
+
+
 def dense_masked(cfg: RoutedConfig, p: dict, x: jax.Array, shared: bool = True) -> jax.Array:
     """sum over the held experts of w_i E_i(x) by a mask, every expert on
     every token: no sort, no gather, nothing to drop."""
@@ -30,7 +34,11 @@ def dense_masked(cfg: RoutedConfig, p: dict, x: jax.Array, shared: bool = True) 
     y = jnp.zeros_like(xt)
     for j in range(count):
         share = jnp.sum(jnp.where(experts == first + j, weights, 0.0), axis=-1)
-        y = y + share[:, None] * _swiglu(xt, p["w_gate"][j], p["w_up"][j], p["w_down"][j])
+        if cfg.expert == "relu2":
+            out = _relu2(xt, p["w_up"][j], p["w_down"][j])
+        else:
+            out = _swiglu(xt, p["w_gate"][j], p["w_up"][j], p["w_down"][j])
+        y = y + share[:, None] * out
     if shared and cfg.shared_dim:
         y = y + _swiglu(xt, p["shared_gate"], p["shared_up"], p["shared_down"])
     return y.reshape(x.shape)
@@ -187,7 +195,7 @@ def _assert_layer_matches_the_mask(cfg, p, x, **kw):
     loss = lambda f: (lambda p, x: jnp.sum(f(p, x) ** 2))
     got = jax.grad(loss(lambda p, x: routed_experts(cfg, p, x, **kw)[0]), (0, 1))(p, x)
     want = jax.grad(loss(lambda p, x: dense_masked(cfg, p, x)), (0, 1))(p, x)
-    for name in ("router", "w_gate", "w_up", "w_down"):
+    for name in ("router", "w_up", "w_down") + (("w_gate",) if cfg.expert == "swiglu" else ()):
         assert name in got[0]
     for a, b in zip(jax.tree_util.tree_leaves(got), jax.tree_util.tree_leaves(want)):
         assert np.all(np.isfinite(np.asarray(a)))
@@ -262,6 +270,107 @@ def test_rows_past_the_last_group_are_zero_and_pass_no_gradient(row_tile, monkey
         cfg, p, x = _layer_holding(held_rows)
         stats = _assert_layer_matches_the_mask(cfg, p, x, kind="xla")
         assert int(stats["rows_run"]) == -(-held_rows // TILE) * TILE
+
+
+# --- the token-major gathers: backward over a token's live slots, no wider than the buffer
+
+WIDE = 32  # the input's width where the router is an identity over 32 experts
+
+# (experts, choices, the span held): a token's choices as many as fit the held
+# experts and more of them than are held (Nemotron's 22 of 512 with 8 held,
+# scaled down: the gather of the rows' cotangent is then two slots wide, not six).
+ROUTERS = {
+    "choices-fit-the-held": dict(n_routed=8, top_k=2, held=(2, 4)),
+    "more-choices-than-held": dict(n_routed=32, top_k=6, held=(4, 2)),
+}
+# tokens, and how many held experts token t chooses (of two at most).
+CHOICES = {
+    "held-0": (64, lambda t: 0),
+    "every-slot-live": (64, lambda t: 2),
+    "a-count-that-ends-inside-a-row-tile": (64, lambda t: 2 if t < 21 else 0),
+    "live-slots-among-dead-ones": (64, lambda t: t % 3),
+    "tokens-the-row-tile-does-not-divide": (72, lambda t: (t + 1) % 3),
+}
+
+
+def _layer_choosing(router: str, expert: str, choices: str):
+    """A layer whose router is an identity on the input's first columns, which
+    hold the logits: token t chooses as many held experts as `CHOICES` says
+    and absent ones for the rest of its choices."""
+    cfg = RoutedConfig(scale=1.8, expert=expert, **ROUTERS[router])
+    tokens, n_held = CHOICES[choices]
+    first, count = cfg.span
+    absent = [e for e in range(cfg.n_routed) if not first <= e < first + count]
+    logits = np.asarray(jax.random.normal(jax.random.key(2), (tokens, cfg.n_routed))) * 0.3
+    for t in range(tokens):
+        chosen = [first + (t + i) % count for i in range(n_held(t))]
+        chosen += [absent[(t + i) % len(absent)] for i in range(cfg.top_k - n_held(t))]
+        logits[t, chosen] += 4.0
+    p = init_routed_params(cfg, jax.random.key(0), WIDE, WIDTH, jnp.float32)
+    p["router"] = jnp.eye(WIDE, cfg.n_routed, dtype=jnp.float32)
+    x = jax.random.normal(jax.random.key(1), (tokens, WIDE), jnp.float32)
+    x = x.at[:, : cfg.n_routed].set(jnp.asarray(logits)).reshape(1, tokens, WIDE)
+    return cfg, p, x, sum(n_held(t) for t in range(tokens))
+
+
+@pytest.mark.parametrize("choices", CHOICES)
+@pytest.mark.parametrize("expert", ["swiglu", "relu2"])
+@pytest.mark.parametrize("router", ROUTERS)
+def test_the_token_major_pass_is_the_mask_over_experts(row_tile, router, expert, choices):
+    """Forward and the gradients with respect to x, the router and the
+    experts' matrices, with the gather of the rows' cotangent, where a token
+    has more choices than experts are held, over its two live slots alone."""
+    cfg, p, x, held = _layer_choosing(router, expert, choices)
+    tokens = x.shape[1]
+    stats = _assert_layer_matches_the_mask(cfg, p, x, kind="xla")
+    assert int(stats["assignments"]) == tokens * cfg.top_k
+    assert int(stats["assignments_held"]) == held and int(stats["dropped"]) == 0
+    assert int(stats["slots_read"]) == tokens * cfg.top_k + cfg.buffer_rows(tokens) == tokens * (cfg.top_k + 2)
+
+
+def test_the_counters_sum_the_slots_a_pass_reads_over_the_blocks(row_tile):
+    cfg, p, x, held = _layer_choosing("more-choices-than-held", "relu2", "live-slots-among-dead-ones")
+    stats = [routed_experts(cfg, p, x, kind="xla")[1], routed_experts(cfg, p, 2 * x, kind="xla")[1]]
+    counted = {k: float(v) for k, v in moe.routing_counters(cfg, stats).items()}
+    assert counted["moe.slots_read"] == 2 * 64 * (6 + 2) and counted["moe.assignments"] == 2 * 64 * 6
+    assert counted["moe.assignments_held"] == 2 * held and counted["moe.dropped"] == 0.0
+
+
+def _rows_back_by_token(rows, slot, held, weight=None):
+    """The pass token by token: the gather of every slot, the product rounded
+    by a cast, the select, the sum."""
+    picked = rows[jnp.minimum(slot, rows.shape[0] - 1)].astype(jnp.float32)  # [T, j, d]
+    if weight is not None:
+        picked = (picked * weight[..., None]).astype(rows.dtype).astype(jnp.float32)
+    return jnp.sum(jnp.where((slot < held)[..., None], picked, 0), axis=1).astype(rows.dtype)
+
+
+@pytest.mark.parametrize("weighted", [False, True], ids=["unweighted", "weighted"])
+@pytest.mark.parametrize("tokens", [8, 64, 77])
+def test_rows_back_is_the_gather_product_select_and_sum(tokens, weighted):
+    """Bit for bit in bfloat16, at a count of none, one, some and every row."""
+    keys = jax.random.split(jax.random.key(tokens), 3)
+    rows = jax.random.normal(keys[0], (tokens * 2, D), jnp.bfloat16)
+    slot = jax.random.permutation(keys[1], tokens * 2).astype(jnp.int32).reshape(tokens, 2)
+    weight = jax.random.uniform(keys[2], (tokens, 2)).astype(jnp.bfloat16) if weighted else None
+    for held in (0, 1, tokens // 2 + 3, tokens * 2):
+        got = moe._rows_back(rows, slot, jnp.asarray(held, jnp.int32), weight)
+        want = _rows_back_by_token(rows, slot, held, weight)
+        np.testing.assert_array_equal(np.asarray(got, np.float32), np.asarray(want, np.float32))
+
+
+def test_live_slots_first_keeps_the_smallest_slots():
+    slot = jnp.asarray([[40, 3, 55, 7, 41], [9, 50, 60, 2, 70], [80, 81, 82, 83, 84]], jnp.int32)
+    np.testing.assert_array_equal(
+        np.asarray(moe._live_slots_first(slot, 2)), [[3, 7], [2, 9], [80, 81]])
+
+
+def test_where_the_choices_fit_the_held_experts_no_sort_is_added():
+    """`k <= count`: the layer's text holds the two sorts it had and no other."""
+    sorts = lambda r: jax.jit(
+        lambda p, x: routed_experts(RoutedConfig(**ROUTERS[r]), p, x, kind="xla")[0]
+    ).lower(*_layer_choosing(r, "swiglu", "held-0")[1:3]).as_text().count("stablehlo.sort")
+    assert sorts("more-choices-than-held") == sorts("choices-fit-the-held") + 1
 
 
 def test_config_refuses_what_is_not_a_span():
